@@ -6,38 +6,20 @@
 2. Measured backend crossover: ``core.autotune.race`` times every backend
    each spec can run in-process (pallas vs XLA at mesh 1x1) and reports
    the winner next to the committed default table's entry — the same
-   measurement ``tools/gen_autotune.py`` persists, run live.
-3. Chip-level race: the same race on a 16-device (4,4) sub-mesh (spawned
-   in a subprocess with forced host devices so this process keeps 1
-   visible device), putting the systolic/allgather schedules into the
-   field against pallas/XLA.
-4. Table IV analogue: WideSA (AIE) vs PL-only (AutoSA) energy-efficiency
+   measurement ``tools/gen_autotune.py`` persists, run live.  The
+   chip-level schedules (systolic/allgather) run on a real 2x2 mesh in
+   ``chip_smoke.py --chips 4``, one process holding all four chips.
+3. Table IV analogue: WideSA (AIE) vs PL-only (AutoSA) energy-efficiency
    ratios recomputed from the paper's numbers against our bounds.
 """
 
 from __future__ import annotations
 
-import json
-import subprocess
-import sys
 import time
 
 from repro.core import AIE_TARGET, Target, autotune, enumerate_schedules, matmul
 from repro.core.plio import assign_plios, build_mapped_graph, congestion, naive_assignment
 from repro.kernels import registry
-
-_SUBPROC = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-import json, sys
-sys.path.insert(0, "src")
-from repro.core import Target, autotune, matmul
-
-rec = matmul(256, 256, 256, "float32")
-policy = autotune.PlanPolicy(mode="measured", reps=2, warmup=1)
-res = autotune.race(rec, Target(name="chip_4x4", mesh_shape=(4, 4)), policy)
-print(json.dumps(res))
-"""
 
 # specs raced in-process for section 2; smoke shapes keep interpret-mode
 # pallas affordable while still crossing the pallas/XLA break-even
@@ -88,24 +70,6 @@ def run(csv_rows: list):
         csv_rows.append(
             (f"autotune_race_{name}", res["us"][res["backend"]],
              f"winner={res['backend']};{agree}"))
-
-    print("\n== chip-level race: systolic/allgather vs pallas/XLA (4x4) ==")
-    t0 = time.perf_counter()
-    proc = subprocess.run(
-        [sys.executable, "-c", _SUBPROC], capture_output=True, text=True,
-        cwd=".",
-    )
-    dt = time.perf_counter() - t0
-    if proc.returncode != 0:
-        print("subprocess failed:", proc.stderr[-500:])
-        return
-    res = json.loads(proc.stdout.strip().splitlines()[-1])
-    for backend, us in sorted(res["us"].items(), key=lambda kv: kv[1]):
-        mark = " <- winner" if backend == res["backend"] else ""
-        print(f"  {backend:10s} {us:12.1f} us{mark}")
-        csv_rows.append(
-            (f"mapping_race44_{backend}_mm256", us,
-             f"winner={res['backend']};subproc_s={dt:.1f}"))
 
     print("\n== Table IV analogue (energy-efficiency ratios, from paper) ==")
     # paper Table IV: norm. TOPS/W of WideSA vs PL-only

@@ -11,15 +11,17 @@ import os
 
 from repro.core import roofline as RL
 
+V5E = RL.peaks(RL.V5E)  # the dry run models a v5e pod
+
 CHIPS = {"16x16": 256, "2x16x16": 512}
 
 
 def _terms(d):
     coll = sum(d["coll"].values()) if d["coll"] else 0.0
     return {
-        "t_comp": d["flops"] / RL.PEAK_FLOPS_BF16,
-        "t_mem": d["bytes_accessed"] / RL.HBM_BW,
-        "t_coll": coll / RL.ICI_BW,
+        "t_comp": d["flops"] / V5E.bf16_flops,
+        "t_mem": d["bytes_accessed"] / V5E.hbm_bw,
+        "t_coll": coll / V5E.ici_bw,
         "temp": (d["memory"]["temp_bytes"]
                  + d["memory"]["argument_bytes"]) / 2**30,
         "useful": d["model_flops"] / max(

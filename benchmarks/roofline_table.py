@@ -37,6 +37,8 @@ from repro.core.mapper import Target, best_plan, predict_bounds
 from repro.core import roofline as RL
 from repro.kernels import registry
 
+V5E = RL.peaks(RL.V5E)  # the dry run models a v5e pod
+
 CHIPS = {"16x16": 256, "2x16x16": 512}
 
 
@@ -212,9 +214,9 @@ def _rl_from_json(d: dict) -> RL.Roofline:
         flops_per_chip=d["flops"],
         bytes_per_chip=d["bytes_accessed"],
         coll_bytes_per_chip=coll_total,
-        t_compute=d["flops"] / RL.PEAK_FLOPS_BF16,
-        t_memory=d["bytes_accessed"] / RL.HBM_BW,
-        t_collective=coll_total / RL.ICI_BW,
+        t_compute=d["flops"] / V5E.bf16_flops,
+        t_memory=d["bytes_accessed"] / V5E.hbm_bw,
+        t_collective=coll_total / V5E.ici_bw,
         bottleneck="",
         model_flops=d["model_flops"],
         useful_ratio=d["model_flops"] / max(

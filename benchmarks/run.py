@@ -546,6 +546,9 @@ def main() -> None:
                          "benchmarks/BENCH_PR10.json explicitly when "
                          "refreshing the committed baseline)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.ci:
         ci_bench(args.out)
         return
@@ -567,18 +570,22 @@ def main() -> None:
         "roofline": roofline_table.run,         # EXPERIMENTS §Roofline
     }
     csv_rows: list = []
+    failed = []
     for name, fn in sections.items():
         if only and name not in only:
             continue
         try:
             fn(csv_rows)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report every section
+            failed.append(name)
             print(f"[bench {name}] FAILED: {type(e).__name__}: {e}",
                   file=sys.stderr)
 
     print("\nname,us_per_call,derived")
     for name, us, derived in csv_rows:
         print(f"{name},{us:.1f},{derived}")
+    if failed:
+        sys.exit(f"bench sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

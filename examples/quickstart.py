@@ -45,8 +45,8 @@ def main():
     print(f"  collective axis per stream: "
           f"{plan.axis_assignment.stream_axis}")
 
-    print("\nexecuting the plan (Pallas, interpret mode):")
-    fn = lower_plan(plan, backend="pallas", interpret=True)
+    print("\nexecuting the plan (Pallas; the interpreter off a TPU):")
+    fn = lower_plan(plan, backend="pallas")
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.standard_normal((1024, 1024)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((1024, 1024)), jnp.float32)

@@ -1,86 +1,33 @@
-"""Version-portable jax API surface (single import point for moving APIs).
+"""The project's defaults for jax's mesh and shard_map entry points.
 
-The repo targets the jax version baked into the container, but the public
-APIs it leans on have moved across releases:
+The repo is pinned to one jax release (see ``requirements.txt``).  These
+two wrappers only fix the defaults every call site wants:
 
-  * ``shard_map``    — ``jax.experimental.shard_map.shard_map(check_rep=...)``
-                       in jax<=0.4.x, promoted to ``jax.shard_map`` with the
-                       ``check_rep`` kwarg later renamed ``check_vma``.
-  * ``make_mesh``    — ``axis_types=``/``jax.sharding.AxisType`` only exist
-                       on newer releases; older ones take (shapes, names).
+  * ``shard_map``  — ``jax.shard_map`` with replication checking
+                     (``check_vma``) off unless asked for;
+  * ``make_mesh``  — ``jax.make_mesh`` with every axis ``AxisType.Auto``,
+                     so GSPMD propagates shardings the way the schedules
+                     in ``kernels/systolic.py`` expect.
 
-Every call site in src/, tests/ and benchmarks/ goes through this module so
-a jax upgrade (or downgrade) is a one-file change.  The Pallas-specific
-shims (``compiler_params``, interpret-mode fallback) live with the kernels
-in ``repro.kernels.runtime`` for the same reason.
+The Pallas-specific helpers (``compiler_params``, the interpret-mode
+resolution) live with the kernels in ``repro.kernels.runtime``.
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
-
 import jax
 
 
-@functools.lru_cache(maxsize=1)
-def _shard_map_impl():
-    """(callable, name_of_replication_check_kwarg)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # jax<=0.4.x
-    params = inspect.signature(fn).parameters
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return fn, kw
-    return fn, None
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    ``check`` maps onto ``check_vma``/``check_rep`` (replication checking),
-    whichever the installed jax spells.
-    """
-    fn, check_kw = _shard_map_impl()
-    kwargs = {check_kw: check} if check_kw is not None else {}
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict across jax versions.
-
-    Older releases return a list with one per-module dict; newer ones
-    return the dict directly.  Returns {} when XLA offers no analysis.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``jax.shard_map``; ``check`` is its ``check_vma`` replication check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with auto axis types where the API supports them.
-
-    Releases without ``jax.make_mesh`` at all fall back to reshaping the
-    device list into a ``jax.sharding.Mesh`` directly.
-    """
-    axis_shapes, axis_names = tuple(axis_shapes), tuple(axis_names)
-    fn = getattr(jax, "make_mesh", None)
-    if fn is None:  # very old jax: build the Mesh by hand
-        import math
-
-        import numpy as np
-
-        devs = list(devices) if devices is not None else jax.devices()
-        n = math.prod(axis_shapes)
-        arr = np.asarray(devs[:n]).reshape(axis_shapes)
-        return jax.sharding.Mesh(arr, axis_names)
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    sig = inspect.signature(fn).parameters
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if "axis_types" in sig and axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return fn(axis_shapes, axis_names, **kwargs)
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)

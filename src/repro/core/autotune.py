@@ -56,6 +56,7 @@ import warnings
 from pathlib import Path
 from typing import TYPE_CHECKING, Any
 
+from .codegen import UnsupportedLoweringError
 from .mapper import ExecutionPlan, Target, map_recurrence
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
@@ -389,6 +390,14 @@ def _time_backend(fn, operands, *, warmup: int, reps: int) -> float:
     return statistics.median(samples)
 
 
+def _skip_warning(backend: str, name: str, err: Exception) -> None:
+    """A backend that cannot lower this recurrence does not enter the
+    race.  Any other failure (a compile error of a backend this process
+    offers, a wrong result shape) propagates."""
+    warnings.warn(f"autotune race: {backend} skipped for {name}: {err}",
+                  stacklevel=3)
+
+
 def _race_chain(chain, target: Target, policy: PlanPolicy,
                 backends: tuple[str, ...] | None) -> dict:
     """Chain variant of ``race``: times the fused backends (the
@@ -418,10 +427,8 @@ def _race_chain(chain, target: Target, policy: PlanPolicy,
             us[backend] = round(
                 _time_backend(fn, operands, warmup=policy.warmup,
                               reps=policy.reps), 1)
-        except Exception as e:  # noqa: BLE001 - mirror the single-rec race
-            warnings.warn(f"autotune race: {backend} failed for chain "
-                          f"{chain.name}: {type(e).__name__}: {e}",
-                          stacklevel=2)
+        except (UnsupportedLoweringError, fusion.FusionError) as e:
+            _skip_warning(backend, chain.name, e)
     if not us:
         raise RuntimeError(
             f"autotune race: no backend ran for chain {chain.name}")
@@ -484,11 +491,8 @@ def race(rec: "UniformRecurrence", target: Target,
             us[backend] = round(
                 _time_backend(fn, operands, warmup=policy.warmup,
                               reps=policy.reps), 1)
-        except Exception as e:  # noqa: BLE001 - a backend that cannot
-            # lower this recurrence simply does not enter the race
-            warnings.warn(f"autotune race: {backend} failed for "
-                          f"{rec.name}: {type(e).__name__}: {e}",
-                          stacklevel=2)
+        except UnsupportedLoweringError as e:
+            _skip_warning(backend, rec.name, e)
     if not us:
         raise RuntimeError(f"autotune race: no backend ran for {rec.name}")
     winner = min(us, key=us.get)

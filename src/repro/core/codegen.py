@@ -24,7 +24,8 @@ Every backend resolves the recurrence through the KernelSpec registry
 backends dispatch through the spec's ``systolic_lowering`` /
 ``allgather_lowering`` hooks (implemented in ``repro/kernels/systolic.py``)
 — codegen carries no per-recurrence schedule of its own.  A spec without
-the hook raises NotImplementedError; an unregistered recurrence raises
+the hook, or a shape its schedule cannot tile over the mesh, raises
+``UnsupportedLoweringError``; an unregistered recurrence raises
 ``registry.UnregisteredRecurrenceError`` from any backend.
 """
 
@@ -34,6 +35,12 @@ import functools
 from typing import Callable
 
 from .mapper import ExecutionPlan
+
+
+class UnsupportedLoweringError(ValueError):
+    """This backend cannot lower this recurrence: no hook is registered,
+    or the shape does not tile over the mesh.  The one failure an
+    autotune race skips a backend for."""
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +114,7 @@ def lower_plan(
         hook = (spec.systolic_lowering if backend == "systolic"
                 else spec.allgather_lowering)
         if hook is None:
-            raise NotImplementedError(
+            raise UnsupportedLoweringError(
                 f"{backend} backend: recurrence {spec.name!r} registers no "
                 f"{backend} lowering hook (supports_systolic="
                 f"{spec.supports_systolic}) — see docs/systolic.md for the "

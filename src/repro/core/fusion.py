@@ -586,7 +586,9 @@ def lower_fused(plan: FusedPlan, backend: str | None = None, mesh=None,
         spec = registry.get(plan.chain.stages[-1].name)
         hook = spec.fused_systolic_lowering
         if hook is None:
-            raise NotImplementedError(
+            from .codegen import UnsupportedLoweringError
+
+            raise UnsupportedLoweringError(
                 f"fused_systolic: consumer spec {spec.name!r} registers "
                 "no fused_systolic_lowering hook — see docs/fusion.md")
         return hook(plan, mesh)

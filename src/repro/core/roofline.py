@@ -1,11 +1,12 @@
 """Three-term roofline analysis from compiled XLA artifacts (DESIGN.md §7).
 
-The container is CPU-only; TPU v5e is the *target*.  We therefore derive the
-roofline terms structurally from the dry-run's compiled module:
+The roofline terms are derived structurally from a compiled module, with
+the peaks of the device it targets (``PEAKS``, keyed by the
+``device_kind`` JAX reports):
 
-    compute    = HLO_FLOPs            / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes_accessed   / (chips * HBM_BW)
-    collective = collective_bytes     / (chips * ICI_BW)
+    compute    = HLO_FLOPs            / (chips * peak FLOP/s)
+    memory     = HLO_bytes_accessed   / (chips * HBM bytes/s)
+    collective = collective_bytes     / (chips * ICI bytes/s per link)
 
 ``compiled.cost_analysis()`` on an SPMD-partitioned module reports
 *per-device* flops/bytes (verified empirically: a 512-way sharded matmul
@@ -21,11 +22,42 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# --- TPU v5e hardware constants (per chip) ---------------------------------
-PEAK_FLOPS_BF16 = 197e12
-PEAK_FLOPS_INT8 = 394e12
-HBM_BW = 819e9
-ICI_BW = 50e9  # per-link; 2D torus: traffic modelled per the dominant link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind, with their source."""
+
+    bf16_flops: float   # FLOP/s
+    int8_ops: float     # OP/s
+    hbm_bw: float       # bytes/s
+    ici_bw: float       # bytes/s per link (2-D torus: the dominant link)
+    source: str
+
+
+#: ``device_kind`` as JAX reports it -> published peaks.  A device that
+#: is not listed has no roofline: ``peaks`` raises, it never defaults.
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, int8_ops=394e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interchip interconnect over 4 links
+        ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e' (system "
+               "architecture): 197 TFLOP/s bf16, 394 TOP/s int8, 16 GB "
+               "HBM at 819 GB/s, 1,600 Gbit/s ICI"),
+}
+
+#: The chip the dry run and the paper-scale tables model.
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; KeyError for a device with none listed."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            "them to core/roofline.PEAKS with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1,
@@ -142,16 +174,17 @@ def analyze(
     cost: dict,
     hlo_text: str,
     model_flops: float,
-    peak_flops: float = PEAK_FLOPS_BF16,
+    device_kind: str,
 ) -> Roofline:
+    pk = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(hlo_text)
     coll_total = float(sum(v for k, v in coll.items() if k != "_counts"))
 
-    t_comp = flops / peak_flops
-    t_mem = nbytes / HBM_BW
-    t_coll = coll_total / ICI_BW
+    t_comp = flops / pk.bf16_flops
+    t_mem = nbytes / pk.hbm_bw
+    t_coll = coll_total / pk.ici_bw
     terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
     bottleneck = max(terms, key=terms.get)  # type: ignore[arg-type]
     useful = model_flops / (flops * chips) if flops > 0 else 0.0
@@ -173,7 +206,7 @@ def analyze(
     )
 
 
-def collective_time_s(nbytes: float, link_gbps: float = ICI_BW / 1e9) -> float:
+def collective_time_s(nbytes: float, link_gbps: float) -> float:
     """Wire time for ``nbytes`` over a ``link_gbps`` GB/s interconnect —
     the outer-level term of the hierarchical combined cost model (the
     inner level keeps its PLIO model; this prices the inter-chip link)."""

@@ -3,8 +3,8 @@
     registry.py   — KernelSpec registry: the per-recurrence execution
                     contract (arity, grid loops, tile kwargs, Pallas +
                     XLA lowerings, capabilities) in one place
-    runtime.py    — plan-driven runtime: version-portable Pallas compat
-                    shim + execute_plan(plan, *operands) registry dispatch
+    runtime.py    — plan-driven runtime: the helpers every kernel shares
+                    + execute_plan(plan, *operands) registry dispatch
     systolic.py   — chip-level shard_map schedules (Cannon rings for
                     mm/bmm, halo exchange for the jacobi2d stencils, and
                     the all-gather baselines) — the KernelSpec
@@ -23,8 +23,9 @@
                     execute_plan with an XLA fallback + per-site report
     ref.py        — pure-jnp oracles (= the registry's XLA lowerings)
 
-All kernels validate in interpret=True mode on CPU; BlockSpecs are written
-for TPU VMEM/MXU geometry (see core/partition.py constants).  Adding a
+Off a TPU the kernels run in the Pallas interpreter; on a TPU they
+compile through Mosaic (``runtime.resolve_interpret``), with blocks kept
+Mosaic-legal by the staging layer (``runtime.tile``).  Adding a
 kernel = an IR builder in core/recurrence.py + one registry entry (README:
 'Adding a new recurrence').
 """

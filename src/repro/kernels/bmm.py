@@ -28,15 +28,7 @@ def bmm_kernel(a_ref, b_ref, o_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[0]
-    b = b_ref[0]
-    if jnp.issubdtype(a.dtype, jnp.integer):
-        acc_ref[...] += jnp.dot(
-            a.astype(jnp.int32), b.astype(jnp.int32),
-            preferred_element_type=jnp.int32,
-        )
-    else:
-        acc_ref[...] += jnp.dot(a, b, preferred_element_type=acc_ref.dtype)
+    acc_ref[...] += runtime.mxu_dot(a_ref[0], b_ref[0], acc_ref.dtype)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():

@@ -10,11 +10,14 @@ so the convolution becomes the uniform MM recurrence
 
     O[h, w] = sum_s  F_flat[s] * S[s, h, w]
 
-executed on the MXU as a (1 x PQ) @ (PQ x HW-tile) contraction per output
-block — the same systolic mapping the paper derives (conv's reduction loops
-p,q are the time loops; h,w are the space loops).  The kernel below consumes
-the stack with disjoint MXU-aligned blocks (no halo reads inside the
-kernel, exactly like AIE cores that only see DMA-fed local buffers).
+— the same systolic mapping the paper derives (conv's reduction loops
+p,q are the time loops; h,w are the space loops).  The kernel below
+consumes the stack with disjoint (sublane, lane)-aligned blocks (no halo
+reads inside the kernel, exactly like AIE cores that only see DMA-fed
+local buffers) and sums scalar x plane on the vector unit, with the PQ
+filter taps in SMEM: a rank-1 contraction has no MXU shape, and the VPU
+path is the same for every dtype (int8/int16 planes widen to int32 lanes
+in-register).
 """
 
 from __future__ import annotations
@@ -29,27 +32,22 @@ from jax.experimental.pallas import tpu as pltpu
 from . import runtime
 
 
-def conv_kernel(s_ref, f_ref, o_ref, acc_ref):
-    """s_ref: (S, bh, bw) window stack block; f_ref: (S,) filter taps."""
+def conv_kernel(f_ref, s_ref, o_ref, acc_ref):
+    """f_ref: (S,) filter taps in SMEM (accumulator dtype); s_ref:
+    (bs, bh, bw) window stack block."""
+    l = pl.program_id(2)
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(l == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    s = s_ref[...]
-    f = f_ref[...]
-    if jnp.issubdtype(s.dtype, jnp.integer):
-        s32 = s.astype(jnp.int32)
-        f32 = f.astype(jnp.int32)
-        acc_ref[...] += jnp.einsum(
-            "shw,s->hw", s32, f32, preferred_element_type=jnp.int32
-        )
-    else:
-        acc_ref[...] += jnp.einsum(
-            "shw,s->hw", s, f, preferred_element_type=jnp.float32
-        )
+    bs = s_ref.shape[0]
+    acc = acc_ref[...]
+    for s in range(bs):
+        acc = acc + f_ref[l * bs + s] * s_ref[s].astype(acc.dtype)
+    acc_ref[...] = acc
 
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    @pl.when(l == pl.num_programs(2) - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -89,8 +87,8 @@ def conv2d_stacked(
         conv_kernel,
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bs, bh, bw), lambda i, j, l: (l, i, j)),
-            pl.BlockSpec((bs,), lambda i, j, l: (l,)),
         ],
         out_specs=pl.BlockSpec((bh, bw), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), out_dtype),
@@ -101,4 +99,4 @@ def conv2d_stacked(
                 dimension_semantics or ("parallel", "parallel", "arbitrary")
             ),
         ),
-    )(stack, filt_flat)
+    )(filt_flat.astype(acc_dtype), stack)

@@ -21,14 +21,24 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import runtime
 from .widesa_mm import matmul as mm
 
 
-def dft_matrix(n: int, dtype=np.float32) -> tuple[np.ndarray, np.ndarray]:
-    """Real/imag planes of the n-point DFT matrix."""
-    k = np.arange(n)
-    ang = -2.0 * np.pi * np.outer(k, k) / n
-    return np.cos(ang).astype(dtype), np.sin(ang).astype(dtype)
+def dft_matrix(n: int) -> tuple[jax.Array, jax.Array]:
+    """Real/imag float32 planes of the n-point DFT matrix.
+
+    Built with jnp, so under jit the planes are computed on the device
+    instead of entering the program as an n x n constant.  The phase
+    index ``k*j mod n`` is exact in int32, which keeps the float32
+    angles accurate to an ulp of 2*pi.
+    """
+    if n * n >= 2**31:
+        raise ValueError(f"{n}-point DFT phase index overflows int32")
+    k = jnp.arange(n, dtype=jnp.int32)
+    ang = ((k[:, None] * k[None, :]) % n).astype(jnp.float32) * (
+        -2.0 * np.pi / n)
+    return jnp.cos(ang), jnp.sin(ang)
 
 
 def _cmul_mm(ar, ai, br, bi, *, three_mult: bool, bm, bn, bk, interpret,
@@ -63,21 +73,26 @@ def fft2d(
 ) -> tuple[jax.Array, jax.Array]:
     """2-D DFT of a (R, C) complex grid held as two real planes."""
     r, c = x_re.shape
+    # stage 1 is (r,r)@(r,c), stage 2 (r,c)@(c,c): the tiles divide the
+    # extents exactly (the DFT planes are not padded), each stage with its
+    # own contraction tile
+    bm = runtime.divisor_tile(r, bm, runtime.sublanes(x_re.dtype))
+    bn = runtime.divisor_tile(c, bn, runtime.MXU_LANES)
+    bk1 = runtime.divisor_tile(r, bk, runtime.MXU_LANES)
+    bk2 = runtime.divisor_tile(c, bk, runtime.MXU_LANES)
     fr_re, fr_im = dft_matrix(r)
     fc_re, fc_im = dft_matrix(c)
-    fr_re, fr_im = jnp.asarray(fr_re), jnp.asarray(fr_im)
-    fc_re, fc_im = jnp.asarray(fc_re), jnp.asarray(fc_im)
 
     # stage 1: rows — Y = F_R @ X
     y_re, y_im = _cmul_mm(
         fr_re, fr_im, x_re, x_im,
-        three_mult=three_mult, bm=bm, bn=bn, bk=bk, interpret=interpret,
+        three_mult=three_mult, bm=bm, bn=bn, bk=bk1, interpret=interpret,
         dimension_semantics=dimension_semantics,
     )
     # stage 2: cols — Z = Y @ F_C
     z_re, z_im = _cmul_mm(
         y_re, y_im, fc_re, fc_im,
-        three_mult=three_mult, bm=bm, bn=bn, bk=bk, interpret=interpret,
+        three_mult=three_mult, bm=bm, bn=bn, bk=bk2, interpret=interpret,
         dimension_semantics=dimension_semantics,
     )
     return z_re, z_im

@@ -5,7 +5,8 @@ a generic window contraction whose reduction loop rides a third grid
 dimension with a VMEM accumulator.  The stencil does not need any of
 that: the star has a fixed 5 planes that always fit one block, so the
 kernel below contracts them in a single grid visit per output tile
-(grid = (i, j), both "parallel"; no scratch, no revisits).  The staging
+(grid = (i, j), both "parallel"; no scratch, no revisits), as a sum of
+scalar x plane on the vector unit with the star weights in SMEM.  The staging
 layer (ops.jacobi2d / ops.jacobi2d_ms) still builds the shifted-point
 stack
 
@@ -22,27 +23,20 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import runtime
 
 
-def jacobi_kernel(s_ref, w_ref, o_ref):
-    """One (bh, bw) output tile: o = sum_s w[s] * stack[s] (all 5 planes
-    resident — single visit, no accumulator scratch)."""
-    s = s_ref[...]
-    w = w_ref[...]
-    if jnp.issubdtype(s.dtype, jnp.integer):
-        out = jnp.einsum(
-            "shw,s->hw", s.astype(jnp.int32), w.astype(jnp.int32),
-            preferred_element_type=jnp.int32,
-        )
-    else:
-        out = jnp.einsum(
-            "shw,s->hw", s, w, preferred_element_type=jnp.float32
-        )
-    o_ref[...] = out.astype(o_ref.dtype)
+def jacobi_kernel(w_ref, s_ref, o_ref):
+    """One (bh, bw) output tile: o = sum_s w[s] * stack[s] (all planes
+    resident — single visit, no accumulator scratch).  ``w_ref`` holds
+    the weights in SMEM in the accumulator dtype."""
+    acc = w_ref[0] * s_ref[0].astype(w_ref.dtype)
+    for s in range(1, s_ref.shape[0]):
+        acc = acc + w_ref[s] * s_ref[s].astype(w_ref.dtype)
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -75,8 +69,8 @@ def jacobi2d_stacked(
         jacobi_kernel,
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((s, bh, bw), lambda i, j: (0, i, j)),
-            pl.BlockSpec((s,), lambda i, j: (0,)),
         ],
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), out_dtype),
@@ -86,4 +80,4 @@ def jacobi2d_stacked(
                 dimension_semantics or ("parallel", "parallel")
             ),
         ),
-    )(stack, weights)
+    )(weights.astype(runtime.acc_dtype(stack.dtype)), stack)

@@ -1,7 +1,9 @@
 """Public jit'd wrappers for the WideSA kernels.
 
 Each wrapper owns the staging-layer data movement (the paper's PL DMA
-module, §IV): padding to tile multiples, shifted-window stacking for
+module, §IV): Mosaic-legal tiles (``runtime.tile``: a requested block
+extent rounds up to the whole dim or a multiple of the (sublane, lane)
+tile) with padding to tile multiples, shifted-window stacking for
 conv/fir, and complex lowering for FFT/complex FIR.  Model code calls these
 (`use_pallas=True` paths); the dry-run uses the XLA path since Mosaic only
 lowers on TPU targets — ``interpret=None`` resolves through
@@ -14,7 +16,6 @@ which derives the tile/semantics kwargs below from a mapper ExecutionPlan.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -27,15 +28,19 @@ from . import fir as _fir
 from . import fft2d as _fft
 from . import jacobi2d as _jacobi
 from . import mttkrp as _mttkrp
+from . import runtime
 from . import widesa_mm as _mm
+from .runtime import MXU_LANES
 
 
-def _div_tile(n: int, tile: int) -> int:
-    """Largest divisor of ``n`` that is <= ``tile`` (exact-grid tiles)."""
-    tile = max(1, min(tile, n))
-    while n % tile:
-        tile -= 1
-    return tile
+def _lane(ext: int, req: int) -> int:
+    """Legal block extent of a minor (lane) dim."""
+    return runtime.tile(ext, req, MXU_LANES)
+
+
+def _sub(ext: int, req: int, dtype) -> int:
+    """Legal block extent of a second-minor (sublane) dim."""
+    return runtime.tile(ext, req, runtime.sublanes(dtype))
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
@@ -60,7 +65,7 @@ def matmul(
     """C = A @ B with automatic padding to the plan tiles."""
     m, k = a.shape
     _, n = b.shape
-    bm_, bn_, bk_ = min(bm, m) or 1, min(bn, n) or 1, min(bk, k) or 1
+    bm_, bn_, bk_ = _sub(m, bm, a.dtype), _lane(n, bn), _lane(k, bk)
     ap = _pad_to(a, (bm_, bk_))
     bp = _pad_to(b, (bk_, bn_))
     out = _mm.matmul(ap, bp, bm=bm_, bn=bn_, bk=bk_, interpret=interpret,
@@ -82,7 +87,7 @@ def bmm(
     """C[b] = A[b] @ B[b] per batch, with automatic padding to the tiles."""
     nb, m, k = a.shape
     _, _, n = b.shape
-    bm_, bn_, bk_ = min(bm, m) or 1, min(bn, n) or 1, min(bk, k) or 1
+    bm_, bn_, bk_ = _sub(m, bm, a.dtype), _lane(n, bn), _lane(k, bk)
     ap = _pad_to(a, (1, bm_, bk_))
     bp = _pad_to(b, (1, bk_, bn_))
     out = _bmm.bmm(ap, bp, bm=bm_, bn=bn_, bk=bk_, interpret=interpret,
@@ -122,7 +127,7 @@ def _star2d(
     stack = jnp.stack(
         [grid[di : di + oh, dj : dj + ow] for di, dj in offsets]
     )  # (S, oh, ow)
-    bh_, bw_ = min(bh, oh) or 1, min(bw, ow) or 1
+    bh_, bw_ = _sub(oh, bh, grid.dtype), _lane(ow, bw)
     stack = _pad_to(stack, (1, bh_, bw_))
     out = _jacobi.jacobi2d_stacked(
         stack, weights, bh=bh_, bw=bw_, interpret=interpret,
@@ -225,9 +230,9 @@ def mttkrp(
     """
     ni, nk, nl = x.shape
     _, nj = b.shape
-    bi_, bj_ = min(bi, ni) or 1, min(bj, nj) or 1
-    bk_, bl_ = min(bk, nk) or 1, min(bl, nl) or 1
-    xp = _pad_to(x, (bi_, bk_, bl_))
+    bi_, bj_ = _sub(ni, bi, x.dtype), _lane(nj, bj)
+    bk_, bl_ = _sub(nk, bk, x.dtype), _lane(nl, bl)
+    xp = _pad_to(x.transpose(1, 0, 2), (bk_, bi_, bl_))  # k-major
     bp = _pad_to(b, (bk_, bj_))
     cp = _pad_to(c, (bl_, bj_))
     out = _mttkrp.mttkrp(xp, bp, cp, bi=bi_, bj=bj_, bk=bk_, bl=bl_,
@@ -252,7 +257,7 @@ def conv2d(
     stack = jnp.stack(
         [img[i : i + oh, j : j + ow] for i in range(p) for j in range(q)]
     )  # (p*q, oh, ow)
-    bh_, bw_ = min(bh, oh), min(bw, ow)
+    bh_, bw_ = _sub(oh, bh, img.dtype), _lane(ow, bw)
     stack = _pad_to(stack, (1, bh_, bw_))
     out = _conv.conv2d_stacked(
         stack, filt.reshape(-1), bh=bh_, bw=bw_, interpret=interpret,
@@ -273,7 +278,7 @@ def fir(
     t = taps.shape[0]
     n_out = x.shape[0] - t + 1
     stack = jnp.stack([x[i : i + n_out] for i in range(t)])  # (t, n_out)
-    bn_ = min(bn, n_out)
+    bn_ = _lane(n_out, bn)
     stack = _pad_to(stack, (1, bn_))
     out = _fir.fir_stacked(stack, taps, bn=bn_, interpret=interpret,
                            dimension_semantics=dimension_semantics)
@@ -303,16 +308,9 @@ def fft2d(
     interpret: bool | None = None,
     dimension_semantics: tuple[str, ...] | None = None,
 ):
-    r, c = x_re.shape
-    # Both DFT stages run with the same tiles: stage 1 is (r,r)@(r,c) and
-    # stage 2 is (r,c)@(c,c), so bm must divide r, bn must divide c, and
-    # bk must divide BOTH contraction extents (r and c) — hence gcd.
-    bm_ = _div_tile(r, bm)
-    bn_ = _div_tile(c, bn)
-    bk_ = _div_tile(math.gcd(r, c), bk)
     return _fft.fft2d(
         x_re, x_im,
-        bm=bm_, bn=bn_, bk=bk_,
+        bm=bm, bn=bn, bk=bk,
         three_mult=three_mult, interpret=interpret,
         dimension_semantics=dimension_semantics,
     )

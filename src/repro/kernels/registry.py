@@ -14,7 +14,8 @@ of the mapper need:
     xla            the XLA reference lowering (a ref.py oracle)
     builder        the IR builder in core/recurrence.py
     operands       (recurrence, rng) -> sample operands matching its
-                   extents (tests / benches / smoke all draw from here)
+                   extents (tests / benches / smoke all draw from here);
+                   ``rng`` is a numpy Generator or a ``DeviceRng``
     systolic_lowering
                    chip-level neighbour-stream schedule hook,
                    ``(plan, mesh) -> Callable(*operands)`` — the
@@ -58,6 +59,7 @@ import dataclasses
 from typing import TYPE_CHECKING, Any, Callable
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core import recurrence as ir
@@ -160,6 +162,28 @@ def _ops(fname: str) -> Callable[..., Any]:
         return getattr(ops, fname)(*a, **kw)
 
     return call
+
+
+class DeviceRng:
+    """The two numpy ``Generator`` draws ``KernelSpec.operands`` makes,
+    made on the device from one PRNG key instead — traceable, so a
+    jitted ``spec.operands(rec, DeviceRng(key))`` builds bench-size
+    operands in device memory (and ``jax.eval_shape`` of it gives their
+    shapes without allocating)."""
+
+    def __init__(self, key):
+        self._key = key
+
+    def _next(self):
+        self._key, key = jax.random.split(self._key)
+        return key
+
+    def integers(self, low: int, high: int, shape):
+        return jax.random.randint(self._next(), tuple(shape), low, high,
+                                  dtype=jnp.int32)
+
+    def standard_normal(self, shape):
+        return jax.random.normal(self._next(), tuple(shape), jnp.float32)
 
 
 def _draw(rng, shape, dtype: str):

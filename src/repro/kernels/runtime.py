@@ -2,15 +2,16 @@
 
 Two jobs:
 
-1. **Version-portable Pallas compat shim.**  ``compiler_params(...)``
-   resolves the moving ``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams``
-   name (renamed across jax releases) and filters kwargs the installed
-   class does not know, so kernels never touch ``pltpu`` spelling directly.
-   ``resolve_interpret`` centralizes the interpret-mode fallback: Mosaic
-   only lowers on real TPU backends, so on CPU/GPU every kernel runs under
-   ``interpret=True`` unless the caller forces otherwise.  The dtype
-   packing ladder is shared with ``core/partition`` (one source of truth
-   for DTYPE_BYTES/PACKING between the cost model and the runtime).
+1. **What every kernel shares.**  ``compiler_params(...)`` builds the
+   Pallas TPU compiler params (an unknown kwarg is an error);
+   ``resolve_interpret`` decides interpret mode from the backend alone
+   (Mosaic compiles the kernels on a TPU; everywhere else they run in the
+   Pallas interpreter, and on a TPU the interpreter is refused);
+   ``tile`` makes a requested block extent Mosaic-legal; ``mxu_dot`` is
+   the one block contraction every MXU kernel uses, with the integer
+   paths the MXU takes.  The dtype packing ladder is shared with
+   ``core/partition`` (one source of truth for DTYPE_BYTES/PACKING
+   between the cost model and the runtime).
 
 2. **``execute_plan(plan, *operands)``.**  A single entry point that takes
    a ``mapper.ExecutionPlan``, looks up the recurrence's ``KernelSpec`` in
@@ -34,11 +35,11 @@ across every ``lower_plan`` backend.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import TYPE_CHECKING
 
 import jax
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.partition import (  # noqa: F401  (re-exported ladder)
@@ -46,6 +47,7 @@ from repro.core.partition import (  # noqa: F401  (re-exported ladder)
     MXU_LANES,
     PACKING,
     PACKING_TPU,
+    SUBLANES,
 )
 
 if TYPE_CHECKING:  # pragma: no cover - typing only, avoids import cycles
@@ -54,62 +56,116 @@ if TYPE_CHECKING:  # pragma: no cover - typing only, avoids import cycles
 
 
 # ---------------------------------------------------------------------------
-# compat shim: compiler params + interpret fallback
+# compiler params, interpret mode, Mosaic-legal tiles, the MXU contraction
 # ---------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=1)
-def _compiler_params_cls():
-    """The installed Pallas TPU compiler-params class, newest name first."""
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
-def compiler_params(*, dimension_semantics=None, **kwargs):
-    """Build Pallas TPU compiler params portably.
-
-    Unknown kwargs (perf hints a given jax release lacks) are dropped
-    rather than erroring, so kernels can request e.g. vmem limits without
-    pinning a jax version.  ``dimension_semantics`` is the exception: it
-    changes kernel *correctness* (reduction grid dims must stay
-    "arbitrary"), so a params class that cannot carry it is an error, not
-    a silent drop.  Returns None when no params class exists —
-    ``pl.pallas_call`` accepts ``compiler_params=None``.
-    """
-    cls = _compiler_params_cls()
-    if cls is None:  # pragma: no cover - jax too old/new to have either name
-        return None
-    known = {f.name for f in dataclasses.fields(cls)}
-    if dimension_semantics is not None:
-        if "dimension_semantics" not in known:  # pragma: no cover
-            raise RuntimeError(
-                f"{cls.__name__} does not accept dimension_semantics; "
-                "refusing to drop a correctness-critical parameter — "
-                "update kernels/runtime.py for this jax version")
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**{k: v for k, v in kwargs.items() if k in known})
+def compiler_params(*, dimension_semantics, **kwargs):
+    """``pltpu.CompilerParams`` for one kernel.  ``dimension_semantics``
+    is required: reduction grid dims must stay "arbitrary"."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics), **kwargs)
 
 
 @functools.lru_cache(maxsize=1)
 def default_interpret() -> bool:
-    """True unless a real TPU backend is attached (Mosaic lowers TPU-only)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except RuntimeError:  # pragma: no cover - no backend at all
-        return True
+    """True unless a TPU backend is attached (Mosaic compiles TPU-only)."""
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """None -> backend-appropriate default; explicit bool wins."""
-    return default_interpret() if interpret is None else bool(interpret)
+    """Interpret mode for one kernel call.  None follows the backend.
+    ``False`` off a TPU compiles through Mosaic for a described chip
+    (the compile-only checks); ``True`` on a TPU is refused, so no
+    kernel on the chip ever runs in the interpreter."""
+    if interpret is None:
+        return default_interpret()
+    if interpret and not default_interpret():
+        raise ValueError(
+            "interpret=True on a TPU backend: kernels on the chip compile "
+            "through Mosaic")
+    return bool(interpret)
+
+
+def tile(ext: int, req: int, align: int) -> int:
+    """Mosaic-legal block extent for an array dim of size ``ext``.
+
+    A block dim is legal when it is the whole dim or a multiple of the
+    hardware tile (``align``: MXU_LANES on the minor dim, the dtype's
+    sublane count on the second-minor one).  The request rounds up to
+    the next legal extent; the staging layer (ops.py) pads the array to
+    a multiple of the result.
+    """
+    t = -(-max(int(req), 1) // align) * align
+    return ext if t >= ext else t
+
+
+def divisor_tile(ext: int, req: int, align: int) -> int:
+    """Mosaic-legal block extent that divides ``ext`` exactly: the
+    largest multiple of ``align`` not above ``max(req, align)`` that
+    divides ``ext``, else the whole dim (for operands that are not
+    padded)."""
+    t = max(int(req), align) // align * align
+    while t >= align:
+        if t < ext and ext % t == 0:
+            return t
+        t -= align
+    return ext
+
+
+def sublanes(dtype) -> int:
+    """Second-minor tile of ``dtype``: 8 rows of 32-bit words, packed
+    2x for 16-bit and 4x for 8-bit types."""
+    return SUBLANES * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _int8_limbs(x) -> tuple:
+    """An integer block as int8 limbs of 7 bits, the top one signed:
+    ``x == sum_p limb[p] * 2**(7*p)``.  int8 is its own limb; int16
+    splits into three (the top in [-2, 1]), int32 into five (the top in
+    [-8, 7])."""
+    if x.dtype == jnp.int8:
+        return (x,)
+    n = {2: 3, 4: 5}[x.dtype.itemsize]
+    w = x.astype(jnp.int32)
+    limbs = [((w >> (7 * p)) & 127).astype(jnp.int8) for p in range(n - 1)]
+    return (*limbs, (w >> (7 * (n - 1))).astype(jnp.int8))
+
+
+def mxu_dot(a, b, acc=None):
+    """``a @ b`` for 2-D blocks, accumulated in ``acc_dtype(a.dtype)``,
+    in forms the MXU takes.
+
+    Floats go in as they are (float32 at HIGHEST precision, so the MXU
+    does not round products to bf16).  int8 goes in as int8 with an int32
+    accumulator.  The MXU has no int16 or int32 path: wider integer
+    operands split into int8 limbs (``_int8_limbs``) and the limb
+    products are summed with their shifts in int32, dropping the ones
+    shifted past bit 31.  Every step is ring arithmetic, so the result
+    equals the widened int32 dot modulo 2**32 — bit-exact against
+    ``ref.py``.  An int16 x int16 block costs nine int8 passes.
+    """
+    if not jnp.issubdtype(a.dtype, jnp.integer):
+        # float32 operands keep float32 products: no single bf16 pass
+        precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                     else None)
+        return jnp.dot(a, b, precision=precision, preferred_element_type=(
+            jnp.float32 if acc is None else acc))
+    if a.dtype == b.dtype == jnp.int8:
+        return jnp.dot(a, b, preferred_element_type=jnp.int32)
+    out = None
+    for p, ap in enumerate(_int8_limbs(a)):
+        for q, bq in enumerate(_int8_limbs(b)):
+            shift = 7 * (p + q)
+            if shift >= 32:
+                continue
+            part = jnp.dot(ap, bq, preferred_element_type=jnp.int32)
+            part = part * (1 << shift)
+            out = part if out is None else out + part
+    return out
 
 
 def acc_dtype(dtype):
     """Accumulator dtype ladder: integer inputs -> int32, else float32."""
-    import jax.numpy as jnp
-
     if jnp.issubdtype(jnp.dtype(dtype), jnp.integer):
         return jnp.int32
     return jnp.float32
@@ -117,8 +173,6 @@ def acc_dtype(dtype):
 
 def out_dtype(dtype):
     """Default output dtype: int accumulations widen to int32."""
-    import jax.numpy as jnp
-
     if jnp.issubdtype(jnp.dtype(dtype), jnp.integer):
         return jnp.int32
     return jnp.dtype(dtype)
@@ -180,9 +234,9 @@ def execute_plan(plan: "ExecutionPlan", *operands,
     ``(x[i,k,l], b[k,j], c[l,j])``).
 
     Block shapes, grid and dimension semantics come from the plan; the
-    staging-layer data movement (padding, window stacking, complex
-    lowering) is ops.py's, unchanged.  ``interpret=None`` resolves to the
-    backend default (interpret off TPU).  ``out_dtype`` (kernels that
+    staging-layer data movement (padding to Mosaic-legal tiles, window
+    stacking, complex lowering) is ops.py's.  ``interpret`` resolves
+    through ``resolve_interpret``.  ``out_dtype`` (kernels that
     support it, e.g. mm/bmm) requests the accumulator flush dtype — the
     MXU-native way to get fp32 results from low-precision operands
     without materializing upcast inputs.

@@ -78,6 +78,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map as _shard_map
+from repro.core.codegen import UnsupportedLoweringError
 from repro.core.recurrence import stencil_star
 
 from . import runtime
@@ -96,7 +97,7 @@ def _space_axes(plan: "ExecutionPlan") -> tuple[str, str]:
 
 def _require_divisible(what: str, extent: int, width: int, axis: str):
     if extent % width:
-        raise ValueError(
+        raise UnsupportedLoweringError(
             f"{what}: extent {extent} does not divide over the {width}-wide "
             f"mesh axis {axis!r} — pad the operand or pick a mesh whose "
             "axis widths divide the space extents")
@@ -106,7 +107,7 @@ def _require_square(plan: "ExecutionPlan", mesh, what: str) -> tuple:
     ax0, ax1 = _space_axes(plan)
     n0, n1 = mesh.shape[ax0], mesh.shape[ax1]
     if n0 != n1:
-        raise ValueError(
+        raise UnsupportedLoweringError(
             f"{what} needs a square space array, got {ax0}={n0} x "
             f"{ax1}={n1}")
     return ax0, ax1, n0
@@ -265,8 +266,8 @@ def cannon_fft2d(plan: "ExecutionPlan", mesh) -> Callable:
         r, c = x_re.shape
         _require_divisible("fft2d rows", r, steps, ax0)
         _require_divisible("fft2d cols", c, steps, ax1)
-        fr_re, fr_im = (jnp.asarray(m) for m in dft_matrix(r))
-        fc_re, fc_im = (jnp.asarray(m) for m in dft_matrix(c))
+        fr_re, fr_im = dft_matrix(r)
+        fc_re, fc_im = dft_matrix(c)
         y_re, y_im = cfn(fr_re, fr_im, x_re, x_im)    # stage 1: F_R @ X
         return cfn(y_re, y_im, fc_re, fc_im)          # stage 2: Y @ F_C
 
@@ -282,14 +283,14 @@ def _star_of(plan: "ExecutionPlan") -> tuple[tuple[tuple[int, int], ...], int]:
     functions — the IR, not the kernel, declares the halo width."""
     star = stencil_star(plan.recurrence)
     if star is None:
-        raise ValueError(
+        raise UnsupportedLoweringError(
             f"halo_stencil: recurrence {plan.recurrence.name!r} carries no "
             "multi-point read access — not a stencil")
     radius = 0
     for off in star:
         di, dj = off[0], off[1] if len(off) > 1 else 0
         if di and dj:
-            raise ValueError(
+            raise UnsupportedLoweringError(
                 "halo_stencil handles star stencils only (no diagonal "
                 f"points / corner halos), got offset {off}")
         radius = max(radius, abs(di), abs(dj))
@@ -387,7 +388,7 @@ def halo_stencil(plan: "ExecutionPlan", mesh) -> Callable:
         _require_divisible("stencil interior rows", h, n0, ax0)
         _require_divisible("stencil interior cols", w, n1, ax1)
         if r > h // n0 or r > w // n1:
-            raise ValueError(
+            raise UnsupportedLoweringError(
                 f"halo radius {r} exceeds the {h // n0}x{w // n1} shard — "
                 "a one-hop exchange can only import the adjacent shard; "
                 "use fewer chips or a larger grid")
@@ -476,7 +477,7 @@ def chain_conv2d(plan: "ExecutionPlan", mesh) -> Callable:
         h = img.shape[0] - p + 1
         _require_divisible("conv2d output rows", h, width, "+".join(axes))
         if p - 1 > h // width:
-            raise ValueError(
+            raise UnsupportedLoweringError(
                 f"window height {p} exceeds the {h // width}-row shard — "
                 "the width-(p-1) halo must come from the adjacent shard "
                 "(one hop); use fewer chips or larger images")
@@ -522,7 +523,7 @@ def chain_fir(plan: "ExecutionPlan", mesh) -> Callable:
         n_out = x.shape[0] - t + 1
         _require_divisible("fir outputs", n_out, width, "+".join(axes))
         if t - 1 > n_out // width:
-            raise ValueError(
+            raise UnsupportedLoweringError(
                 f"tap count {t} exceeds the {n_out // width}-sample shard "
                 "— the width-(t-1) halo must come from the adjacent shard "
                 "(one hop); use fewer chips or longer signals")
@@ -701,7 +702,7 @@ def fused_halo_chain(fused_plan, mesh) -> Callable:
         _require_divisible("fused chain output rows", hf, n0, ax0)
         _require_divisible("fused chain output cols", wf, n1, ax1)
         if (n0 > 1 and s_h > hf // n0) or (n1 > 1 and s_w > wf // n1):
-            raise ValueError(
+            raise UnsupportedLoweringError(
                 f"fused deep halo {s_h}x{s_w} exceeds the "
                 f"{hf // n0}x{wf // n1} shard — a one-hop exchange can "
                 "only import the adjacent shard; use fewer chips or a "
@@ -855,8 +856,8 @@ def fused_cannon_fft2d(fused_plan, mesh) -> Callable:
         r, c = x_re.shape
         _require_divisible("fused fft2d rows", r, steps, ax0)
         _require_divisible("fused fft2d cols", c, steps, ax1)
-        fr_re, fr_im = (jnp.asarray(m) for m in dft_matrix(r))
-        fc_re, fc_im = (jnp.asarray(m) for m in dft_matrix(c))
+        fr_re, fr_im = dft_matrix(r)
+        fc_re, fc_im = dft_matrix(c)
         return fn(fr_re, fr_im, x_re, x_im, fc_re, fc_im)
 
     return run

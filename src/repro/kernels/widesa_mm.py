@@ -10,8 +10,9 @@ Grid layout: (i, j, k) with k innermost ("arbitrary" — it revisits the same
 output block).  Mosaic double-buffers the A/B input blocks automatically
 (multiple-buffering == the paper's DMA ping-pong).
 
-Supported dtypes (paper Table II): float32, bfloat16 (accum f32), int8,
-int16 (accum int32).
+Supported dtypes (paper Table II): float32, bfloat16 (accum f32), int8
+(native MXU, accum int32), int16 (three int8 limbs per operand — see
+``runtime.mxu_dot``; accum int32).
 """
 
 from __future__ import annotations
@@ -25,8 +26,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import runtime
 
-_acc_dtype = runtime.acc_dtype
-
 
 def mm_kernel(a_ref, b_ref, o_ref, acc_ref):
     """One (N0, M0) output tile; K streams through the k grid dim."""
@@ -35,18 +34,7 @@ def mm_kernel(a_ref, b_ref, o_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]
-    b = b_ref[...]
-    acc_t = acc_ref.dtype
-    if jnp.issubdtype(a.dtype, jnp.integer):
-        # MXU int path: widen to int32 lanes (int8/int16 packed natively on
-        # real hardware; widening keeps interpret-mode exact)
-        acc_ref[...] += jnp.dot(
-            a.astype(jnp.int32), b.astype(jnp.int32),
-            preferred_element_type=jnp.int32,
-        )
-    else:
-        acc_ref[...] += jnp.dot(a, b, preferred_element_type=acc_t)
+    acc_ref[...] += runtime.mxu_dot(a_ref[...], b_ref[...], acc_ref.dtype)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():
@@ -72,8 +60,8 @@ def matmul(
 ) -> jax.Array:
     """C[m,n] = A[m,k] @ B[k,n] with WideSA plan tiles.
 
-    Shapes must be divisible by the tiles (the mapper guarantees this via
-    divisor-exact block selection; ops.matmul pads otherwise).  Tile sizes
+    Shapes must be divisible by the tiles, and the tiles Mosaic-legal
+    (ops.matmul legalizes and pads).  Tile sizes
     and ``dimension_semantics`` normally come from an ExecutionPlan via
     ``runtime.execute_plan``; the defaults reproduce the plan the mapper
     picks for MXU-aligned MM.
@@ -84,9 +72,8 @@ def matmul(
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (
         (m, n, k), (bm, bn, bk))
     if out_dtype is None:
-        out_dtype = _acc_dtype(a.dtype) if jnp.issubdtype(
-            a.dtype, jnp.integer) else a.dtype
-    acc_dtype = _acc_dtype(a.dtype)
+        out_dtype = runtime.out_dtype(a.dtype)
+    acc_dtype = runtime.acc_dtype(a.dtype)
 
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(

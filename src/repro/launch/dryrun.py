@@ -170,8 +170,7 @@ def _lower_one(cfg, shape, mesh, ctx, api):
 
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    from repro.compat import cost_analysis
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = RL.collective_bytes(hlo)
     mem_d = {

@@ -28,6 +28,7 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import SHAPES, get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.configs.base import ShapeSpec
     from repro.train import Trainer, TrainConfig
 
@@ -38,6 +39,7 @@ def main():
         cfg = get_config(args.arch)
         shape = SHAPES[args.shape]
 
+    enable_compile_cache()
     mesh = None
     multi_pod = args.mesh == "multi"
     if args.mesh != "none":

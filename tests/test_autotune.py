@@ -392,3 +392,34 @@ def test_machine_factor_normalizes_by_suite_median():
     fresh = {"a": 20.0, "b": 200.0, "c": 80.0, "unshared": 1.0}
     assert autotune.machine_factor(table, fresh) == pytest.approx(2.0)
     assert autotune.machine_factor(table, {"unshared": 1.0}) == 1.0
+
+
+@pytest.mark.parametrize("err,skipped", [
+    ("unsupported", True),    # this backend cannot lower the recurrence
+    ("compile", False),       # a compile error of an offered backend
+])
+def test_race_skips_only_unsupported_lowerings(monkeypatch, err, skipped):
+    """A backend that cannot lower the recurrence leaves the race with a
+    warning; any other failure of a backend the process offers
+    propagates instead of being raced around."""
+    from repro.core import codegen
+
+    real = codegen.lower_plan
+
+    def lower(plan, backend="pallas", **kw):
+        if backend == "pallas":
+            if err == "unsupported":
+                raise codegen.UnsupportedLoweringError("no pallas here")
+            raise RuntimeError("Mosaic refused the kernel")
+        return real(plan, backend=backend, **kw)
+
+    monkeypatch.setattr(codegen, "lower_plan", lower)
+    policy = PlanPolicy(mode="measured", reps=1, warmup=0)
+    rec = _smoke_rec()
+    if skipped:
+        with pytest.warns(UserWarning, match="pallas skipped for mm"):
+            res = autotune.race(rec, SINGLE, policy)
+        assert "pallas" not in res["us"] and "xla" in res["us"]
+    else:
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            autotune.race(rec, SINGLE, policy)

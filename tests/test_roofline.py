@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core import roofline as RL
 
@@ -63,7 +64,8 @@ def test_roofline_terms_and_bottleneck():
     r = RL.analyze(
         arch="a", shape="s", mesh_name="16x16", chips=256,
         cost={"flops": 197e12, "bytes accessed": 819e9 * 2},
-        hlo_text="", model_flops=197e12 * 256 * 0.5)
+        hlo_text="", model_flops=197e12 * 256 * 0.5,
+        device_kind=RL.V5E)
     assert abs(r.t_compute - 1.0) < 1e-6
     assert abs(r.t_memory - 2.0) < 1e-6
     assert r.bottleneck == "memory"
@@ -98,3 +100,11 @@ def test_probe_configs_layer_counts():
     small, big, _, scaling = probe_configs(cfg)
     assert small.n_layers == 8 and big.n_layers == 14  # seg(6)+rem(2)
     assert scaling == 5
+
+
+def test_peaks_keyed_by_device_kind():
+    pk = RL.peaks("TPU v5 lite")
+    assert (pk.bf16_flops, pk.int8_ops, pk.hbm_bw) == (197e12, 394e12, 819e9)
+    assert "TPU v5e" in pk.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        RL.peaks("cpu")

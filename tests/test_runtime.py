@@ -1,5 +1,7 @@
 """Plan-driven runtime tests: execute_plan dispatch vs the jnp oracles,
-the mapper's LRU plan cache, and the version-portable compat shims."""
+the mapper's LRU plan cache, and the helpers every kernel shares
+(compiler params, interpret resolution, Mosaic-legal tiles, the MXU
+contraction)."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -74,18 +76,6 @@ def test_execute_plan_fft2d_nonsquare():
                                atol=1.0, rtol=1e-3)
 
 
-def test_compat_make_mesh_without_jax_make_mesh(monkeypatch):
-    """compat.make_mesh must work on releases lacking jax.make_mesh."""
-    import jax
-
-    from repro import compat
-
-    monkeypatch.delattr(jax, "make_mesh", raising=False)
-    mesh = compat.make_mesh((1,), ("d",))
-    assert mesh.axis_names == ("d",)
-    assert mesh.shape["d"] == 1
-
-
 def test_execute_plan_fft2d():
     xr, xi = _mk((32, 32), "float32"), _mk((32, 32), "float32")
     plan = best_plan(fft2d_stage(32, 32), CHIP)
@@ -141,11 +131,74 @@ def test_packing_ladder_shared_with_partition():
 
 def test_compiler_params_portable():
     params = runtime.compiler_params(
-        dimension_semantics=("parallel", "arbitrary"),
-        not_a_real_compiler_knob=1,  # unknown kwargs must be dropped
-    )
-    assert params is not None
+        dimension_semantics=("parallel", "arbitrary"))
     assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
+    with pytest.raises(TypeError):  # an unknown kwarg is never dropped
+        runtime.compiler_params(dimension_semantics=("parallel",),
+                                not_a_real_compiler_knob=1)
+
+
+def test_interpret_follows_backend():
+    assert runtime.resolve_interpret(None) is True  # the CPU test backend
+    assert runtime.resolve_interpret(False) is False  # described-chip compiles
+
+
+@pytest.mark.parametrize("ext,req,align,want", [
+    (2816, 32, 128, 128),     # lane request below the tile rounds up
+    (64, 8, 128, 64),         # ... and stops at the whole dim
+    (151936, 128, 128, 128),
+    (4, 128, 8, 4),           # decode rows: the whole dim
+    (200, 64, 16, 64),        # bf16 sublanes: already a multiple of 16
+    (200, 20, 32, 32),        # int8 sublanes round up to 32
+])
+def test_tile_is_mosaic_legal(ext, req, align, want):
+    t = runtime.tile(ext, req, align)
+    assert t == want
+    assert t == ext or t % align == 0
+
+
+@pytest.mark.parametrize("ext,req,align,want", [
+    (8192, 128, 128, 128),
+    (96, 32, 128, 96),        # no 128-multiple divides 96: the whole dim
+    (384, 512, 128, 128),     # 384 itself is the whole dim; 256 ∤ 384
+    (64, 32, 8, 32),
+])
+def test_divisor_tile_divides_exactly(ext, req, align, want):
+    t = runtime.divisor_tile(ext, req, align)
+    assert t == want and ext % t == 0
+
+
+def test_sublanes_by_dtype():
+    assert [runtime.sublanes(d) for d in
+            ("float32", "bfloat16", "int16", "int8")] == [8, 16, 16, 32]
+
+
+def test_mxu_dot_int16_limbs_exact_at_range_edges():
+    """The three-limb int16 product equals the widened int32 dot modulo
+    2**32, including the extremes of the int16 range (whose products
+    overflow int32 when summed)."""
+    edge = np.array([-32768, -32767, -129, -128, -1, 0, 1, 127, 128,
+                     16383, 16384, 32639, 32640, 32767], np.int16)
+    a = np.resize(edge, (16, 32)).astype(np.int16)
+    b = np.resize(edge[::-1], (32, 8)).astype(np.int16)
+    want = (a.astype(np.int64) @ b.astype(np.int64)) % 2**32
+    got = np.asarray(runtime.mxu_dot(jnp.asarray(a), jnp.asarray(b)))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got.astype(np.int64) % 2**32, want)
+
+
+@pytest.mark.parametrize("da,db", [("int32", "int16"), ("int32", "int32")])
+def test_mxu_dot_wide_int_limbs_exact(da, db):
+    """int32 operands (a fused chain's accumulator feeding the next GEMM)
+    split into five limbs; the result still wraps like the int32 dot."""
+    rng = np.random.default_rng(3)
+    lo_a, hi_a = np.iinfo(da).min, np.iinfo(da).max
+    lo_b, hi_b = np.iinfo(db).min, np.iinfo(db).max
+    a = rng.integers(lo_a, hi_a, (8, 16), endpoint=True).astype(da)
+    b = rng.integers(lo_b, hi_b, (16, 8), endpoint=True).astype(db)
+    want = (a.astype(np.int64) @ b.astype(np.int64)) % 2**32
+    got = np.asarray(runtime.mxu_dot(jnp.asarray(a), jnp.asarray(b)))
+    np.testing.assert_array_equal(got.astype(np.int64) % 2**32, want)
 
 
 # ---------------------------------------------------------------------------
@@ -203,3 +256,20 @@ def test_fft2d_stage_backends_agree():
                                atol=0.5, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(p_im), np.asarray(x_im),
                                atol=0.5, rtol=1e-3)
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch, tmp_path):
+    """Where ``JAX_COMPILATION_CACHE_DIR`` is set the helper sets nothing
+    (JAX reads the variable itself); the fallback is one fixed,
+    gitignored directory of the checkout."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    root = compile_cache.CHECKOUT_CACHE_DIR.parent
+    assert (root / "chip_smoke.py").exists()
+    assert ".jax_cache/" in (root / ".gitignore").read_text().splitlines()
