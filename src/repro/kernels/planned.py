@@ -363,9 +363,10 @@ def _dispatch_mm(x, w, site: str):
     n = w.shape[1]
     plan, reason = _decide("mm", (m, n, k), x.dtype, w.dtype)
     _record(site, (m, n, k), plan=plan, reason=reason)
-    if plan is None:
-        return ref.matmul(x, w)
-    return _mm_planned(site, x, w)
+    with jax.named_scope(site):
+        if plan is None:
+            return ref.matmul(x, w)
+        return _mm_planned(site, x, w)
 
 
 def planned_dense(x, w, *, site: str = "dense"):
@@ -424,9 +425,10 @@ def _dispatch_bmm(a, b, site: str, out_dtype=None):
     n = b.shape[2]
     plan, reason = _decide("bmm", (nb, m, n, k), a.dtype, b.dtype)
     _record(site, (nb, m, n, k), plan=plan, reason=reason)
-    if plan is None:
-        return _bmm_fallback(a, b, out_dtype)
-    return _bmm_planned(site, out_dtype, a, b)
+    with jax.named_scope(site):
+        if plan is None:
+            return _bmm_fallback(a, b, out_dtype)
+        return _bmm_planned(site, out_dtype, a, b)
 
 
 def planned_bmm(a, b, *, site: str = "bmm", out_dtype=None):
@@ -543,11 +545,12 @@ def planned_mlp_pair(x, wu, bu, wd, *, act: str = "gelu",
     plan, reason = _decide_pair(
         m, k, ff, n, (x.dtype, wu.dtype, bu.dtype, wd.dtype), act)
     _record(site, _pair_shape(m, k, ff, n), plan=plan, reason=reason)
-    if plan is None:
-        act_fn = _ACT_FNS.get(act, jax.nn.gelu)
-        h = act_fn(planned_dense(x, wu, site="mlp.up") + bu)
-        return planned_dense(h, wd, site="mlp.down")
-    out = _mlp_pair_planned(site, act, x.reshape(m, k), wu, bu, wd)
+    with jax.named_scope(site):
+        if plan is None:
+            act_fn = _ACT_FNS.get(act, jax.nn.gelu)
+            h = act_fn(planned_dense(x, wu, site="mlp.up") + bu)
+            return planned_dense(h, wd, site="mlp.down")
+        out = _mlp_pair_planned(site, act, x.reshape(m, k), wu, bu, wd)
     return out.reshape(*lead, n)
 
 

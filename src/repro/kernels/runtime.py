@@ -252,5 +252,6 @@ def execute_plan(plan: "ExecutionPlan", *operands,
     sem = kw.pop("dimension_semantics")
     if out_dtype is not None:
         kw["out_dtype"] = out_dtype
-    return spec.pallas(*operands, **kw, dimension_semantics=sem,
-                       interpret=resolve_interpret(interpret))
+    with jax.named_scope(f"widesa.{rec.name}"):
+        return spec.pallas(*operands, **kw, dimension_semantics=sem,
+                           interpret=resolve_interpret(interpret))

@@ -422,14 +422,15 @@ def paged_write(pool, new, block_tables, pos, active):
     blocks since re-allocated to another lane — so their flat index is
     forced out of range and dropped by the scatter (``mode="drop"``),
     never clamped onto a live row."""
-    nb, bs = pool.shape[0], pool.shape[1]
-    blk = jnp.take_along_axis(
-        block_tables, (pos // bs)[:, None], axis=1)[:, 0]
-    idx = blk * bs + pos % bs
-    idx = jnp.where(active, idx, nb * bs)  # OOB sentinel -> dropped
-    flat = pool.reshape(nb * bs, *pool.shape[2:])
-    flat = flat.at[idx].set(new.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+    with jax.named_scope("kv.write"):
+        nb, bs = pool.shape[0], pool.shape[1]
+        blk = jnp.take_along_axis(
+            block_tables, (pos // bs)[:, None], axis=1)[:, 0]
+        idx = blk * bs + pos % bs
+        idx = jnp.where(active, idx, nb * bs)  # OOB sentinel -> dropped
+        flat = pool.reshape(nb * bs, *pool.shape[2:])
+        flat = flat.at[idx].set(new.astype(pool.dtype), mode="drop")
+        return flat.reshape(pool.shape)
 
 
 def paged_gather(pool, block_tables):
@@ -439,8 +440,9 @@ def paged_gather(pool, block_tables):
     the lane's ``pos`` are garbage (freed or never-written blocks) — the
     decode mask hides them, exactly like the zero tail of a contiguous
     lane cache."""
-    g = pool[block_tables]  # [B, T, bs, ...]
-    return g.reshape(block_tables.shape[0], -1, *pool.shape[2:])
+    with jax.named_scope("kv.gather"):
+        g = pool[block_tables]  # [B, T, bs, ...]
+        return g.reshape(block_tables.shape[0], -1, *pool.shape[2:])
 
 
 def apply_attention_decode_paged(p, cfg, x, pool_k, pool_v, block_tables,

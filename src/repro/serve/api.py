@@ -35,6 +35,7 @@ bit-identically on re-admission (same jitted per-chunk executables).
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,23 @@ class Request:
     kind: str = "text"
     chunks: list | None = None
     fed: int = 0
+    # host-clock stamps (time.perf_counter): queued by submit, first
+    # prefill dispatched; and the prompt rows prefilled for it, real and
+    # bucket-padded, summed over re-admissions after preemption
+    t_submit: float = 0.0
+    t_admit: float | None = None
+    prefill_tokens: int = 0
+    prefill_padded_tokens: int = 0
+
+
+#: Prefix of the engines' profiler spans (``jax.profiler.TraceAnnotation``
+#: is near free while no profile is being taken).
+SPAN_PREFIX = "repro/serve."
+
+
+def span(name: str, **args):
+    """A profiler span ``repro/serve.<name>`` carrying ``args``."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
 
 
 def validate_request(prompt, max_new_tokens: int, max_seq: int,
@@ -151,7 +169,8 @@ class EngineBase:
                          self._extra_rows(extra))
         rid = self._next_rid
         self._next_rid += 1
-        self.queue.append(Request(rid, prompt, max_new_tokens, extra))
+        self.queue.append(Request(rid, prompt, max_new_tokens, extra,
+                                  t_submit=time.perf_counter()))
         return rid
 
     # explicit-name alias so call sites read symmetrically with
@@ -181,7 +200,8 @@ class EngineBase:
         rid = self._next_rid
         self._next_rid += 1
         self.queue.append(Request(rid, prompt, max_new_tokens,
-                                  kind="audio", chunks=chunks))
+                                  kind="audio", chunks=chunks,
+                                  t_submit=time.perf_counter()))
         return rid
 
     def step(self) -> int:  # provided by the engine subclass

@@ -55,6 +55,8 @@ systems artifact, not a quality one.
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -64,7 +66,7 @@ from repro.core import autotune
 from repro.kernels import planned
 
 from .api import EngineBase, Request, validate_request  # noqa: F401
-from .api import _StreamState
+from .api import SPAN_PREFIX, _StreamState, span
 from .paged_cache import PagedKVCache
 from .scheduler import Scheduler, SchedulerConfig
 
@@ -185,6 +187,8 @@ class ServeEngine(EngineBase):
         free = self._free_slots()
         while free and self.queue:
             req = self.queue.pop(0)
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
             stream = None
             if req.kind == "audio":
                 ck, cv, el, ec, carry = self._stream_admit_state(req)
@@ -253,7 +257,14 @@ class PagedServeEngine(EngineBase):
     for every lane at full horizon — shrink it to oversubscribe and
     exercise preemption).  ``stats`` tracks ``decode_compiles`` (pinned
     at 1 by the tests), ``prefill_compiles`` (one per bucket),
-    ``preemptions`` and ``steps``.
+    ``preemptions`` and ``steps``.  Each request counts the prompt rows
+    prefilled for it, real (``prefill_tokens``) and as their buckets
+    computed them (``prefill_padded_tokens``).
+
+    Each ``step()`` is a profiler step span ``repro/serve.step`` holding
+    the spans ``admit`` (with one ``prefill`` per request, carrying its
+    ``rid``, ``bucket`` and real ``tokens``, and its ``write_prefill``),
+    ``capacity``, ``decode`` and ``sample``.
     """
 
     def __init__(self, cfg: ModelConfig, *, max_lanes: int = 4,
@@ -317,10 +328,12 @@ class PagedServeEngine(EngineBase):
         self.num_blocks = self.kv.num_blocks
         before = planned.planned_report()
         tune0 = autotune.counters()
+
+        def paged_decode_step(p, pools, t, bt, pos, act):
+            return self.api.paged_decode(p, pools, t, bt, pos, act)
+
         with self._plan_ctx():
-            decode_jit = jax.jit(
-                lambda p, pools, t, bt, pos, act:
-                self.api.paged_decode(p, pools, t, bt, pos, act))
+            decode_jit = jax.jit(paged_decode_step)
             tokens0 = jnp.zeros((self.max_lanes, 1), jnp.int32)
             bt0, pos0, act0 = self.kv.device_args()
             self._decode_exec = decode_jit.lower(
@@ -379,10 +392,12 @@ class PagedServeEngine(EngineBase):
         fn = self._prefill_fns.get(key)
         if fn is None:
             if use_li:
-                fn = jax.jit(lambda p, b, li: self.api.prefill(
-                    p, b, rows, last_index=li))
+                def prefill_step(p, b, li):
+                    return self.api.prefill(p, b, rows, last_index=li)
             else:
-                fn = jax.jit(lambda p, b: self.api.prefill(p, b, rows))
+                def prefill_step(p, b):
+                    return self.api.prefill(p, b, rows)
+            fn = jax.jit(prefill_step)
             self._prefill_fns[key] = fn
             self.stats["prefill_compiles"] += 1
         return fn
@@ -394,9 +409,11 @@ class PagedServeEngine(EngineBase):
         key = ("stream", rows)
         fn = self._prefill_fns.get(key)
         if fn is None:
-            fn = jax.jit(
-                lambda p, ek, ev, el, tk, li: self.api.stream_prefill(
-                    p, ek, ev, el, tk, rows, last_index=li))
+            def prefill_step(p, ek, ev, el, tk, li):
+                return self.api.stream_prefill(
+                    p, ek, ev, el, tk, rows, last_index=li)
+
+            fn = jax.jit(prefill_step)
             self._prefill_fns[key] = fn
             self.stats["prefill_compiles"] += 1
         return fn
@@ -410,28 +427,33 @@ class PagedServeEngine(EngineBase):
             self.kv.blocks_for(extra_rows + plen))
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :plen] = eff
-        stream = None
-        if req.kind == "audio":
-            ck, cv, el, ec, carry = self._stream_admit_state(req)
-            fn = self._stream_prefill_fn(bucket)
-            logits, pc = fn(self.params, ck, cv, el,
-                            jnp.asarray(tokens),
-                            jnp.asarray([plen - 1], jnp.int32))
-            stream = (ec, carry)
-        else:
-            batch = {"tokens": jnp.asarray(tokens)}
-            if req.extra:
-                batch.update({k: jnp.asarray(v[None])
-                              for k, v in req.extra.items()})
-            use_li = not self._exact_prefill
-            fn = self._prefill_fn(
-                bucket + extra_rows, tuple(sorted(batch)), use_li)
-            if use_li:
-                logits, pc = fn(self.params, batch,
+        with span("prefill", rid=req.rid, bucket=bucket, tokens=plen):
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
+            stream = None
+            if req.kind == "audio":
+                ck, cv, el, ec, carry = self._stream_admit_state(req)
+                fn = self._stream_prefill_fn(bucket)
+                logits, pc = fn(self.params, ck, cv, el,
+                                jnp.asarray(tokens),
                                 jnp.asarray([plen - 1], jnp.int32))
+                stream = (ec, carry)
             else:
-                logits, pc = fn(self.params, batch)
-        req.output.append(int(jnp.argmax(logits[0])))
+                batch = {"tokens": jnp.asarray(tokens)}
+                if req.extra:
+                    batch.update({k: jnp.asarray(v[None])
+                                  for k, v in req.extra.items()})
+                use_li = not self._exact_prefill
+                fn = self._prefill_fn(
+                    bucket + extra_rows, tuple(sorted(batch)), use_li)
+                if use_li:
+                    logits, pc = fn(self.params, batch,
+                                    jnp.asarray([plen - 1], jnp.int32))
+                else:
+                    logits, pc = fn(self.params, batch)
+            req.output.append(int(jnp.argmax(logits[0])))
+        req.prefill_tokens += plen
+        req.prefill_padded_tokens += bucket
         if len(req.output) >= req.max_new_tokens:
             # admit-time done check: the prefill token satisfied the
             # budget — finish without ever occupying a lane
@@ -439,8 +461,9 @@ class PagedServeEngine(EngineBase):
             self.finished.append(req)
             self.kv.allocator.release(blocks)
             return
-        self.kv.install_lane(lane, blocks, extra_rows + plen)
-        self.kv.write_prefill(lane, pc)
+        with span("write_prefill"):
+            self.kv.install_lane(lane, blocks, extra_rows + plen)
+            self.kv.write_prefill(lane, pc)
         self.lanes[lane] = req
         self._lane_seq[lane] = self._admit_seq
         self._admit_seq += 1
@@ -511,34 +534,44 @@ class PagedServeEngine(EngineBase):
     def step(self) -> int:
         """Admit + one decode step for all active lanes.  Returns active
         request count after the step plus the queue backlog."""
+        with jax.profiler.StepTraceAnnotation(
+                SPAN_PREFIX + "step", step_num=self.stats["steps"]):
+            return self._step()
+
+    def _step(self) -> int:
         with self._plan_ctx():
             # bucketed prefills compile lazily on first admit, and the
             # streaming chunk feeds trace the encoder GEMMs — the
             # engine's policy/target must be ambient for those traces
-            self._admit()
+            with span("admit"):
+                self._admit()
             self._feed_streams()
-        self._ensure_capacity()
+        with span("capacity"):
+            self._ensure_capacity()
         active = [i for i, r in enumerate(self.lanes) if r is not None]
         if not active:
             return len(self.queue)
-        self.kv.guard_decode_write()
-        tokens = np.zeros((self.max_lanes, 1), np.int32)
-        for i in active:
-            tokens[i, 0] = self.lanes[i].output[-1]
-        bt, pos, act = self.kv.device_args()
-        logits, self.kv.pools = self._decode_exec(
-            self.params, self.kv.pools, jnp.asarray(tokens), bt, pos, act)
+        with span("decode"):
+            self.kv.guard_decode_write()
+            tokens = np.zeros((self.max_lanes, 1), np.int32)
+            for i in active:
+                tokens[i, 0] = self.lanes[i].output[-1]
+            bt, pos, act = self.kv.device_args()
+            logits, self.kv.pools = self._decode_exec(
+                self.params, self.kv.pools, jnp.asarray(tokens), bt, pos,
+                act)
         self.stats["steps"] += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for i in active:
-            req = self.lanes[i]
-            req.output.append(int(nxt[i]))
-            self.kv.pos[i] += 1
-            if len(req.output) >= req.max_new_tokens:
-                req.done = True
-                self.finished.append(req)
-                self.kv.release_lane(i)
-                self.lanes[i] = None
-                self._lane_seq.pop(i, None)
-                self._streams.pop(i, None)
+        with span("sample"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            for i in active:
+                req = self.lanes[i]
+                req.output.append(int(nxt[i]))
+                self.kv.pos[i] += 1
+                if len(req.output) >= req.max_new_tokens:
+                    req.done = True
+                    self.finished.append(req)
+                    self.kv.release_lane(i)
+                    self.lanes[i] = None
+                    self._lane_seq.pop(i, None)
+                    self._streams.pop(i, None)
         return sum(r is not None for r in self.lanes) + len(self.queue)

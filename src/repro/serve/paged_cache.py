@@ -160,7 +160,7 @@ class PagedKVCache:
             return self._write_fns[rows]
         layout = dict(self.layout)
 
-        def write(pools, pc, idx, lane):
+        def write_prefill(pools, pc, idx, lane):
             new = {}
             for name, kind in layout.items():
                 pool = pools[name]
@@ -180,7 +180,7 @@ class PagedKVCache:
                         pool, src[:, 0], lane, axis=1)
             return new
 
-        fn = jax.jit(write)
+        fn = jax.jit(write_prefill)
         self._write_fns[rows] = fn
         return fn
 
