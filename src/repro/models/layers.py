@@ -433,16 +433,46 @@ def paged_write(pool, new, block_tables, pos, active):
         return flat.reshape(pool.shape)
 
 
-def paged_gather(pool, block_tables):
+def paged_write_layers(pool, rows, block_tables, pos, active):
+    """Scatter one token's K/V rows of every layer into a stacked block
+    pool in one scatter: pool [L, NB, bs, ...]; rows [L, B, ...].  Lanes
+    as in ``paged_write``: an inactive lane's index is forced out of
+    range and dropped, never clamped onto a live row."""
+    with jax.named_scope("kv.write"):
+        nl, nb, bs = pool.shape[:3]
+        blk = jnp.take_along_axis(
+            block_tables, (pos // bs)[:, None], axis=1)[:, 0]
+        idx = blk * bs + pos % bs
+        idx = jnp.where(active, idx, nb * bs)  # OOB sentinel -> dropped
+        flat = pool.reshape(nl, nb * bs, *pool.shape[3:])
+        # index (layer, row) pairs: with the layer a window axis instead,
+        # XLA makes the row axis major and relayouts the whole pool
+        layer = jnp.arange(nl)[:, None]
+        flat = flat.at[layer, idx[None, :]].set(
+            rows.astype(pool.dtype), mode="drop")
+        return flat.reshape(pool.shape)
+
+
+def paged_gather(pool, block_tables, layer=None):
     """Assemble each lane's logical K/V sequence from its block table.
 
-    pool [NB, bs, ...]; block_tables [B, T] -> [B, T*bs, ...].  Rows past
-    the lane's ``pos`` are garbage (freed or never-written blocks) — the
-    decode mask hides them, exactly like the zero tail of a contiguous
-    lane cache."""
+    pool [NB, bs, ...], or a stacked [L, NB, bs, ...] pool with ``layer``
+    picking the layer inside the same gather; block_tables [B, T] ->
+    [B, T*bs, ...].  Rows past the lane's ``pos`` are garbage (freed or
+    never-written blocks) — the decode mask hides them, exactly like the
+    zero tail of a contiguous lane cache."""
     with jax.named_scope("kv.gather"):
-        g = pool[block_tables]  # [B, T, bs, ...]
-        return g.reshape(block_tables.shape[0], -1, *pool.shape[2:])
+        # [B, T, bs, ...]
+        g = pool[block_tables] if layer is None else pool[layer, block_tables]
+        return g.reshape(block_tables.shape[0], -1, *g.shape[3:])
+
+
+def with_row_at(seq, row, pos):
+    """seq [B, S, ...] with each lane's ``row`` [B, ...] at ``pos``, cast
+    to ``seq``'s (the pool's) dtype as a stored row would be: the current
+    token's K/V before ``paged_write_layers`` stores it."""
+    lanes = jnp.arange(seq.shape[0])
+    return seq.at[lanes, pos].set(row.astype(seq.dtype), mode="drop")
 
 
 def apply_attention_decode_paged(p, cfg, x, pool_k, pool_v, block_tables,
@@ -461,6 +491,27 @@ def apply_attention_decode_paged(p, cfg, x, pool_k, pool_v, block_tables,
         p, cfg, q, kseq, vseq, pos,
         sites=("attn.paged_scores", "attn.paged_values"))
     return out, pool_k, pool_v
+
+
+def apply_attention_decode_stacked(p, cfg, x, pool_k, pool_v, layer,
+                                   block_tables, pos):
+    """One-token decode of layer ``layer`` against stacked [L, NB, bs,
+    ...] block pools, which it only reads: the new K/V row joins the
+    gathered sequence at ``pos`` and is returned, cast to the pool dtype,
+    for ``paged_write_layers`` to store after the layer scan.  Same math
+    and bits as ``apply_attention_decode_paged`` for every active lane."""
+    b = x.shape[0]
+    q, k, v = _qkv(p, cfg, x, pos[:, None])
+    # pool rows hold all heads flattened: [B, Hkv*hd]
+    k = k.reshape(b, -1).astype(pool_k.dtype)
+    v = v.reshape(b, -1).astype(pool_v.dtype)
+    kseq = with_row_at(paged_gather(pool_k, block_tables, layer), k, pos)
+    vseq = with_row_at(paged_gather(pool_v, block_tables, layer), v, pos)
+    heads = (cfg.n_kv_heads, cfg.hd)
+    out = _masked_decode_attention(
+        p, cfg, q, kseq.reshape(b, -1, *heads), vseq.reshape(b, -1, *heads),
+        pos, sites=("attn.paged_scores", "attn.paged_values"))
+    return out, k, v
 
 
 # ---------------------------------------------------------------------------

@@ -177,19 +177,23 @@ def apply_mla_decode(p, cfg, x, cache_ckv, cache_kr, pos):
     return out, cache_ckv, cache_kr
 
 
-def apply_mla_decode_paged(p, cfg, x, pool_ckv, pool_kr, block_tables,
-                           pos, active):
-    """Block-paged absorbed decode: the compressed latent cache lives in
-    a shared block pool indexed through per-lane block tables (see
-    ``layers.paged_write``/``paged_gather``)."""
-    from .layers import paged_gather, paged_write
+def apply_mla_decode_paged(p, cfg, x, pool_ckv, pool_kr, layer,
+                           block_tables, pos):
+    """Block-paged absorbed decode of layer ``layer``: the compressed
+    latent cache lives in stacked [L, NB, bs, ...] block pools indexed
+    through per-lane block tables, which this only reads.  The new latent
+    rows join the gathered sequence at ``pos`` and are returned, cast to
+    the pool dtype, for ``layers.paged_write_layers`` to store after the
+    layer scan (see ``layers.apply_attention_decode_stacked``)."""
+    from .layers import paged_gather, with_row_at
 
     qn, qr = _queries(p, cfg, x, pos[:, None])
     ckv_new, kr_new = _latent(p, cfg, x, pos[:, None])
-    pool_ckv = paged_write(pool_ckv, ckv_new[:, 0], block_tables, pos,
-                           active)
-    pool_kr = paged_write(pool_kr, kr_new[:, 0], block_tables, pos, active)
-    ckv_seq = paged_gather(pool_ckv, block_tables)
-    kr_seq = paged_gather(pool_kr, block_tables)
+    ckv_new = ckv_new[:, 0].astype(pool_ckv.dtype)
+    kr_new = kr_new[:, 0].astype(pool_kr.dtype)
+    ckv_seq = with_row_at(paged_gather(pool_ckv, block_tables, layer),
+                          ckv_new, pos)
+    kr_seq = with_row_at(paged_gather(pool_kr, block_tables, layer),
+                         kr_new, pos)
     out = _absorbed_decode(p, cfg, qn, qr, ckv_seq, kr_seq, pos)
-    return out, pool_ckv, pool_kr
+    return out, ckv_new, kr_new

@@ -386,47 +386,45 @@ def init_paged_pools(cfg, num_blocks, block_size, max_lanes,
                 (L_total, num_blocks, block_size, cfg.rope_head_dim),
                 dtype),
         }
-    return {
-        "k": jnp.zeros(
-            (L_total, num_blocks, block_size, cfg.n_kv_heads, cfg.hd),
-            dtype),
-        "v": jnp.zeros(
-            (L_total, num_blocks, block_size, cfg.n_kv_heads, cfg.hd),
-            dtype),
-    }
+    # one K/V row is all heads' Hkv*hd values: a [bs, Hkv*hd] block then
+    # fills whole (16, 128) bf16 TPU tiles and the pools keep a row-major
+    # layout, where a trailing [Hkv, hd] lays the block axis minor-most
+    # and every gather and scatter relayouts the whole pool first
+    shape = (L_total, num_blocks, block_size, cfg.n_kv_heads * cfg.hd)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _decode_blocks_paged(stacked, cfg, x, pool_slices, block_tables, pos,
-                         active, *, moe: bool):
-    """Paged twin of ``_decode_blocks``: per-layer block pools instead of
-    per-layer lane caches; tables/pos/active are broadcast constants."""
-    if stacked is None:
-        return x, pool_slices
-
+def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
+                         pos, *, moe: bool):
+    """Paged twin of ``_decode_blocks``.  The stacked [L, NB, bs, ...]
+    pools stay whole and loop-invariant: the scan's inputs are the layer
+    parameters and the layer indices ``layers``, each layer gathers its
+    lanes' rows straight from the stacked pools, and the scan returns
+    each layer's new rows ([n, B, ...] per leaf) for the caller to write
+    once.  Pools passed as the scan's inputs and outputs would be sliced
+    out and written back whole, layer by layer, on every step."""
     def body(x, inp):
-        lp, ps = inp
+        lp, layer = inp
         h = L.apply_norm(lp["ln1"], cfg, x)
         if cfg.use_mla:
             attn, ckv, kr = MLA.apply_mla_decode_paged(
-                lp["attn"], cfg, h, ps["ckv"], ps["kr"], block_tables,
-                pos, active)
-            new_ps = {"ckv": ckv, "kr": kr}
+                lp["attn"], cfg, h, pools["ckv"], pools["kr"], layer,
+                block_tables, pos)
+            rows = {"ckv": ckv, "kr": kr}
         else:
-            attn, pk, pv = L.apply_attention_decode_paged(
-                lp["attn"], cfg, h, ps["k"], ps["v"], block_tables, pos,
-                active)
-            new_ps = {"k": pk, "v": pv}
+            attn, k, v = L.apply_attention_decode_stacked(
+                lp["attn"], cfg, h, pools["k"], pools["v"], layer,
+                block_tables, pos)
+            rows = {"k": k, "v": v}
         x = x + attn
         h = L.apply_norm(lp["ln2"], cfg, x)
         if moe:
             y, _ = MOE.apply_moe(lp["moe"], cfg, h)
         else:
             y = L.apply_mlp(lp["mlp"], cfg, h)
-        return x + y, new_ps
+        return x + y, rows
 
-    x, new_pools = jax.lax.scan(body, x, (stacked, pool_slices),
-                                unroll=cfg.scan_unroll)
-    return x, new_pools
+    return jax.lax.scan(body, x, (stacked, layers), unroll=cfg.scan_unroll)
 
 
 def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
@@ -435,31 +433,28 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
 
     ``pos``/tables/``active`` are host-owned inputs (the engine advances
     pos and edits tables between steps), so the compiled executable's
-    shapes never depend on which requests are in flight."""
+    shapes never depend on which requests are in flight.  The layer scans
+    only read the pools; every layer's new row is written after them in
+    one scatter per pool, in place where the caller donates the pools."""
     x = embed_tokens(p, cfg, tokens)
-    n_dense, n_moe = _layer_split(cfg)
-
-    def slices(lo, hi):
-        return {k: v[lo:hi] for k, v in pools.items()}
-
-    x, ps_dense = _decode_blocks_paged(
-        p.get("dense_layers"), cfg, x, slices(0, n_dense), block_tables,
-        pos, active, moe=False)
-    x, ps_moe = _decode_blocks_paged(
-        p.get("moe_layers"), cfg, x, slices(n_dense, cfg.n_layers),
-        block_tables, pos, active, moe=True)
+    n_dense, _ = _layer_split(cfg)
+    groups = (("dense_layers", 0, n_dense, False),
+              ("moe_layers", n_dense, cfg.n_layers, True))
+    parts = []
+    for name, lo, hi, moe in groups:
+        if p.get(name) is None:
+            continue
+        x, rows = _decode_blocks_paged(
+            p[name], cfg, x, pools, jnp.arange(lo, hi), block_tables, pos,
+            moe=moe)
+        parts.append(rows)
     x = L.apply_norm(p["ln_f"], cfg, x)
     logits = logits_fn(p, cfg, x)[:, 0]
 
-    new_pools = {}
-    for k in pools:
-        parts = []
-        if ps_dense is not None and n_dense:
-            parts.append(ps_dense[k])
-        if ps_moe is not None and n_moe:
-            parts.append(ps_moe[k])
-        new_pools[k] = jnp.concatenate(parts, axis=0) if len(parts) > 1 \
-            else parts[0]
+    rows = jax.tree.map(lambda *r: jnp.concatenate(r), *parts)
+    new_pools = {
+        k: L.paged_write_layers(pool, rows[k], block_tables, pos, active)
+        for k, pool in pools.items()}
     return logits, new_pools
 
 

@@ -333,7 +333,9 @@ class PagedServeEngine(EngineBase):
             return self.api.paged_decode(p, pools, t, bt, pos, act)
 
         with self._plan_ctx():
-            decode_jit = jax.jit(paged_decode_step)
+            # the pools are donated: the step writes its new rows into
+            # them in place instead of copying every pool each step
+            decode_jit = jax.jit(paged_decode_step, donate_argnums=(1,))
             tokens0 = jnp.zeros((self.max_lanes, 1), jnp.int32)
             bt0, pos0, act0 = self.kv.device_args()
             self._decode_exec = decode_jit.lower(

@@ -169,7 +169,10 @@ class PagedKVCache:
                     nb, bs = pool.shape[1], pool.shape[2]
                     flat = pool.reshape(
                         pool.shape[0], nb * bs, *pool.shape[3:])
-                    flat = flat.at[:, idx].set(src[:, 0], mode="drop")
+                    # a pool row may flatten the cache's trailing axes
+                    rows = src[:, 0].reshape(
+                        src.shape[0], src.shape[2], *pool.shape[3:])
+                    flat = flat.at[:, idx].set(rows, mode="drop")
                     new[name] = flat.reshape(pool.shape)
                 elif kind == "lane_scalar":
                     # one scalar per lane ([max_lanes] pool, [B=1] src):

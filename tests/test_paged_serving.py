@@ -164,6 +164,7 @@ def test_paged_matches_slot_bit_identical(lanes):
 @pytest.mark.parametrize("arch,lanes", [
     ("deepseek-v2-236b", 1),   # MoE + MLA: absorbed paged decode
     ("mamba2-780m", 2),        # pure SSM: lane-resident state only
+    ("olmoe-1b-7b", 2),        # MoE with GQA: MoE layers only, no dense
 ])
 def test_paged_matches_slot_across_families(arch, lanes):
     cfg, params = _setup(arch)
@@ -172,6 +173,92 @@ def test_paged_matches_slot_across_families(arch, lanes):
     got = _drain(_paged(cfg, params, max_lanes=lanes, max_seq=64,
                         block_size=8), prompts)
     assert ref == got
+
+
+def _decode_write_then_gather(p, cfg, pools, tokens, tables, pos, active):
+    """The decode step as every layer once ran it: per-layer pools
+    through the layer scan, each layer writing its row with
+    ``paged_write`` and then reading its lanes with ``paged_gather``."""
+    from repro.models import layers as L
+    from repro.models import transformer as TFM
+
+    heads = (cfg.n_kv_heads, cfg.hd)
+
+    def body(x, inp):
+        lp, pk, pv = inp
+        h = L.apply_norm(lp["ln1"], cfg, x)
+        attn, pk, pv = L.apply_attention_decode_paged(
+            lp["attn"], cfg, h, pk, pv, tables, pos, active)
+        x = x + attn
+        h = L.apply_norm(lp["ln2"], cfg, x)
+        return x + L.apply_mlp(lp["mlp"], cfg, h), (pk, pv)
+
+    split = [pools[k].reshape(*pools[k].shape[:3], *heads) for k in "kv"]
+    x = TFM.embed_tokens(p, cfg, tokens)
+    x, (pk, pv) = jax.lax.scan(body, x, (p["dense_layers"], *split),
+                               unroll=cfg.scan_unroll)
+    x = L.apply_norm(p["ln_f"], cfg, x)
+    logits = TFM.logits_fn(p, cfg, x)[:, 0]
+    return logits, {"k": pk.reshape(pools["k"].shape),
+                    "v": pv.reshape(pools["v"].shape)}
+
+
+def test_decode_reads_pools_then_writes_rows_once_as_write_then_gather():
+    """The decode step gathers from the pools it was given, puts each
+    lane's new row into its sequence, and writes every layer's rows
+    after the layer scan: the same pools and active-lane logits, bit for
+    bit, as writing each row before gathering, layer by layer.  Lane 3
+    is inactive and its table points at lane 0's blocks: it writes
+    nothing."""
+    cfg, params = _setup()
+    api = build_model(cfg)
+    nb, bs, lanes, per_lane = 24, 4, 4, 6
+    pools = api.paged_init(nb, bs, lanes)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(pools))
+    pools = {k: jax.random.normal(kk, v.shape, jnp.float32).astype(v.dtype)
+             for kk, (k, v) in zip(keys, sorted(pools.items()))}
+    rng = np.random.default_rng(11)
+    tables = rng.permutation(nb)[:lanes * per_lane].reshape(lanes, per_lane)
+    tables[3] = tables[0]
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray([13, 0, 23, 6], jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (lanes, 1)), jnp.int32)
+
+    ref_logits, ref_pools = jax.jit(
+        lambda *a: _decode_write_then_gather(params, cfg, *a))(
+            pools, tokens, tables, pos, active)
+    logits, new_pools = jax.jit(
+        lambda *a: api.paged_decode(params, *a))(
+            pools, tokens, tables, pos, active)
+
+    for k in pools:
+        np.testing.assert_array_equal(np.asarray(new_pools[k], np.float32),
+                                      np.asarray(ref_pools[k], np.float32))
+        # lane 3's row would land in lane 0's block 1; only the active
+        # lanes' rows changed
+        changed = np.argwhere(np.any(
+            np.asarray(new_pools[k] != pools[k]), axis=-1))
+        want = {(layer, int(tables[i, pos[i] // bs]), int(pos[i]) % bs)
+                for layer in range(cfg.n_layers) for i in range(3)}
+        assert {tuple(map(int, c)) for c in changed} == want, k
+    np.testing.assert_array_equal(np.asarray(logits[:3]),
+                                  np.asarray(ref_logits[:3]))
+
+
+def test_decode_step_donates_the_pools():
+    """The engine donates the block pools to the decode executable, so
+    each step updates them in place: the step's input pools are gone
+    after it, and the engine holds the live output."""
+    cfg, params = _setup()
+    eng = _paged(cfg, params, max_lanes=2, max_seq=32, block_size=8)
+    eng.submit(_prompts(cfg, [6])[0], max_new_tokens=4)
+    eng.step()                  # admission, then the first decode
+    before = eng.kv.pools
+    eng.step()                  # a decode-only step
+    assert all(a.is_deleted() for a in before.values())
+    assert not any(a.is_deleted() for a in eng.kv.pools.values())
+    assert len(eng.run_until_drained()[0].output) == 4
 
 
 def test_bucketed_prefill_is_output_transparent():
