@@ -56,6 +56,7 @@ def serve(cell, seeds, devs, seconds, quant=None):
     """``quant`` defaults to the control of the configuration's dtype."""
     drv = harness.driver("serve", cell.root)
     cfg, s, mix = cell.config, cell.settings, cell.traffic
+    ref = drv.reference(cell)
     eng, shapes = drv.engine(cfg, s)
     rows = []
     for k, seed in enumerate(seeds):
@@ -78,8 +79,8 @@ def serve(cell, seeds, devs, seconds, quant=None):
         else:
             drv._open(loop, mix, mark, seconds, s["drain_seconds"],
                       tracer)
-        got, n = drv._check(loop.tracks, params, cfg, s, mix, seed)
-        low, _ = drv._check(loop.tracks, params, cfg, s, mix, seed,
+        got, n = drv._check(ref, loop.tracks, params, cfg, s, mix, seed)
+        low, _ = drv._check(ref, loop.tracks, params, cfg, s, mix, seed,
                             quant=quant or SERVE_CONTROL[cfg["torch_dtype"]])
         eng.finished.clear()
         rows.append({"seed": seed, "program": got, "control": low,

@@ -2,6 +2,15 @@
 engine (``serve.make_engine(kind="paged")``), driven step by step on the
 real clock by a closed or open loop.
 
+Everything that belongs to the architecture comes from the module that
+the configuration names, ``"reference": "<name>"`` for
+``chipbench/reference/<name>.py``: its ``logits`` is the plain forward
+that decides ``correct``, and its ``work(config)`` the operations and
+bytes the per-layer metrics read (``run.data["lm"]``).  A new
+architecture adds its configuration and its reference module; the
+published config.json keys map onto the program's ModelConfig by name
+(``HF_TO_PROGRAM``).
+
 Cell settings (``chipbench/workloads/<cell>.json``):
 
   lanes, max_seq, block_size   the engine's decode width, horizon, blocks
@@ -25,13 +34,13 @@ differences of consecutive stamps.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 
 import jax
 import numpy as np
 
-from chipbench import harness, trace, traffic, weights, work
-from chipbench.reference import qwen as ref
+from chipbench import harness, trace, traffic, weights
 
 #: published config.json key -> the program's ModelConfig field
 HF_TO_PROGRAM = {
@@ -41,7 +50,28 @@ HF_TO_PROGRAM = {
     "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings", "hidden_act": "act",
     "torch_dtype": "dtype", "head_dim": "head_dim",
+    # mixture of experts (DeepSeek-V2, OLMoE)
+    "n_routed_experts": "moe_num_experts", "num_experts_per_tok": "moe_top_k",
+    "n_shared_experts": "moe_shared_experts",
+    "moe_intermediate_size": "moe_d_ff",
+    "first_k_dense_replace": "moe_first_dense",
+    # multi-head latent attention (DeepSeek-V2)
+    "kv_lora_rank": "kv_lora_rank", "q_lora_rank": "q_lora_rank",
+    "qk_rope_head_dim": "rope_head_dim", "qk_nope_head_dim": "nope_head_dim",
+    "v_head_dim": "v_head_dim",
 }
+
+
+def reference(cell: harness.Cell):
+    """The plain reference module that the cell's configuration names;
+    FileNotFoundError, naming the file, where it is not there."""
+    name = cell.config["reference"]
+    path = cell.root / "chipbench" / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {cell.config['name']!r} names the reference "
+            f"{name!r}, and there is no {path}")
+    return harness.load_module(path, "chipbench_reference_" + name)
 
 
 def model_config(config: dict):
@@ -218,12 +248,12 @@ def _open(loop: Loop, mix, t_window, seconds, drain, tracer):
     return t0
 
 
-def _check(tracks, params, config, settings, mix, seed, quant=None):
+def _check(ref, tracks, params, config, settings, mix, seed, quant=None):
     """Widest gap by which a served token's reference logit lies below
-    the reference's best, over a sample of finished requests drawn from
-    the seed with the longest among them.  With ``quant`` the reading is
-    the control's: the gap of the token that the lower precision puts
-    first, at the same positions."""
+    the reference's best (``ref.logits``), over a sample of finished
+    requests drawn from the seed with the longest among them.  With
+    ``quant`` the reading is the control's: the gap of the token that
+    the lower precision puts first, at the same positions."""
     done = [tr for tr in tracks if tr.in_window and tr.req.done]
     if not done:
         return float("nan"), 0
@@ -263,8 +293,62 @@ def _check(tracks, params, config, settings, mix, seed, quant=None):
     return widest, tokens
 
 
+class GcLog:
+    """Python's garbage collections while it is installed: generation,
+    start on the host clock, seconds and objects collected."""
+
+    def __init__(self):
+        self.runs = []
+        self._t = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._t = harness.now()
+        elif self._t is not None:
+            self.runs.append((info["generation"], self._t,
+                              harness.now() - self._t, info["collected"]))
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+    def summary(self, t0: float) -> str:
+        full = [r for r in self.runs if r[0] == 2]
+        by_gen = {g: sum(1 for r in self.runs if r[0] == g) for g in range(3)}
+        return (f"gc collections by generation {by_gen}, "
+                f"{sum(r[2] for r in self.runs):.3f} s in all; full ones "
+                f"(start after window open s, s, collected): "
+                f"{[(round(r[1] - t0, 3), round(r[2], 3), r[3]) for r in full]}")
+
+
+def _log_steps(steps, t0: float, seconds: float) -> None:
+    """Where the window's time went, step by step: decode-only steps,
+    steps that admitted, and the host time between steps."""
+    win = [st for st in steps if t0 <= st.t0 < t0 + seconds]
+    if not win:
+        return
+    ms = lambda x: round(1e3 * x, 2)  # noqa: E731
+    dec = np.array([st.t1 - st.t0 for st in win if not st.prefills])
+    adm = [st for st in win if st.prefills]
+    between = sum(b.t0 - a.t1 for a, b in zip(win, win[1:]))
+    longest = sorted(win, key=lambda st: st.t0 - st.t1)[:5]
+    harness.log(
+        f"steps in window: {len(dec)} decode-only, median "
+        f"{ms(np.median(dec)) if len(dec) else None} ms, p90 "
+        f"{ms(np.percentile(dec, 90)) if len(dec) else None} ms, "
+        f"sum {dec.sum():.3f} s; {len(adm)} admitting "
+        f"({sum(len(st.prefills) for st in adm)} prefills), sum "
+        f"{sum(st.t1 - st.t0 for st in adm):.3f} s; between steps "
+        f"{between:.3f} s; longest (ms, prefills): "
+        f"{[(ms(st.t1 - st.t0), len(st.prefills)) for st in longest]}")
+
+
 def run(ctx: harness.Context):
     config, s, mix = ctx.cell.config, ctx.cell.settings, ctx.cell.traffic
+    ref = reference(ctx.cell)
     devs = ctx.devices
     eng, shapes = engine(config, s)
     params = weights.make(shapes, ctx.seed, config["hidden_size"])
@@ -283,12 +367,15 @@ def run(ctx: harness.Context):
         marks["t0"] = harness.now()
         return marks["t0"]
 
-    if mix["kind"] == "closed":
-        _closed(loop, mix, ctx.seed, t_window, ctx.seconds, s["warm_steps"],
-                s["drain_seconds"], tracer)
-    else:
-        _open(loop, mix, t_window, ctx.seconds, s["drain_seconds"], tracer)
+    with GcLog() as gcs:
+        if mix["kind"] == "closed":
+            _closed(loop, mix, ctx.seed, t_window, ctx.seconds,
+                    s["warm_steps"], s["drain_seconds"], tracer)
+        else:
+            _open(loop, mix, t_window, ctx.seconds, s["drain_seconds"],
+                  tracer)
     t0 = marks["t0"]
+    harness.log(f"{gcs.summary(t0)}; objects tracked {len(gc.get_objects())}")
     reduced = tracer.stop(devs)
     stats1, c1 = dict(eng.stats), counter.snapshot()
     harness.log(f"engine stats before window {stats0}, after {stats1}; "
@@ -299,15 +386,16 @@ def run(ctx: harness.Context):
     failed = sum(1 for tr in in_win if not tr.req.done)
     harness.log(f"window: {len(in_win)} requests arrived, {failed} "
                 f"unfinished after the drain, {len(loop.steps)} steps")
+    _log_steps(loop.steps, t0, ctx.seconds)
     del eng, loop.eng
-    gap, n_tok = _check(tracks, params, config, s, mix, ctx.seed)
+    gap, n_tok = _check(ref, tracks, params, config, s, mix, ctx.seed)
     harness.log(f"check: widest served-token logit gap {gap!r} over "
                 f"{n_tok} served tokens")
-    lm = work.LM.from_config(config)
     return harness.Run(
         setup_s=t0 - ctx.t_start, window_s=ctx.seconds,
         attempted=len(in_win), failed=failed,
         checks=[harness.Check("served_logit_gap", gap, s["limit"])],
         memory_peak_bytes=mem, peaks=ctx.peaks, chips=len(devs), trace=reduced,
-        data={"t0": t0, "tracks": tracks, "steps": loop.steps, "lm": lm,
-              "lanes": s["lanes"]})
+        data={"t0": t0, "tracks": tracks, "steps": loop.steps,
+              "lm": ref.work(config), "lanes": s["lanes"],
+              "stats": {"start": stats0, "end": stats1}})

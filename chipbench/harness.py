@@ -10,6 +10,9 @@ metric lives in a file of its own that is found by the name given in
   chipbench/traffic/<traffic>.json     the mix's parameters
   chipbench/drivers/<kind>.py          ``run(ctx) -> Run``
   chipbench/metrics/<metric>.py        ``read(run) -> float | None``
+  chipbench/reference/<name>.py        a serve configuration's
+                                       ``"reference"``: ``logits`` and
+                                       ``work(config)``
 """
 
 from __future__ import annotations

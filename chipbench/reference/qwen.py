@@ -20,6 +20,10 @@ output channel, activations per row, each scaled to the format's largest
 value).  ``quant="bf16"`` rounds them to bfloat16, the control of a
 float32 model.
 Imports nothing of the program.
+
+``work(config)`` gives the operations and bytes that the serving
+metrics read for this architecture: ``chipbench/work.py``'s dense GQA
+decoder.
 """
 
 from __future__ import annotations
@@ -28,6 +32,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import work as counts
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
@@ -131,3 +137,9 @@ def logits(weights, config: dict, tokens, sel, quant: str | None = None):
     items = tuple((k, config[k]) for k in _KEYS)
     return _logits(weights, jnp.asarray(tokens, jnp.int32),
                    jnp.asarray(sel, jnp.int32), items, quant)
+
+
+def work(config: dict) -> counts.LM:
+    """Operations and bytes of this architecture's steps, from the
+    published sizes."""
+    return counts.LM.from_config(config)

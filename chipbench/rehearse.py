@@ -83,12 +83,12 @@ def serve(cell, topo):
     from repro.kernels import runtime
 
     from chipbench.drivers import serve as drv
-    from chipbench.reference import qwen as ref
 
     # compile the kernels for the chip: interpret mode follows the
     # backend, which is the CPU here
     runtime.default_interpret = lambda: False
     s, config = cell.settings, cell.config
+    ref = drv.reference(cell)
     one = SingleDeviceSharding(topo.devices[0])
     eng, shapes = drv.engine(config, s)
     params = _on(one, shapes)
@@ -118,10 +118,9 @@ def serve(cell, topo):
         _report(f"{cell.name} prefill bucket {b}", pre, t0)
     pad = s["check_pad"]
     t0 = time.perf_counter()
-    items = tuple((k, config[k]) for k in ref._KEYS)
-    rc = ref._logits.lower(
-        params, _on(one, jax.ShapeDtypeStruct((pad,), i32)),
-        _on(one, jax.ShapeDtypeStruct((pad,), i32)), items, None).compile()
+    seq = _on(one, jax.ShapeDtypeStruct((pad,), i32))
+    rc = jax.jit(lambda p, t, i: ref.logits(p, config, t, i)).lower(
+        params, seq, seq).compile()
     _report(f"{cell.name} reference, {pad} tokens", rc, t0)
 
 

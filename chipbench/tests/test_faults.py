@@ -56,8 +56,11 @@ def _alter_token(monkeypatch):
 def _state_unchanged(monkeypatch):
     def wrap(dec):
         def f(p, pools, *a):
+            # the step takes the pools donated: hand back a copy made
+            # before it consumed them
+            kept = jax.tree.map(jnp.copy, pools)
             logits, _ = dec(p, pools, *a)
-            return logits, pools
+            return logits, kept
         return f
     _wrap_decode(monkeypatch, wrap)
 

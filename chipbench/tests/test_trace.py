@@ -60,3 +60,32 @@ def test_chip_trace_breakdown(chip):
     assert b["device_ops"][0][0].startswith("matmul")
     assert len(b["device_ops"]) <= 10
     assert {n for n, _ in b["idle_gaps"]} <= {"call", "outside spans"}
+
+
+def test_exposed_collective_share_by_hand():
+    from types import SimpleNamespace
+
+    from chipbench import harness
+
+    reader = harness.metric_reader("exposed_collective_share")
+
+    def ev(*ops):
+        return T.Events(np.array([(s, e) for _, s, e in ops], float),
+                        [f"%{n} = f32[8]{{0}} op(%p)" for n, _, _ in ops])
+
+    # device 0: an async exchange inside a loop, half of it under a
+    # convolution (the loop that holds both hides nothing); device 1: an
+    # all-reduce with nothing beside it; a fusion that only reads a
+    # collective's result is no collective
+    dev0 = ev(("fusion.1", 0, 10), ("while.7", 10, 20),
+              ("collective-permute-start.1", 10, 11),
+              ("collective-permute-done.1", 11, 14), ("convolution.2", 12, 20))
+    dev1 = ev(("all-reduce.3", 0, 5), ("fusion.2", 5, 20))
+    dev1.names[1] = "%fusion.2 = f32[8]{0} fusion(%collective-permute-done.1)"
+    red = T.Reduced(window=(0, 40), ops=[dev0, dev1], modules=[], spans=[])
+    got = reader.read(SimpleNamespace(trace=red))
+    assert got == pytest.approx(100.0 * (2 + 5) / 2 / 40)
+    alone = T.Reduced(window=(0, 40), ops=[ev(("fusion.1", 0, 10))],
+                      modules=[], spans=[])
+    assert reader.read(SimpleNamespace(trace=alone)) is None
+    assert reader.read(SimpleNamespace(trace=None)) is None

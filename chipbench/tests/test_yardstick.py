@@ -7,13 +7,19 @@ import numpy as np
 import pytest
 
 from chipbench import harness, peaks, traffic, work
+from chipbench.reference import qwen as ref
 from chipbench.tests import tiny
 
 QWEN = json.loads((tiny.BENCH / "configs" / "qwen1.5-0.5b.json").read_text())
+#: the serve driver reads the counts of the configuration's reference
+#: module; for Qwen they are chipbench/work.py's, unchanged
+COUNTS = pytest.mark.parametrize("counts", [work.LM.from_config, ref.work],
+                                 ids=["work.LM", "reference.work"])
 
 
-def test_qwen_sizes_by_hand():
-    lm = work.LM.from_config(QWEN)
+@COUNTS
+def test_qwen_sizes_by_hand(counts):
+    lm = counts(QWEN)
     # 24 x (4 x 1024^2 + 3 x 1024 x 2816 + 3072 bias + 2 norms)
     # + 151936 x 1024 tied embedding + the final norm
     assert lm.params() == 463_987_712
@@ -22,8 +28,9 @@ def test_qwen_sizes_by_hand():
     assert lm.weight_bytes_total() == 2 * 463_987_712
 
 
-def test_qwen_step_work_by_hand():
-    lm = work.LM.from_config(QWEN)
+@COUNTS
+def test_qwen_step_work_by_hand(counts):
+    lm = counts(QWEN)
     per_layer = 12_845_056                        # matmul weights of a layer
     assert lm.decode_flops([1]) == 2 * 24 * per_layer + 4 * 24 * 1024 \
         + 2 * 1024 * 151936

@@ -20,7 +20,8 @@ QWEN_TINY = {
     "rms_norm_eps": 1e-06, "rope_theta": 1000000.0,
     "tie_word_embeddings": True, "torch_dtype": "float32",
     "vocab_size": 512, "attention_bias": True,
-    "program": {"family": "dense", "qkv_bias": True}, "reduced": [],
+    "program": {"family": "dense", "qkv_bias": True}, "reference": "qwen",
+    "reduced": [],
 }
 MM_TINY = {"name": "mm-tiny", "recurrence": "mm",
            "extents": {"i": 256, "j": 256, "k": 256},
@@ -55,17 +56,21 @@ TRAFFIC = {
 }
 
 
-def make_root(tmp: Path, cells=CELLS) -> Path:
+def make_root(tmp: Path, cells=CELLS, configs=(QWEN_TINY, MM_TINY)) -> Path:
     """A benchmark root under ``tmp``: BENCHMARK.json, the tiny cells'
-    files, and the real drivers, metrics and references."""
+    files, and the real drivers, metrics and references (the last each
+    linked on its own, so that a test can add one)."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     d = tmp / "chipbench"
-    for sub in ("configs", "workloads", "traffic"):
+    for sub in ("configs", "workloads", "traffic", "reference"):
         (d / sub).mkdir(parents=True, exist_ok=True)
     for sub in ("drivers", "metrics"):
         if not (d / sub).exists():
             (d / sub).symlink_to(BENCH / sub)
-    for cfg in (QWEN_TINY, MM_TINY):
+    for f in (BENCH / "reference").glob("*.py"):
+        if not (d / "reference" / f.name).exists():
+            (d / "reference" / f.name).symlink_to(f)
+    for cfg in configs:
         (d / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
     for name, spec in TRAFFIC.items():
         (d / "traffic" / f"{name}.json").write_text(json.dumps(spec))
@@ -74,7 +79,7 @@ def make_root(tmp: Path, cells=CELLS) -> Path:
     bench["configs"] = [
         {"name": c["name"], "source": "test", "reduced": [], "why": "test",
          "file": f"chipbench/configs/{c['name']}.json"}
-        for c in (QWEN_TINY, MM_TINY)]
+        for c in configs]
     bench["workloads"] = [
         {"name": n, "config": c, "traffic": t, "chips": k, "why": "test"}
         for n, (c, t, k, _) in cells.items()]

@@ -4,12 +4,15 @@ seed: one jitted call, in the type they are served in.
 The tree's structure and shapes are the serving program's (``jax
 .eval_shape`` of its init); the values are the benchmark's own, so the
 reference and the program read the same numbers and the reference takes
-nothing that the program made.  By leaf name:
+nothing that the program made.  By leaf name, first match wins:
 
-  embed              normal, std 2 / sqrt(d): logits of std about 2
-  norm weights       1 + 0.1 * normal (every ``ln*`` / ``*norm`` leaf)
+  embed, lm_head     normal, std 2 / sqrt(d): logits of std about 2
+  norm weights       1 + 0.1 * normal (a leaf under an ``ln*`` or
+                     ``*norm`` group, or a leaf named ``*norm``)
   biases (b*)        0.1 * normal
-  matrices (w*)      normal / sqrt(fan_in)
+  any other array    normal / sqrt(fan_in), the second-last axis: the
+  of rank >= 2       projections, routers and expert stacks ``[E, d, ff]``
+                     of dense, GQA, MoE and MLA blocks alike
 """
 
 from __future__ import annotations
@@ -24,11 +27,12 @@ def _rule(path: tuple, shape, d: int):
     name = path[-1]
     if name == "embed" or name == "lm_head":
         return lambda k: jax.random.normal(k, shape) * (2.0 / np.sqrt(d))
-    if any(p.startswith("ln") or p.endswith("norm") for p in path[:-1]):
+    if name.endswith("norm") or any(
+            p.startswith("ln") or p.endswith("norm") for p in path[:-1]):
         return lambda k: 1.0 + 0.1 * jax.random.normal(k, shape)
     if name.startswith("b"):
         return lambda k: 0.1 * jax.random.normal(k, shape)
-    if name.startswith("w") and len(shape) >= 2:
+    if len(shape) >= 2:
         return lambda k: jax.random.normal(k, shape) / np.sqrt(shape[-2])
     raise ValueError(f"no weight rule for leaf {'/'.join(path)} {shape}")
 
