@@ -19,6 +19,13 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # YaRN rope scaling (DeepSeek-V2 ``rope_scaling``); factor 0 = off
+    rope_scaling_factor: float = 0.0
+    rope_original_positions: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     act: str = "silu"
@@ -38,12 +45,17 @@ class ModelConfig:
     fsdp: bool = True            # shard weights over the data axis
     grad_accum: int = 1          # microbatched gradient accumulation
     # --- MoE ---
-    moe_num_experts: int = 0
+    moe_num_experts: int = 0     # experts whose weights this model holds
+    moe_router_experts: int = 0  # router width; 0 -> moe_num_experts.
+                                 # Wider: one chip's share of an
+                                 # expert-parallel layer, holding the
+                                 # router's experts [0, moe_num_experts)
     moe_top_k: int = 0
+    moe_norm_topk: bool = True   # renormalise the top-k weights
     moe_shared_experts: int = 0
     moe_d_ff: int = 0
     moe_first_dense: int = 0     # leading dense layers (deepseek: 1)
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25  # EP all-to-all exchange only
     # --- MLA (deepseek) ---
     use_mla: bool = False
     kv_lora_rank: int = 0
@@ -69,6 +81,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def moe_router_width(self) -> int:
+        return self.moe_router_experts or self.moe_num_experts
 
     @property
     def d_inner(self) -> int:
@@ -120,7 +136,7 @@ class ModelConfig:
             per_moe = (
                 self.moe_num_experts * mlp_params(self.moe_d_ff)
                 + self.moe_shared_experts * mlp_params(self.moe_d_ff)
-                + d * self.moe_num_experts  # router
+                + d * self.moe_router_width  # router
             )
             total += n_moe * per_moe
         elif self.family == "ssm":
@@ -138,14 +154,17 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: top-k + shared only)."""
+        """Active params per token (MoE: top-k + shared only; of a held
+        share, the top-k's expected part among the held experts)."""
         if self.family != "moe":
             return self.param_count()
         d, L = self.d_model, self.n_layers
         n_moe = L - self.moe_first_dense
         full = self.param_count()
+        active = (self.moe_top_k * self.moe_num_experts
+                  // self.moe_router_width)
         inactive = n_moe * (
-            (self.moe_num_experts - self.moe_top_k) * 3 * d * self.moe_d_ff
+            (self.moe_num_experts - active) * 3 * d * self.moe_d_ff
         )
         return full - inactive
 

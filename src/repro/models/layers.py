@@ -59,17 +59,61 @@ def layernorm(x, w, b, eps):
 # rotary embedding
 # ---------------------------------------------------------------------------
 
-def rope_freqs(hd: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor ``0.1 * mscale * ln(factor) + 1`` (1 where
+    the factor does not stretch)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta):
-    """x: [..., S, H, hd]; positions: [..., S]."""
+def rope_freqs(hd: int, cfg):
+    """Inverse frequencies [hd/2] of base ``cfg.rope_theta``.  With YaRN
+    (``cfg.rope_scaling_factor``) they ramp from the base frequencies
+    (dims below ``beta_fast``'s correction dim) to the base over the
+    factor (dims above ``beta_slow``'s), linearly in between, the
+    correction dims taken over the original positions (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    theta = cfg.rope_theta
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    factor = cfg.rope_scaling_factor
+    if not factor:
+        return inv
+
+    def corr_dim(rotations):
+        return hd * math.log(cfg.rope_original_positions / (
+            rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.rope_beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_attn_scale(cfg) -> float:
+    """Factor on the softmax scale: YaRN's ``m**2``, with ``m`` the
+    magnitude factor at ``rope_mscale_all_dim`` (1 without YaRN)."""
+    if not cfg.rope_scaling_factor or not cfg.rope_mscale_all_dim:
+        return 1.0
+    return yarn_mscale(cfg.rope_scaling_factor, cfg.rope_mscale_all_dim) ** 2
+
+
+def apply_rope(x, positions, cfg):
+    """x: [..., S, H, hd]; positions: [..., S].  Rotates the two halves
+    of the head dim; YaRN scales cos and sin by ``m(mscale) /
+    m(mscale_all_dim)``."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # [hd/2]
+    freqs = rope_freqs(hd, cfg)  # [hd/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    factor = cfg.rope_scaling_factor
+    if factor:
+        m = yarn_mscale(factor, cfg.rope_mscale) / yarn_mscale(
+            factor, cfg.rope_mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -128,8 +172,8 @@ def _qkv(p, cfg, x, positions):
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
     if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg)
+        k = apply_rope(k, positions, cfg)
     q = constrain(q, "batch", None, "heads", None)
     k = constrain(k, "batch", None, "kv_heads", None)
     return q, k, v

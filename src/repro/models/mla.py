@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.kernels.planned import planned_dense
 from repro.parallel.sharding import constrain
-from .layers import apply_rope, dense_init, rmsnorm, _dtype
+from .layers import apply_rope, dense_init, rmsnorm, rope_attn_scale, _dtype
 
 
 def init_mla(key, cfg):
@@ -69,7 +69,7 @@ def _queries(p, cfg, x, positions):
         q = planned_dense(x, p["wq"], site="mla.q")
     q = q.reshape(b, s, h, nope + rope)
     qn, qr = q[..., :nope], q[..., nope:]
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = apply_rope(qr, positions, cfg)
     return constrain(qn, "batch", None, "heads", None), constrain(
         qr, "batch", None, "heads", None)
 
@@ -79,7 +79,7 @@ def _latent(p, cfg, x, positions):
                   p["kv_norm"], cfg.norm_eps)
     # [B,S,1,rope] shared across heads
     kr = planned_dense(x, p["wkr"], site="mla.k_rope")[:, :, None, :]
-    kr = apply_rope(kr, positions, cfg.rope_theta)
+    kr = apply_rope(kr, positions, cfg)
     return ckv, kr[:, :, 0, :]
 
 
@@ -103,7 +103,7 @@ def apply_mla(p, cfg, x, positions, *, causal=True):
     v = planned_dense(ckv, p["wuv"], site="mla.v_up").reshape(b, s, h, vd)
     kn = constrain(kn, "batch", None, "heads", None)
     v = constrain(v, "batch", None, "heads", None)
-    scale = 1.0 / math.sqrt(nope + rope)
+    scale = rope_attn_scale(cfg) / math.sqrt(nope + rope)
 
     if s > BLOCKWISE_SEQ_THRESHOLD:
         q_cat = jnp.concatenate([qn, qr], axis=-1)
@@ -141,18 +141,20 @@ def _absorbed_decode(p, cfg, qn, qr, ckv_seq, kr_seq, pos):
     # absorb W_uk into q:  q_abs[h, kvl] = qn[h] @ W_uk[h]^T
     wuk = p["wuk"].reshape(kvl, h, nope)
     q_abs = jnp.einsum("bqhd,lhd->bqhl", qn, wuk)  # [B,1,H,kvl]
-    scale = 1.0 / math.sqrt(nope + rope)
-    logits = (
-        jnp.einsum("bqhl,bkl->bhqk", q_abs, ckv_seq,
-                   preferred_element_type=jnp.float32)
-        + jnp.einsum("bqhd,bkd->bhqk", qr, kr_seq,
-                     preferred_element_type=jnp.float32)
-    ) * scale
+    scale = rope_attn_scale(cfg) / math.sqrt(nope + rope)
+    with jax.named_scope("attn.latent_scores"):
+        logits = (
+            jnp.einsum("bqhl,bkl->bhqk", q_abs, ckv_seq,
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("bqhd,bkd->bhqk", qr, kr_seq,
+                         preferred_element_type=jnp.float32)
+        ) * scale
     kpos = jnp.arange(ckv_seq.shape[1])[None, :]
     mask = kpos <= pos[:, None]
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1).astype(ckv_seq.dtype)
-    out_lat = jnp.einsum("bhqk,bkl->bqhl", w, ckv_seq)  # [B,1,H,kvl]
+    with jax.named_scope("attn.latent_values"):
+        out_lat = jnp.einsum("bhqk,bkl->bqhl", w, ckv_seq)  # [B,1,H,kvl]
     wuv = p["wuv"].reshape(kvl, h, vd)
     out = jnp.einsum("bqhl,lhd->bqhd", out_lat, wuv).reshape(b, 1, h * vd)
     return planned_dense(out, p["wo"], site="mla.out")

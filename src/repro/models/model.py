@@ -53,7 +53,9 @@ class ModelAPI:
     # them.  paged_layout() maps cache leaf -> "paged" (block pool,
     # [L, NB, bs, ...]) or "lane" ([L, max_lanes, ...] resident state);
     # paged_decode(p, pools, tokens, block_tables, pos, active) keeps
-    # pos/tables/active host-owned so its compiled shape never changes.
+    # pos/tables/active host-owned so its compiled shape never changes;
+    # it returns (logits, new pools), and an MoE model's step its count
+    # of (token, held expert) assignments as a third output.
     paged_init: Callable = None
     paged_decode: Callable = None
     paged_layout: Callable = None

@@ -1,20 +1,33 @@
-"""Mixture-of-Experts block: top-k routing, capacity, shared experts.
+"""Mixture-of-Experts block: top-k routing, held experts, shared experts.
 
-Two execution paths, same routing math:
+Routing: softmax over the router's experts -> top-k -> renormalised
+where ``cfg.moe_norm_topk`` (DeepSeek-V2-Lite leaves the top-k weights
+as they are).  An auxiliary load-balance loss (Switch-style) is
+returned alongside.
 
-  * baseline "TP-MoE" — experts sharded over the 'model' axis, tokens
-    replicated across it; every shard computes its local experts'
-    contribution and a psum combines.  Collective cost = one all-reduce of
-    activations per block, identical in shape to a dense-FFN TP all-reduce.
-    This is the GSPMD-friendly path used by train/prefill/decode alike.
+A layer may hold only some of the router's experts: one chip's share of
+an expert-parallel deployment (``cfg.moe_router_experts`` wider than
+``cfg.moe_num_experts``).  It routes over all of them and computes the
+part of the result its own experts give; what the others would add is
+left out.
+
+Three execution paths, same routing math:
+
+  * single device — dropless: every (token, held expert) assignment is
+    computed, by a grouped matmul over the held experts
+    (``jax.lax.ragged_dot``) on the assignments sorted by expert.  Rows
+    marked invalid (prefill pad rows, inactive decode lanes) get no
+    assignment, so they neither cost expert work nor change a real row.
+  * "TP-MoE" — experts sharded over the 'model' axis, tokens replicated
+    across it; every shard computes its local experts' part, dropless as
+    above, and a psum combines.  Collective cost = one all-reduce of
+    activations per block, identical in shape to a dense-FFN TP
+    all-reduce.
   * "EP a2a" — sequence-sharded dispatch with all_to_all to expert shards
     (see parallel/collectives.py); enabled per-config, used by the §Perf
     hillclimb to cut collective bytes (the WideSA congestion model picks
-    the axis).
-
-Routing: softmax -> top-k -> renormalize, capacity = ceil(T·k/E · cf) with
-drop-on-overflow (GShard-style), sort-based dispatch (no [T,E,C] one-hot).
-An auxiliary load-balance loss (Switch-style) is returned alongside.
+    the axis).  Its exchange is capacity-sized, ceil(T·k/E · cf) slots
+    per expert with drop-on-overflow (GShard-style, ``_dispatch_indices``).
 """
 
 from __future__ import annotations
@@ -30,11 +43,14 @@ from .layers import dense_init, _dtype
 
 
 def init_moe(key, cfg):
+    """The router over all ``cfg.moe_router_width`` experts; weights for
+    the ``cfg.moe_num_experts`` held ones."""
     d, e, ff = cfg.d_model, cfg.moe_num_experts, cfg.moe_d_ff
     ks = jax.random.split(key, 5)
     dt = _dtype(cfg)
     p = {
-        "router": dense_init(ks[0], d, e, jnp.float32, scale=0.02),
+        "router": dense_init(ks[0], d, cfg.moe_router_width, jnp.float32,
+                             scale=0.02),
         "wg": (jax.random.normal(ks[1], (e, d, ff), jnp.float32)
                / math.sqrt(d)).astype(dt),
         "wu": (jax.random.normal(ks[2], (e, d, ff), jnp.float32)
@@ -70,19 +86,21 @@ def moe_specs(cfg):
 
 
 def route(cfg, logits):
-    """softmax -> top-k -> renormalize.  logits: [T, E] (fp32)."""
+    """softmax -> top-k -> renormalise (``cfg.moe_norm_topk``).
+    logits: [T, E_router] (fp32)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     weights, ids = jax.lax.top_k(probs, cfg.moe_top_k)  # [T, k]
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if cfg.moe_norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, ids, probs
 
 
 def load_balance_loss(cfg, probs, ids):
-    """Switch-style aux loss: E * sum_e f_e * P_e.
+    """Switch-style aux loss: E * sum_e f_e * P_e over the router's E.
 
     probs: [..., E]; ids: [..., k] — leading axes are flattened.
     """
-    e = cfg.moe_num_experts
+    e = cfg.moe_router_width
     one_hot = jax.nn.one_hot(ids.reshape(-1), e, dtype=jnp.float32)
     counts = jnp.sum(one_hot, axis=0)
     f = counts / jnp.maximum(jnp.sum(counts), 1.0)
@@ -118,55 +136,75 @@ def _expert_ffn(cfg, wg, wu, wd, x):
     return planned_bmm(h, wd, site="moe.down")
 
 
-def moe_ffn_tokens(cfg, p, x_flat, *, local_experts=None):
-    """Route + dispatch + expert FFN + combine for a flat token batch.
+def _held(ids, first, count, valid):
+    """[T, k] bool: the assignments to the held experts [first,
+    first + count) of the rows ``valid`` marks (all rows where None)."""
+    held = (ids >= first) & (ids < first + count)
+    if valid is not None:
+        held = held & valid[:, None]
+    return held
 
-    x_flat: [T, d].  ``local_experts``: (start, count) to restrict the
-    compute to an expert shard (TP-MoE path; contributions outside the
-    shard are zeroed and later psum'd).  Returns (y_flat, aux_loss).
-    """
+
+def _grouped_ffn(cfg, p, x_flat, weights, ids, first, valid):
+    """Dropless FFN of the held experts p["w*"] [n, ...], which are the
+    router's experts [first, first + n): every held assignment of a valid
+    row is computed once, by ``ragged_dot`` over the assignments sorted
+    by expert.  Returns y [T, d] (the held experts' weighted sum) and the
+    number of assignments computed (int32)."""
     t, d = x_flat.shape
-    e, k = cfg.moe_num_experts, cfg.moe_top_k
-    capacity = max(
-        1, int(math.ceil(t * k * cfg.moe_capacity_factor / e))
-    )
+    k = ids.shape[1]
+    n = p["wg"].shape[0]
+    held = _held(ids, first, n, valid)
+    # most held assignments any routing gives: each row picks k distinct
+    # experts, at most n of them held.  The grouped matmul's time follows
+    # its groups' total, not these rows (TPU v5e: [6144, 2048] rows against
+    # 8 experts of 2048 x 1408 take 0.41 ms with 768 rows in the groups,
+    # 0.78 ms with all of them)
+    rows = t * min(k, n)
+    with jax.named_scope("moe.dispatch"):
+        group = jnp.where(held, ids - first, n).reshape(-1)  # n: not held
+        order = jnp.argsort(group, stable=True)[:rows]
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+        token = order // k
+        xs = x_flat[token]
+    act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    with jax.named_scope("moe.experts"):
+        gate = jax.lax.ragged_dot(xs, p["wg"], sizes,
+                                  preferred_element_type=jnp.float32)
+        up = jax.lax.ragged_dot(xs, p["wu"], sizes,
+                                preferred_element_type=jnp.float32)
+        h = (act(gate) * up).astype(x_flat.dtype)
+        out = jax.lax.ragged_dot(h, p["wd"], sizes,
+                                 preferred_element_type=jnp.float32)
+    with jax.named_scope("moe.combine"):
+        keep = held.reshape(-1)[order]
+        w = weights.reshape(-1)[order]
+        contrib = jnp.where(keep[:, None], out * w[:, None], 0.0)
+        y = jnp.zeros((t, d), jnp.float32).at[token].add(
+            contrib).astype(x_flat.dtype)
+    return y, jnp.sum(sizes)
+
+
+def moe_ffn_tokens(cfg, p, x_flat, *, local_experts=None, valid=None):
+    """Route + dropless held-expert FFN + combine for a flat token batch.
+
+    x_flat: [T, d]; valid: [T] bool or None (every row real).
+    ``local_experts``: (start, count) when p's expert stacks are an
+    expert shard (TP-MoE path; each shard computes its own experts'
+    part, later psum'd).  Otherwise p holds the router's experts [0,
+    cfg.moe_num_experts).  Returns (y_flat, aux_loss, held): ``held``
+    counts the (token, held expert) assignments computed.
+    """
     logits = planned_dense(
         x_flat.astype(jnp.float32), p["router"], site="moe.router")
     weights, ids, probs = route(cfg, logits)
     aux = load_balance_loss(cfg, probs[None], ids[None])
-
-    order, slot, keep, token = _dispatch_indices(cfg, ids, capacity)
-    w_flat = weights.reshape(-1)[order]
-
-    if local_experts is not None:
-        start, count = local_experts
-        sorted_experts = slot // capacity
-        in_shard = (sorted_experts >= start) & (
-            sorted_experts < start + count
-        )
-        keep = keep & in_shard
-        slot = slot - start * capacity
-        slot = jnp.clip(slot, 0, count * capacity - 1)
-        n_exp = count
-    else:
-        n_exp = e
-
-    buf = jnp.zeros((n_exp * capacity, d), x_flat.dtype)
-    buf = buf.at[slot].add(
-        jnp.where(keep[:, None], x_flat[token], 0).astype(x_flat.dtype)
-    )
-    out_buf = _expert_ffn(
-        cfg, p["wg"], p["wu"], p["wd"], buf.reshape(n_exp, capacity, d)
-    ).reshape(n_exp * capacity, d)
-
-    contrib = out_buf[slot] * (
-        w_flat[:, None].astype(x_flat.dtype)
-    ) * keep[:, None].astype(x_flat.dtype)
-    y = jnp.zeros((t, d), x_flat.dtype).at[token].add(contrib)
-    return y, aux
+    first = 0 if local_experts is None else local_experts[0]
+    y, held = _grouped_ffn(cfg, p, x_flat, weights, ids, first, valid)
+    return y, aux, held
 
 
-def _moe_shard_map(p, cfg, x, ctx):
+def _moe_shard_map(p, cfg, x, ctx, valid):
     """Explicit TP-MoE: tokens replicated over the expert ('model') axis,
     each shard computes its local experts, psum combines.  Dispatch
     scatters stay device-local (deterministic memory — a GSPMD scatter
@@ -182,13 +220,14 @@ def _moe_shard_map(p, cfg, x, ctx):
     e = cfg.moe_num_experts
     e_loc = e // n_exp_shards
 
-    def local_fn(x_loc, router, wg, wu, wd):
+    def local_fn(x_loc, v_loc, router, wg, wu, wd):
         b_loc, s, d = x_loc.shape
         shard = jax.lax.axis_index(exp_axis)
         pp = {"router": router, "wg": wg, "wu": wu, "wd": wd}
-        y, aux = moe_ffn_tokens(
+        y, aux, _ = moe_ffn_tokens(
             cfg, pp, x_loc.reshape(b_loc * s, d),
             local_experts=(shard * e_loc, e_loc),
+            valid=v_loc.reshape(b_loc * s),
         )
         y = jax.lax.psum(y, exp_axis)
         aux = jax.lax.pmean(aux, exp_axis)
@@ -201,6 +240,7 @@ def _moe_shard_map(p, cfg, x, ctx):
         mesh=mesh,
         in_specs=(
             P(batch_axis, None, None),
+            P(batch_axis, None),
             P(None, None),
             P(exp_axis, None, None),
             P(exp_axis, None, None),
@@ -209,28 +249,37 @@ def _moe_shard_map(p, cfg, x, ctx):
         out_specs=(P(batch_axis, None, None), P()),
         check=False,
     )
-    return fn(x, p["router"], p["wg"], p["wu"], p["wd"])
+    if valid is None:
+        valid = jnp.ones(x.shape[:2], bool)
+    return fn(x, valid, p["router"], p["wg"], p["wu"], p["wd"])
 
 
-def apply_moe(p, cfg, x):
-    """MoE forward: x [B,S,d] -> [B,S,d], plus aux loss.
+def apply_moe(p, cfg, x, *, valid=None):
+    """MoE forward: x [B,S,d] -> ([B,S,d], aux loss, held).
 
-    Under a mesh the TP-MoE shard_map path runs (experts sharded over the
-    'model' axis, one activation psum per block); on a single device the
-    plain dense path runs.  The EP all-to-all variant lives in
-    parallel/collectives.py and is switched in by the hillclimb configs.
+    ``valid`` [B,S] bool marks the real rows (None: all); the others get
+    no expert assignment.  Under a mesh the TP-MoE shard_map path runs
+    (experts sharded over the 'model' axis, one activation psum per
+    block); on a single device the dropless held-expert path runs, and
+    ``held`` counts the (token, held expert) assignments it computed
+    (None under a mesh).  The EP all-to-all variant lives in
+    parallel/collectives.py and is switched in by the hillclimb configs
+    (it ignores ``valid``).
     """
     from repro.parallel.sharding import current_mesh
 
     b, s, d = x.shape
     ctx = current_mesh()
+    held = None
     if ctx is not None and ctx.mesh is not None and cfg.moe_ep:
         from repro.parallel.collectives import moe_ep_alltoall
         y, aux = moe_ep_alltoall(cfg, p, x, ctx)
     elif ctx is not None and ctx.mesh is not None:
-        y, aux = _moe_shard_map(p, cfg, x, ctx)
+        y, aux = _moe_shard_map(p, cfg, x, ctx, valid)
     else:
-        y, aux = moe_ffn_tokens(cfg, p, x.reshape(b * s, d))
+        y, aux, held = moe_ffn_tokens(
+            cfg, p, x.reshape(b * s, d),
+            valid=None if valid is None else valid.reshape(b * s))
         y = y.reshape(b, s, d)
     if cfg.moe_shared_experts:
         act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
@@ -238,4 +287,4 @@ def apply_moe(p, cfg, x):
             planned_dense(x, p["shared_wu"], site="moe.shared_up")
         h = constrain(h, "batch", None, "ff")
         y = y + planned_dense(h, p["shared_wd"], site="moe.shared_down")
-    return y, aux
+    return y, aux, held

@@ -100,7 +100,7 @@ def _apply_block(p, cfg, x, positions, *, moe: bool):
     x = x + attn
     h = L.apply_norm(p["ln2"], cfg, x)
     if moe:
-        y, aux = MOE.apply_moe(p["moe"], cfg, h)
+        y, aux, _ = MOE.apply_moe(p["moe"], cfg, h)
     else:
         y, aux = L.apply_mlp(p["mlp"], cfg, h), jnp.zeros((), jnp.float32)
     x = x + y
@@ -320,7 +320,7 @@ def _decode_blocks(stacked, cfg, x, cache_slices, pos, *, moe: bool,
         x = x + attn
         h = L.apply_norm(lp["ln2"], cfg, x)
         if moe:
-            y, _ = MOE.apply_moe(lp["moe"], cfg, h)
+            y, _, _ = MOE.apply_moe(lp["moe"], cfg, h)
         else:
             y = L.apply_mlp(lp["mlp"], cfg, h)
         return x + y, new_cs
@@ -395,14 +395,19 @@ def init_paged_pools(cfg, num_blocks, block_size, max_lanes,
 
 
 def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
-                         pos, *, moe: bool):
+                         pos, active, *, moe: bool):
     """Paged twin of ``_decode_blocks``.  The stacked [L, NB, bs, ...]
     pools stay whole and loop-invariant: the scan's inputs are the layer
     parameters and the layer indices ``layers``, each layer gathers its
     lanes' rows straight from the stacked pools, and the scan returns
     each layer's new rows ([n, B, ...] per leaf) for the caller to write
     once.  Pools passed as the scan's inputs and outputs would be sliced
-    out and written back whole, layer by layer, on every step."""
+    out and written back whole, layer by layer, on every step.  MoE
+    layers route only the active lanes; the scan also returns each MoE
+    layer's count of (token, held expert) assignments (None for dense
+    layers)."""
+    valid = active[:, None]
+
     def body(x, inp):
         lp, layer = inp
         h = L.apply_norm(lp["ln1"], cfg, x)
@@ -418,18 +423,21 @@ def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
             rows = {"k": k, "v": v}
         x = x + attn
         h = L.apply_norm(lp["ln2"], cfg, x)
+        held = None
         if moe:
-            y, _ = MOE.apply_moe(lp["moe"], cfg, h)
+            y, _, held = MOE.apply_moe(lp["moe"], cfg, h, valid=valid)
         else:
             y = L.apply_mlp(lp["mlp"], cfg, h)
-        return x + y, rows
+        return x + y, (rows, held)
 
     return jax.lax.scan(body, x, (stacked, layers), unroll=cfg.scan_unroll)
 
 
 def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
     """Block-paged decode: tokens [B,1]; block_tables [B,T] int32; pos
-    [B] int32; active [B] bool -> (logits [B,V], new pools).
+    [B] int32; active [B] bool -> (logits [B,V], new pools), and for an
+    MoE model a third output: the step's (token, held expert) assignments
+    of the active lanes, summed over the MoE layers (int32 scalar).
 
     ``pos``/tables/``active`` are host-owned inputs (the engine advances
     pos and edits tables between steps), so the compiled executable's
@@ -441,12 +449,15 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
     groups = (("dense_layers", 0, n_dense, False),
               ("moe_layers", n_dense, cfg.n_layers, True))
     parts = []
+    held = []
     for name, lo, hi, moe in groups:
         if p.get(name) is None:
             continue
-        x, rows = _decode_blocks_paged(
+        x, (rows, n) = _decode_blocks_paged(
             p[name], cfg, x, pools, jnp.arange(lo, hi), block_tables, pos,
-            moe=moe)
+            active, moe=moe)
+        if moe:
+            held.append(jnp.sum(n))
         parts.append(rows)
     x = L.apply_norm(p["ln_f"], cfg, x)
     logits = logits_fn(p, cfg, x)[:, 0]
@@ -455,6 +466,8 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
     new_pools = {
         k: L.paged_write_layers(pool, rows[k], block_tables, pos, active)
         for k, pool in pools.items()}
+    if held:
+        return logits, new_pools, sum(held)
     return logits, new_pools
 
 
@@ -470,12 +483,18 @@ def prefill(p, cfg, tokens, max_seq, cache_dtype=jnp.bfloat16,
     ``tokens`` may be right-padded to a bucket length and logits are then
     taken at each lane's last valid token instead of position -1, with
     ``cache["pos"]`` set past it.  Pad rows land in the cache but the
-    decode mask (``kpos <= pos``) hides them until overwritten.
+    decode mask (``kpos <= pos``) hides them until overwritten, and MoE
+    layers route no pad row to an expert (routing is dropless, so a real
+    row's output never depends on the others).
     """
     b, s = tokens.shape
     x = embed_tokens(p, cfg, tokens, extra_embeds)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
     cache = init_cache(cfg, b, max_seq, cache_dtype)
+    # rows past each lane's last valid token are bucket padding: MoE
+    # layers give them no expert assignment
+    valid = None if last_index is None else (
+        positions <= (x.shape[1] - s + last_index)[:, None])
 
     def mk_body(moe: bool):
         def body(x, lp):
@@ -496,7 +515,7 @@ def prefill(p, cfg, tokens, max_seq, cache_dtype=jnp.bfloat16,
             x = x + attn
             h = L.apply_norm(lp["ln2"], cfg, x)
             if moe:
-                y, _ = MOE.apply_moe(lp["moe"], cfg, h)
+                y, _, _ = MOE.apply_moe(lp["moe"], cfg, h, valid=valid)
             else:
                 y = L.apply_mlp(lp["mlp"], cfg, h)
             return x + y, entry

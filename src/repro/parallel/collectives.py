@@ -137,6 +137,8 @@ def moe_ep_alltoall(cfg, p, x, ctx):
     buffer is 1/ep the size of the TP-MoE path and the collective is two
     all_to_alls of the *dispatched* tokens instead of a psum of ALL tokens
     — the congestion-model win the paper's PLIO assignment corresponds to.
+    The exchange is capacity-sized (ceil(T·k/E · cf) slots per expert,
+    drop-on-overflow), and the mesh holds all of the router's experts.
     """
     from repro.models.moe import _dispatch_indices, _expert_ffn, route
 

@@ -257,9 +257,13 @@ class PagedServeEngine(EngineBase):
     for every lane at full horizon — shrink it to oversubscribe and
     exercise preemption).  ``stats`` tracks ``decode_compiles`` (pinned
     at 1 by the tests), ``prefill_compiles`` (one per bucket),
-    ``preemptions`` and ``steps``.  Each request counts the prompt rows
-    prefilled for it, real (``prefill_tokens``) and as their buckets
-    computed them (``prefill_padded_tokens``).
+    ``preemptions`` and ``steps``; for an MoE model also
+    ``moe_held_assignments``, the (token, held expert) assignments that
+    decode steps computed for active lanes, summed over MoE layers (it
+    comes back with the sampled tokens: no sync of its own).  Each
+    request counts the prompt rows prefilled for it, real
+    (``prefill_tokens``) and as their buckets computed them
+    (``prefill_padded_tokens``).
 
     Each ``step()`` is a profiler step span ``repro/serve.step`` holding
     the spans ``admit`` (with one ``prefill`` per request, carrying its
@@ -286,12 +290,11 @@ class PagedServeEngine(EngineBase):
         if isinstance(scheduler, SchedulerConfig):
             scheduler = Scheduler(scheduler)
         self.scheduler = scheduler or Scheduler()
-        # bucket pads are invisible to masked attention, but not to every
-        # family: recurrent prompt state (ssm/hybrid) absorbs pad tokens,
-        # and capacity-limited MoE routing lets pads compete with real
-        # tokens for expert slots — both would change outputs.  those
-        # families prefill at exact lengths; dense/vlm/encdec bucket.
-        self._exact_prefill = cfg.family in ("ssm", "hybrid", "moe")
+        # bucket pads are invisible to masked attention and to dropless
+        # MoE routing (pad rows get no expert), but recurrent prompt state
+        # (ssm/hybrid) absorbs pad tokens: those families prefill at exact
+        # lengths; dense/moe/vlm/encdec bucket.
+        self._exact_prefill = cfg.family in ("ssm", "hybrid")
         self.kv: PagedKVCache | None = None
         self.lanes: list[Request | None] = [None] * max_lanes
         self._admit_seq = 0
@@ -300,6 +303,8 @@ class PagedServeEngine(EngineBase):
         self._decode_exec = None
         self.stats = {"decode_compiles": 0, "prefill_compiles": 0,
                       "preemptions": 0, "steps": 0}
+        if cfg.family == "moe":
+            self.stats["moe_held_assignments"] = 0
 
     # -- load ---------------------------------------------------------------
     def load(self, params):
@@ -559,12 +564,15 @@ class PagedServeEngine(EngineBase):
             for i in active:
                 tokens[i, 0] = self.lanes[i].output[-1]
             bt, pos, act = self.kv.device_args()
-            logits, self.kv.pools = self._decode_exec(
+            # an MoE model's step also returns its held-expert count
+            logits, self.kv.pools, *held = self._decode_exec(
                 self.params, self.kv.pools, jnp.asarray(tokens), bt, pos,
                 act)
         self.stats["steps"] += 1
         with span("sample"):
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            nxt, held = jax.device_get((jnp.argmax(logits, axis=-1), held))
+            for n in held:
+                self.stats["moe_held_assignments"] += int(n)
             for i in active:
                 req = self.lanes[i]
                 req.output.append(int(nxt[i]))

@@ -183,7 +183,9 @@ class PagedKVCache:
                         pool, src[:, 0], lane, axis=1)
             return new
 
-        fn = jax.jit(write_prefill)
+        # the pools are donated: the lane's rows are scattered into them
+        # in place instead of into a copy of every pool
+        fn = jax.jit(write_prefill, donate_argnums=(0,))
         self._write_fns[rows] = fn
         return fn
 

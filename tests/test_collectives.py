@@ -62,14 +62,14 @@ cfg = dataclasses.replace(cfg, dtype="float32", moe_capacity_factor=8.0)
 p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
 x = jnp.asarray(rng.standard_normal((8, 4, cfg.d_model)), jnp.float32)
 
-y_ref, aux_ref = MOE.apply_moe(p, cfg, x)  # no mesh: dense path
+y_ref, aux_ref, _ = MOE.apply_moe(p, cfg, x)  # no mesh: dense path
 
 mesh2 = make_mesh((2, 4), ("data", "model"))
 with mesh_context(mesh2):
-    y_tp, aux_tp = jax.jit(lambda p, x: MOE.apply_moe(p, cfg, x))(p, x)
+    y_tp, aux_tp, _ = jax.jit(lambda p, x: MOE.apply_moe(p, cfg, x))(p, x)
 cfg_ep = dataclasses.replace(cfg, moe_ep=True)
 with mesh_context(mesh2):
-    y_ep, aux_ep = jax.jit(lambda p, x: MOE.apply_moe(p, cfg_ep, x))(p, x)
+    y_ep, aux_ep, _ = jax.jit(lambda p, x: MOE.apply_moe(p, cfg_ep, x))(p, x)
 
 # capacity semantics differ across shardings when tokens drop; with a high
 # capacity factor nothing drops and all paths must agree.

@@ -148,8 +148,8 @@ def test_moe_tp_equals_dense_when_single_shard():
     x = jnp.asarray(
         np.random.default_rng(2).standard_normal((32, cfg.d_model)),
         jnp.float32)
-    y1, _ = moe_ffn_tokens(cfg, p, x)
-    y2, _ = moe_ffn_tokens(cfg, p, x,
+    y1, _, _ = moe_ffn_tokens(cfg, p, x)
+    y2, _, _ = moe_ffn_tokens(cfg, p, x,
                            local_experts=(0, cfg.moe_num_experts))
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
 
