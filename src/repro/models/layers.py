@@ -18,6 +18,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import paged_attention as PA
 from repro.kernels.planned import (planned_bmm, planned_dense,
                                    planned_mlp_pair)
 from repro.parallel.sharding import constrain
@@ -537,18 +538,48 @@ def apply_attention_decode_paged(p, cfg, x, pool_k, pool_v, block_tables,
     return out, pool_k, pool_v
 
 
+def _paged_kernel_attention(cfg, q, k, v, pool_k, pool_v, layer,
+                            block_tables, rows):
+    """One-token GQA attention through ``kernels.paged_attention``: each
+    lane's pooled rows ``0 .. rows-1`` read in place, and its new row
+    (``k``, ``v`` [B, Hkv*hd], not in the pools yet) in the same softmax.
+    q [B, 1, Hq, hd] -> [B, 1, Hq*hd] in the compute dtype."""
+    b = q.shape[0]
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    dt = _dtype(cfg)
+    # query head j in the hd columns of its kv head j // G, zeros elsewhere
+    own = jax.nn.one_hot(jnp.arange(hq) // (hq // hkv), hkv, dtype=dt)
+    with jax.named_scope("attn.paged_attention"):
+        q_bd = q.reshape(b, hq, 1, hd).astype(dt) * own[None, :, :, None]
+        out = PA.paged_attention(
+            q_bd.reshape(b, hq, hkv * hd), k, v, pool_k, pool_v, layer,
+            block_tables, rows, scale=1.0 / math.sqrt(hd))
+    # head j = h*G + g keeps the columns of kv head h
+    out = jnp.diagonal(out.reshape(b, hkv, hq // hkv, hkv, hd),
+                       axis1=1, axis2=3)
+    return jnp.moveaxis(out, -1, 1).reshape(b, 1, hq * hd)
+
+
 def apply_attention_decode_stacked(p, cfg, x, pool_k, pool_v, layer,
-                                   block_tables, pos):
+                                   block_tables, pos, active):
     """One-token decode of layer ``layer`` against stacked [L, NB, bs,
-    ...] block pools, which it only reads: the new K/V row joins the
-    gathered sequence at ``pos`` and is returned, cast to the pool dtype,
-    for ``paged_write_layers`` to store after the layer scan.  Same math
-    and bits as ``apply_attention_decode_paged`` for every active lane."""
+    ...] block pools, which it only reads: the new K/V row is returned,
+    cast to the pool dtype, for ``paged_write_layers`` to store after the
+    layer scan.  Where ``kernels.paged_attention`` engages on the pools,
+    it reads each active lane's rows below ``pos`` in place and joins
+    the new row to their softmax.  Otherwise the new row joins the
+    gathered sequence at ``pos`` under the mask: the same math and bits
+    as ``apply_attention_decode_paged`` for every active lane."""
     b = x.shape[0]
     q, k, v = _qkv(p, cfg, x, pos[:, None])
     # pool rows hold all heads flattened: [B, Hkv*hd]
     k = k.reshape(b, -1).astype(pool_k.dtype)
     v = v.reshape(b, -1).astype(pool_v.dtype)
+    if PA.engages(pool_k, cfg):
+        # an inactive lane reads no pooled row; its output is not used
+        out = _paged_kernel_attention(cfg, q, k, v, pool_k, pool_v, layer,
+                                      block_tables, jnp.where(active, pos, 0))
+        return planned_dense(out, p["wo"], site="attn.out"), k, v
     kseq = with_row_at(paged_gather(pool_k, block_tables, layer), k, pos)
     vseq = with_row_at(paged_gather(pool_v, block_tables, layer), v, pos)
     heads = (cfg.n_kv_heads, cfg.hd)

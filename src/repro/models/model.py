@@ -59,6 +59,9 @@ class ModelAPI:
     paged_init: Callable = None
     paged_decode: Callable = None
     paged_layout: Callable = None
+    # paged_kernel(pools) -> whether paged_decode attends through
+    # kernels.paged_attention on these pools (None: never)
+    paged_kernel: Callable = None
     # streaming (chunked) admission — encdec only.  enc_init(b, f_max)
     # builds the incremental encoder state; enc_step(p, ec, frames_chunk)
     # appends one chunk and returns its encoder states; enc_kv(p, enc)
@@ -138,6 +141,7 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
             paged_decode=lambda p, pools, t, bt, pos, act:
                 TFM.decode_step_paged(p, cfg, pools, t, bt, pos, act),
             paged_layout=lambda: TFM.paged_layout(cfg),
+            paged_kernel=lambda pools: TFM.paged_kernel(cfg, pools),
         )
 
     if cfg.family in ("ssm", "hybrid"):

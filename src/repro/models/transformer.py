@@ -21,6 +21,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import paged_attention as PA
 from repro.kernels.planned import planned_dense
 from repro.parallel.sharding import constrain
 from . import layers as L
@@ -394,6 +395,13 @@ def init_paged_pools(cfg, num_blocks, block_size, max_lanes,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def paged_kernel(cfg, pools) -> bool:
+    """Whether ``decode_step_paged`` attends through
+    ``kernels.paged_attention`` on these pools (MLA's latent pools keep
+    their own path)."""
+    return "k" in pools and PA.engages(pools["k"], cfg)
+
+
 def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
                          pos, active, *, moe: bool):
     """Paged twin of ``_decode_blocks``.  The stacked [L, NB, bs, ...]
@@ -419,7 +427,7 @@ def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
         else:
             attn, k, v = L.apply_attention_decode_stacked(
                 lp["attn"], cfg, h, pools["k"], pools["v"], layer,
-                block_tables, pos)
+                block_tables, pos, active)
             rows = {"k": k, "v": v}
         x = x + attn
         h = L.apply_norm(lp["ln2"], cfg, x)

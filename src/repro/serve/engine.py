@@ -260,7 +260,11 @@ class PagedServeEngine(EngineBase):
     ``preemptions`` and ``steps``; for an MoE model also
     ``moe_held_assignments``, the (token, held expert) assignments that
     decode steps computed for active lanes, summed over MoE layers (it
-    comes back with the sampled tokens: no sync of its own).  Each
+    comes back with the sampled tokens: no sync of its own).
+    ``decode_kernel_steps`` counts the decode steps whose program
+    attends through ``kernels.paged_attention``, and ``decode_kv_rows``
+    the K/V rows those steps' kernel read per layer: each active lane's
+    ``pos + 1`` (its pooled rows and its new one).  Each
     request counts the prompt rows prefilled for it, real
     (``prefill_tokens``) and as their buckets computed them
     (``prefill_padded_tokens``).
@@ -301,8 +305,10 @@ class PagedServeEngine(EngineBase):
         self._lane_seq: dict[int, int] = {}
         self._prefill_fns: dict = {}
         self._decode_exec = None
+        self._decode_kernel = False
         self.stats = {"decode_compiles": 0, "prefill_compiles": 0,
-                      "preemptions": 0, "steps": 0}
+                      "preemptions": 0, "steps": 0,
+                      "decode_kernel_steps": 0, "decode_kv_rows": 0}
         if cfg.family == "moe":
             self.stats["moe_held_assignments"] = 0
 
@@ -331,6 +337,8 @@ class PagedServeEngine(EngineBase):
             self.api, max_lanes=self.max_lanes, max_seq=self.max_seq,
             block_size=self.block_size, num_blocks=self.num_blocks)
         self.num_blocks = self.kv.num_blocks
+        self._decode_kernel = bool(
+            self.api.paged_kernel and self.api.paged_kernel(self.kv.pools))
         before = planned.planned_report()
         tune0 = autotune.counters()
 
@@ -569,6 +577,10 @@ class PagedServeEngine(EngineBase):
                 self.params, self.kv.pools, jnp.asarray(tokens), bt, pos,
                 act)
         self.stats["steps"] += 1
+        if self._decode_kernel:
+            self.stats["decode_kernel_steps"] += 1
+            self.stats["decode_kv_rows"] += int(
+                self.kv.pos[active].sum()) + len(active)
         with span("sample"):
             nxt, held = jax.device_get((jnp.argmax(logits, axis=-1), held))
             for n in held:

@@ -93,3 +93,30 @@ def test_qwen_facade_gemm_compiles_for_v5e(one_chip, k, n, m):
     shapes = (jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
               jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
     _compile_mosaic(plan, _on(one_chip, shapes))
+
+
+@pytest.mark.parametrize("pool_dtype,bs", [("bfloat16", 16),
+                                           ("float8_e4m3fn", 32)])
+def test_paged_attention_compiles_for_v5e(one_chip, pool_dtype, bs):
+    """The paged decode attention kernel at qwen1.5-0.5b's serving shapes:
+    16 lanes of 2048 rows, 24 layers, 16 kv heads of 64 (rows of 1024),
+    bf16 queries; the fp8 pool in blocks of one 32-row tile."""
+    from repro.configs.base import ModelConfig
+    from repro.kernels import paged_attention as PA
+
+    lanes, heads, row, seq = 16, 16, 1024, 2048
+    blocks = lanes * seq // bs
+    pool = jax.ShapeDtypeStruct((24, blocks, bs, row), pool_dtype)
+    cfg = ModelConfig(name="qwen1.5-0.5b", family="dense", n_layers=24,
+                      d_model=1024, n_heads=heads, n_kv_heads=heads,
+                      d_ff=2816, vocab=151936)
+    assert PA.engages(pool, cfg)
+    new = jax.ShapeDtypeStruct((lanes, row), pool_dtype)
+    shapes = (jax.ShapeDtypeStruct((lanes, heads, row), jnp.bfloat16),
+              new, new, pool, pool, jax.ShapeDtypeStruct((), jnp.int32),
+              jax.ShapeDtypeStruct((lanes, seq // bs), jnp.int32),
+              jax.ShapeDtypeStruct((lanes,), jnp.int32))
+    compiled = jax.jit(lambda *a: PA.paged_attention(
+        *a, scale=0.125, interpret=False)).lower(
+            *_on(one_chip, shapes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
