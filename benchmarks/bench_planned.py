@@ -9,7 +9,7 @@ Two sections:
     is a validity/overhead check, not a TPU number — the interesting
     output is the plan (tiles, utilization) per shape.
   * **Call-site report** — one transformer forward + decode step and a
-    2-request ServeEngine drain, followed by ``planned_report()``: which
+    2-request serving-engine drain, followed by ``planned_report()``: which
     call sites executed mapper-planned kernels and which fell back.
 
     PYTHONPATH=src python benchmarks/bench_planned.py [--smoke]
@@ -95,7 +95,7 @@ def bench_gemms(smoke: bool):
 def report_model_sites():
     from repro.configs import get_smoke_config
     from repro.models import build_model
-    from repro.serve import ServeEngine
+    from repro.serve import make_engine
 
     cfg = get_smoke_config("qwen1.5-0.5b")
     api = build_model(cfg)
@@ -106,7 +106,7 @@ def report_model_sites():
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)
     api.loss(params, {"tokens": toks, "labels": toks})
 
-    eng = ServeEngine(cfg, max_slots=2, max_seq=32)
+    eng = make_engine(cfg, max_lanes=2, max_seq=32, block_size=8)
     eng.load(params)
     for _ in range(2):
         eng.submit(rng.integers(0, cfg.vocab, 6), max_new_tokens=4)

@@ -10,11 +10,8 @@ This keeps the workload deterministic (same seed -> same arrival
 pattern and prompt lengths -> same admission order) while the timings
 remain real measurements of the engine's step cost.
 
-Works against both engines (``ServeEngine`` / ``PagedServeEngine``) —
-anything with ``submit / step / finished`` and per-lane occupancy.
-
     PYTHONPATH=src python -m benchmarks.bench_serving \
-        --arch qwen1.5-0.5b --engine paged --rates 2,8 --requests 16
+        --arch qwen1.5-0.5b --rates 2,8 --requests 16
 """
 
 from __future__ import annotations
@@ -26,10 +23,7 @@ import numpy as np
 
 
 def _occupied(engine) -> int:
-    lanes = getattr(engine, "lanes", None)
-    if lanes is None:
-        lanes = engine.slots
-    return sum(r is not None for r in lanes)
+    return sum(r is not None for r in engine.lanes)
 
 
 def make_requests(cfg, n: int, *, seed: int, prompt_lens=(4, 20),
@@ -53,7 +47,7 @@ def run_load(engine, requests, *, rate: float, seed: int = 0) -> dict:
     rng = np.random.default_rng(seed)
     arrivals = np.cumsum(rng.exponential(1.0 / rate, len(requests)))
     done_offset = len(engine.finished)
-    stats0 = dict(getattr(engine, "stats", {}))
+    stats0 = dict(engine.stats)
     now = 0.0
     submitted = 0
     submit_time: dict = {}
@@ -81,7 +75,7 @@ def run_load(engine, requests, *, rate: float, seed: int = 0) -> dict:
         for r in engine.finished[done_offset:]])
     tokens = sum(len(r.output) for r in engine.finished[done_offset:])
     makespan = max(now, 1e-9)
-    stats1 = dict(getattr(engine, "stats", {}))
+    stats1 = dict(engine.stats)
     return {
         "rate": rate,
         "requests": len(requests),
@@ -90,12 +84,11 @@ def run_load(engine, requests, *, rate: float, seed: int = 0) -> dict:
         "p50_ms": round(float(np.percentile(lat, 50)) * 1e3, 1),
         "p99_ms": round(float(np.percentile(lat, 99)) * 1e3, 1),
         "steps": steps,
-        "preemptions": (stats1.get("preemptions", 0)
-                        - stats0.get("preemptions", 0)),
-        # compiles after load() == in-flight recompiles; the paged
-        # engine's AOT invariant pins this at 0
-        "decode_recompiles": (stats1.get("decode_compiles", 1)
-                              - stats0.get("decode_compiles", 1)),
+        "preemptions": stats1["preemptions"] - stats0["preemptions"],
+        # compiles after load() == in-flight recompiles; the engine's
+        # AOT invariant pins this at 0
+        "decode_recompiles": (stats1["decode_compiles"]
+                              - stats0["decode_compiles"]),
     }
 
 
@@ -105,12 +98,9 @@ def warmup(engine, cfg, *, seed: int = 99, max_new: int = 2,
     compilation happens outside the timed window (steady-state measure,
     the same contract the kernel benches use)."""
     lo, hi = prompt_lens
-    lens = {lo, hi}
-    sched = getattr(engine, "scheduler", None)
-    if sched is not None:
-        exact = getattr(engine, "_exact_prefill", False)
-        lens = {sched.bucket_for(n, exact=exact) for n in range(lo, hi + 1)}
-        lens = {min(n, engine.max_seq - max_new) for n in lens}
+    lens = {engine.scheduler.bucket_for(n, exact=engine._exact_prefill)
+            for n in range(lo, hi + 1)}
+    lens = {min(n, engine.max_seq - max_new) for n in lens}
     rng = np.random.default_rng(seed)
     for n in sorted(lens):
         engine.submit(rng.integers(0, cfg.vocab, n).astype(np.int32),
@@ -129,7 +119,7 @@ def sweep(engine, cfg, rates, *, requests: int = 16, seed: int = 0,
     return rows
 
 
-def build_engine(arch: str, kind: str, *, max_lanes: int = 4,
+def build_engine(arch: str, *, max_lanes: int = 4,
                  max_seq: int = 64, block_size: int = 8,
                  num_blocks: int | None = None, seed: int = 42):
     import jax
@@ -140,13 +130,8 @@ def build_engine(arch: str, kind: str, *, max_lanes: int = 4,
 
     cfg = get_smoke_config(arch)
     params = build_model(cfg).init(jax.random.PRNGKey(seed))
-    if kind == "paged":
-        eng = make_engine(cfg, kind=kind, max_lanes=max_lanes,
-                          max_seq=max_seq, block_size=block_size,
-                          num_blocks=num_blocks)
-    else:
-        eng = make_engine(cfg, kind=kind, max_slots=max_lanes,
-                          max_seq=max_seq)
+    eng = make_engine(cfg, max_lanes=max_lanes, max_seq=max_seq,
+                      block_size=block_size, num_blocks=num_blocks)
     eng.load(params)
     return cfg, eng
 
@@ -154,7 +139,6 @@ def build_engine(arch: str, kind: str, *, max_lanes: int = 4,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
-    ap.add_argument("--engine", default="paged", choices=["paged", "slot"])
     ap.add_argument("--rates", default="2,8",
                     help="comma-separated Poisson arrival rates (req/s)")
     ap.add_argument("--requests", type=int, default=16)
@@ -165,7 +149,7 @@ def main() -> None:
     ap.add_argument("--num-blocks", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-    cfg, eng = build_engine(args.arch, args.engine, max_lanes=args.lanes,
+    cfg, eng = build_engine(args.arch, max_lanes=args.lanes,
                             max_seq=args.max_seq,
                             block_size=args.block_size,
                             num_blocks=args.num_blocks)

@@ -138,7 +138,7 @@ def ci_bench(out_path: str) -> dict:
                  "unfused stage launches), hierarchical rows (two-level "
                  "serving GEMMs vs the flat single-mesh plan: outer "
                  "collective bytes gate exactly), serving rows "
-                 "(paged vs slot engine at one smoke arrival rate) and "
+                 "(the serving engine at one smoke arrival rate) and "
                  "streaming rows (planned audio frontend vs XLA, "
                  "chunked vs offline first-frame latency, steady-state "
                  "retrace counters gated exactly); compare with "
@@ -337,24 +337,21 @@ def _ci_bench_hierarchy(policy, rng) -> dict:
     return out
 
 
-#: Serving smoke workload: one arrival rate, both engines, identical
-#: seeded request stream.  Chosen so the queue actually builds (the
-#: paged engine's bucketed-prefill advantage is visible) without
-#: oversubscribing the block pool (preemptions stay deterministic: 0).
+#: Serving smoke workload: one arrival rate, one seeded request stream.
+#: Chosen so the queue actually builds without oversubscribing the
+#: block pool (preemptions stay deterministic: 0).
 CI_SERVING_CASE = dict(arch="qwen1.5-0.5b", rate=8.0, requests=10,
                        max_new=4, lanes=4, max_seq=64, block_size=8,
                        seed=0)
 
 
 def _ci_bench_serving() -> dict:
-    """Paged vs slot serving rows for the gate.
+    """The serving engine's row for the gate, keyed ``paged``.
 
     Latencies are wall-time measurements (machine-normalized by the
     comparator like the spec timings); ``decode_recompiles`` and
-    ``preemptions`` are deterministic and gate exactly — the paged
-    engine's AOT invariant pins recompiles at 0.  Both engines serve the
-    *same* seeded request stream, so the same-run throughput ordering
-    (paged > slot) is gated without normalization."""
+    ``preemptions`` are deterministic and gate exactly — the engine's
+    AOT invariant pins recompiles at 0."""
     try:
         from benchmarks.bench_serving import (build_engine, make_requests,
                                               run_load, warmup)
@@ -368,22 +365,19 @@ def _ci_bench_serving() -> dict:
     arch, rate = case.pop("arch"), case.pop("rate")
     n, seed = case.pop("requests"), case.pop("seed")
     max_new = case.pop("max_new")
-    out: dict = {}
-    for kind in ("paged", "slot"):
-        cfg, eng = build_engine(arch, kind, max_lanes=case["lanes"],
-                                max_seq=case["max_seq"],
-                                block_size=case["block_size"])
-        warmup(eng, cfg, max_new=max_new)
-        reqs = make_requests(cfg, n, seed=seed, max_new=max_new)
-        row = run_load(eng, reqs, rate=rate, seed=seed)
-        row["arch"] = arch
-        out[kind] = row
-        print(f"ci-bench serving {kind:5s} {arch:13s} rate={rate:.0f}/s "
-              f"tok/s={row['tokens_per_sec']:8.2f} "
-              f"p99={row['p99_ms']:8.1f}ms "
-              f"preempt={row['preemptions']} "
-              f"recompiles={row['decode_recompiles']}")
-    return out
+    cfg, eng = build_engine(arch, max_lanes=case["lanes"],
+                            max_seq=case["max_seq"],
+                            block_size=case["block_size"])
+    warmup(eng, cfg, max_new=max_new)
+    reqs = make_requests(cfg, n, seed=seed, max_new=max_new)
+    row = run_load(eng, reqs, rate=rate, seed=seed)
+    row["arch"] = arch
+    print(f"ci-bench serving paged {arch:13s} rate={rate:.0f}/s "
+          f"tok/s={row['tokens_per_sec']:8.2f} "
+          f"p99={row['p99_ms']:8.1f}ms "
+          f"preempt={row['preemptions']} "
+          f"recompiles={row['decode_recompiles']}")
+    return {"paged": row}
 
 
 #: Streaming smoke workload: the audio-frontend chunk pipeline plus a
@@ -508,7 +502,7 @@ def _ci_bench_streaming() -> dict:
           f"offline={offline_us:8.1f}us x{first_frame_row['ratio']:.2f}")
 
     # serving steady state: identical second stream must retrace nothing
-    _, eng = build_engine(arch, "paged", max_lanes=case["lanes"],
+    _, eng = build_engine(arch, max_lanes=case["lanes"],
                           max_seq=case["max_seq"],
                           block_size=case["block_size"])
     eng.submit_audio_stream(samples, max_new_tokens=case["max_new"])

@@ -206,8 +206,8 @@ def serving_phase(cfg, *, seed: int, lanes: int = 4, max_seq: int = 2048,
 
     t0 = time.perf_counter()
     eng, params = serve.load_engine(
-        cfg, engine="paged", lanes=lanes, max_seq=max_seq,
-        block_size=block_size, seed=seed)
+        cfg, lanes=lanes, max_seq=max_seq, block_size=block_size,
+        seed=seed)
     print(f"serve {cfg.name}: {cfg.n_layers}L d={cfg.d_model} "
           f"vocab={cfg.vocab} {cfg.dtype}; loaded in "
           f"{time.perf_counter() - t0:.1f}s", flush=True)

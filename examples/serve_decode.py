@@ -14,14 +14,14 @@ import jax
 
 from repro.configs import get_smoke_config
 from repro.models import build_model
-from repro.serve import ServeEngine
+from repro.serve import make_engine
 
 
 def drive(arch: str):
     cfg = get_smoke_config(arch)
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
-    eng = ServeEngine(cfg, max_slots=4, max_seq=64)
+    eng = make_engine(cfg, max_lanes=4, max_seq=64, block_size=8)
     eng.load(params)
     rng = np.random.default_rng(0)
 

@@ -1,7 +1,7 @@
 """Serving launcher CLI.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
-        --requests 16 --max-new 8 [--engine paged] [--published] \
+        --requests 16 --max-new 8 [--published] \
         [--prompt-min 4 --prompt-max 15] [--seed 0] [--stream-audio]
 
 The architecture's ``SMOKE`` preset is served unless ``--published``
@@ -25,17 +25,14 @@ import numpy as np
 import jax
 
 
-def load_engine(cfg, *, engine: str = "paged", lanes: int = 4,
-                max_seq: int = 128, block_size: int = 16, seed: int = 0):
-    """Build and ``load()`` a serving engine for ``cfg`` with random
+def load_engine(cfg, *, lanes: int = 4, max_seq: int = 128,
+                block_size: int = 16, seed: int = 0):
+    """Build and ``load()`` the serving engine for ``cfg`` with random
     weights made on the device from ``seed``.  Returns (engine, params)."""
     from repro.serve import make_engine
 
-    if engine == "paged":
-        kw = dict(max_lanes=lanes, block_size=block_size)
-    else:
-        kw = dict(max_slots=lanes)
-    eng = make_engine(cfg, kind=engine, max_seq=max_seq, **kw)
+    eng = make_engine(cfg, max_lanes=lanes, max_seq=max_seq,
+                      block_size=block_size)
     params = jax.jit(eng.api.init)(jax.random.PRNGKey(seed))
     eng.load(params)
     return eng, params
@@ -79,11 +76,10 @@ def main():
     ap.add_argument("--prompt-max", type=int, default=15)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--slots", type=int, default=4,
-                    help="lanes for either engine")
+                    help="decode lanes")
     ap.add_argument("--max-seq", type=int, default=128)
-    ap.add_argument("--engine", default="slot", choices=["slot", "paged"])
     ap.add_argument("--block-size", type=int, default=16,
-                    help="KV block granularity (paged engine)")
+                    help="KV block granularity")
     ap.add_argument("--stream-audio", action="store_true",
                     help="submit synthesized audio streams through the "
                          "planned frontend (encdec archs only)")
@@ -97,8 +93,7 @@ def main():
 
     enable_compile_cache()
     cfg = (get_config if args.published else get_smoke_config)(args.arch)
-    eng, _ = load_engine(cfg, engine=args.engine, lanes=args.slots,
-                         max_seq=args.max_seq,
+    eng, _ = load_engine(cfg, lanes=args.slots, max_seq=args.max_seq,
                          block_size=args.block_size, seed=args.seed)
 
     if args.stream_audio and eng.frontend is None:
@@ -133,10 +128,9 @@ def main():
     rows = site_rows(planned_report())
     print_sites(rows)
     print(f"autotune (load-time delta): {eng.autotune_report}")
-    if args.engine == "paged":
-        print(f"paged stats: {eng.stats}")
-        assert eng.stats["decode_compiles"] == 1, \
-            "in-flight traffic recompiled the AOT decode executable"
+    print(f"paged stats: {eng.stats}")
+    assert eng.stats["decode_compiles"] == 1, \
+        "in-flight traffic recompiled the AOT decode executable"
     if args.stream_audio:
         # the streaming invariants CI pins: chunk feeds never touch the
         # decode executable, and the frontend's planned stages ran

@@ -475,40 +475,49 @@ def init_paged_pools(cfg, num_blocks, block_size, max_lanes,
 
 
 def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
-    """Block-paged decode twin of ``decode_step``: self-attention K/V via
-    per-lane block tables, cross-attention against the lane's resident
-    encoder K/V."""
+    """Block-paged decode twin of ``decode_step``.  The layer scan reads
+    the stacked self-attention K/V pools in place (the scan's inputs are
+    the layer parameters, the layer indices and the lanes' resident
+    encoder K/V for cross-attention), and every layer's new row is
+    written after it in one scatter per pool, with inactive-lane writes
+    dropped."""
     x = p["embed"][tokens].astype(L._dtype(cfg))
     x = x + jnp.take_along_axis(
         p["pos_dec"][None].astype(x.dtype),
         pos[:, None, None].astype(jnp.int32), axis=1)
 
     def body(x, inp):
-        lp, pk, pv, ek, ev = inp
+        lp, layer, ek, ev = inp
         h = L.apply_norm(lp["ln1"], cfg, x)
-        attn, pk, pv = L.apply_attention_decode_paged(
-            lp["attn"], cfg, h, pk, pv, block_tables, pos, active)
+        attn, k, v = L.apply_attention_decode_stacked(
+            lp["attn"], cfg, h, pools["k"], pools["v"], layer,
+            block_tables, pos, active)
         x = x + attn
         h = L.apply_norm(lp["ln_x"], cfg, x)
         x = x + _cross_attend(lp["xattn"], cfg, h, ek, ev,
                               kv_len=pools["enc_len"])
         h = L.apply_norm(lp["ln2"], cfg, x)
         x = x + L.apply_mlp(lp["mlp"], cfg, h)
-        return x, (pk, pv)
+        return x, (k, v)
 
     x, (ks, vs) = jax.lax.scan(
         body, x,
-        (p["dec_layers"], pools["k"], pools["v"],
-         pools["enc_k"], pools["enc_v"]), unroll=cfg.scan_unroll)
+        (p["dec_layers"], jnp.arange(cfg.n_layers), pools["enc_k"],
+         pools["enc_v"]), unroll=cfg.scan_unroll)
     x = L.apply_norm(p["ln_f"], cfg, x)
     logits = planned_dense(x, p["embed"].T.astype(x.dtype),
                            site="lm_head")[:, 0]
-    new_pools = dict(pools, k=ks, v=vs)
+    new_pools = dict(
+        pools,
+        k=L.paged_write_layers(pools["k"], ks, block_tables, pos, active),
+        v=L.paged_write_layers(pools["v"], vs, block_tables, pos, active))
     return logits, new_pools
 
 
 def decode_step(p, cfg, cache, tokens):
-    """tokens [B,1] -> (logits [B,V], cache)."""
+    """Contiguous-cache decode: tokens [B,1] -> (logits [B,V], cache).
+    No engine serves it; it is the plain reference the paged engine is
+    tested against."""
     b = tokens.shape[0]
     pos = cache["pos"]
     x = p["embed"][tokens].astype(L._dtype(cfg))

@@ -279,8 +279,10 @@ def init_paged_pools(cfg, num_blocks, block_size, max_lanes,
 def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
     """Block-paged decode twin of ``decode_step``.  SSM/conv states are
     per-lane and always advance (inactive lanes evolve garbage that the
-    next admit overwrites); the shared-attention K/V goes through the
-    block tables, with inactive-lane writes dropped."""
+    next admit overwrites).  Shared-attention application ``si`` reads
+    layer ``si`` of the stacked K/V pools in place; every application's
+    new row is written after the trunk in one scatter per pool, with
+    inactive-lane writes dropped."""
     x = p["embed"][tokens].astype(L._dtype(cfg))
     napp = n_attn_apps(cfg)
     conv_dt = pools["conv"].dtype
@@ -304,7 +306,7 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
             lambda a: a[: n_seg * every].reshape(
                 (n_seg, every) + a.shape[1:]),
             (p["trunk"], pools["conv"], pools["ssm"]))
-        new_conv, new_ssm, new_k, new_v = [], [], [], []
+        new_conv, new_ssm, rows_k, rows_v = [], [], [], []
         for si in range(n_seg):
             seg_i = jax.tree.map(lambda a: a[si], seg)
             x, (nc, ns_) = jax.lax.scan(blk_body, x, seg_i,
@@ -312,11 +314,11 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
             new_conv.append(nc)
             new_ssm.append(ns_)
             h = L.apply_norm(p["shared"]["ln1"], cfg, x)
-            attn, pk, pv = L.apply_attention_decode_paged(
-                p["shared"]["attn"], cfg, h, pools["k"][si],
-                pools["v"][si], block_tables, pos, active)
-            new_k.append(pk)
-            new_v.append(pv)
+            attn, k, v = L.apply_attention_decode_stacked(
+                p["shared"]["attn"], cfg, h, pools["k"], pools["v"], si,
+                block_tables, pos, active)
+            rows_k.append(k)
+            rows_v.append(v)
             x = x + attn
             h = L.apply_norm(p["shared"]["ln2"], cfg, x)
             x = x + L.apply_mlp(p["shared"]["mlp"], cfg, h)
@@ -330,11 +332,11 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
             new_conv.append(nc)
             new_ssm.append(ns_)
         new_pools = {
-            "k": jnp.stack(new_k),
-            "v": jnp.stack(new_v),
-            "conv": jnp.concatenate(new_conv, axis=0),
-            "ssm": jnp.concatenate(new_ssm, axis=0),
-        }
+            name: L.paged_write_layers(pools[name], jnp.stack(rows),
+                                       block_tables, pos, active)
+            for name, rows in (("k", rows_k), ("v", rows_v))}
+        new_pools["conv"] = jnp.concatenate(new_conv, axis=0)
+        new_pools["ssm"] = jnp.concatenate(new_ssm, axis=0)
 
     x = L.apply_norm(p["ln_f"], cfg, x)
     logits = planned_dense(x, p["lm_head"].astype(x.dtype),
@@ -343,6 +345,9 @@ def decode_step_paged(p, cfg, pools, tokens, block_tables, pos, active):
 
 
 def decode_step(p, cfg, cache, tokens):
+    """Contiguous-cache decode: tokens [B,1] -> (logits [B,V], cache).
+    No engine serves it; it is the plain reference the paged engine is
+    tested against."""
     b = tokens.shape[0]
     pos = cache["pos"]
     x = p["embed"][tokens].astype(L._dtype(cfg))

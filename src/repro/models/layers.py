@@ -12,7 +12,6 @@ Chip-level sharding still comes from parallel.sharding rules.
 
 from __future__ import annotations
 
-import dataclasses
 import math
 
 import jax
@@ -409,7 +408,7 @@ def apply_attention(p, cfg, x, positions, *, causal=True, chunk=None):
 
 def _masked_decode_attention(p, cfg, q, kseq, vseq, pos, *, sites):
     """Shared one-token GQA decode core: masked scores over a [B,Skv,...]
-    K/V view (contiguous lane cache or block-table gather — the caller
+    K/V view (contiguous cache or block-table gather — the caller
     picks), softmax, value readout, output projection.
 
     Rows with kpos > pos are masked to -1e30, so uninitialized (or
@@ -436,8 +435,11 @@ def _masked_decode_attention(p, cfg, q, kseq, vseq, pos, *, sites):
 def apply_attention_decode(p, cfg, x, cache_k, cache_v, pos):
     """One-token decode: x [B,1,d]; cache [B,S,Hkv,hd]; pos [B] int32.
 
-    Low-precision caches (fp8) are storage-only: reads upcast to the
-    compute dtype (bf16 math, fp8 HBM traffic — the serving pattern)."""
+    The contiguous-cache decode of ``ModelAPI.decode``: no engine serves
+    it, it is the plain reference the paged engine is tested against and
+    what ``launch.dryrun`` lowers.  Low-precision caches (fp8) are
+    storage-only: reads upcast to the compute dtype (bf16 math, fp8 HBM
+    traffic — the serving pattern)."""
     q, k, v = _qkv(p, cfg, x, pos[:, None])
     # write new kv at pos
     cache_k = jax.vmap(
@@ -458,31 +460,14 @@ def apply_attention_decode(p, cfg, x, cache_k, cache_v, pos):
 # block-paged KV cache primitives (continuous-batching serving)
 # ---------------------------------------------------------------------------
 
-def paged_write(pool, new, block_tables, pos, active):
-    """Scatter one token's K/V rows into a block pool.
-
-    pool [NB, bs, ...]; new [B, ...] (one row per lane); block_tables
-    [B, T] int32; pos [B] int32 (the row each lane writes); active [B]
-    bool.  Inactive lanes MUST NOT write — their table rows may point at
-    blocks since re-allocated to another lane — so their flat index is
-    forced out of range and dropped by the scatter (``mode="drop"``),
-    never clamped onto a live row."""
-    with jax.named_scope("kv.write"):
-        nb, bs = pool.shape[0], pool.shape[1]
-        blk = jnp.take_along_axis(
-            block_tables, (pos // bs)[:, None], axis=1)[:, 0]
-        idx = blk * bs + pos % bs
-        idx = jnp.where(active, idx, nb * bs)  # OOB sentinel -> dropped
-        flat = pool.reshape(nb * bs, *pool.shape[2:])
-        flat = flat.at[idx].set(new.astype(pool.dtype), mode="drop")
-        return flat.reshape(pool.shape)
-
-
 def paged_write_layers(pool, rows, block_tables, pos, active):
     """Scatter one token's K/V rows of every layer into a stacked block
-    pool in one scatter: pool [L, NB, bs, ...]; rows [L, B, ...].  Lanes
-    as in ``paged_write``: an inactive lane's index is forced out of
-    range and dropped, never clamped onto a live row."""
+    pool in one scatter: pool [L, NB, bs, ...]; rows [L, B, ...];
+    block_tables [B, T] int32; pos [B] int32 (the row each lane writes);
+    active [B] bool.  Inactive lanes MUST NOT write — their table rows
+    may point at blocks since re-allocated to another lane — so their
+    index is forced out of range and dropped by the scatter
+    (``mode="drop"``), never clamped onto a live row."""
     with jax.named_scope("kv.write"):
         nl, nb, bs = pool.shape[:3]
         blk = jnp.take_along_axis(
@@ -498,17 +483,15 @@ def paged_write_layers(pool, rows, block_tables, pos, active):
         return flat.reshape(pool.shape)
 
 
-def paged_gather(pool, block_tables, layer=None):
-    """Assemble each lane's logical K/V sequence from its block table.
-
-    pool [NB, bs, ...], or a stacked [L, NB, bs, ...] pool with ``layer``
-    picking the layer inside the same gather; block_tables [B, T] ->
-    [B, T*bs, ...].  Rows past the lane's ``pos`` are garbage (freed or
-    never-written blocks) — the decode mask hides them, exactly like the
-    zero tail of a contiguous lane cache."""
+def paged_gather(pool, block_tables, layer):
+    """Assemble each lane's logical K/V sequence of layer ``layer`` from
+    its block table: stacked pool [L, NB, bs, ...]; block_tables [B, T]
+    -> [B, T*bs, ...].  Rows past the lane's ``pos`` are garbage (freed
+    or never-written blocks) — the decode mask hides them, exactly like
+    the zero tail of a contiguous cache."""
     with jax.named_scope("kv.gather"):
         # [B, T, bs, ...]
-        g = pool[block_tables] if layer is None else pool[layer, block_tables]
+        g = pool[layer, block_tables]
         return g.reshape(block_tables.shape[0], -1, *g.shape[3:])
 
 
@@ -518,24 +501,6 @@ def with_row_at(seq, row, pos):
     token's K/V before ``paged_write_layers`` stores it."""
     lanes = jnp.arange(seq.shape[0])
     return seq.at[lanes, pos].set(row.astype(seq.dtype), mode="drop")
-
-
-def apply_attention_decode_paged(p, cfg, x, pool_k, pool_v, block_tables,
-                                 pos, active):
-    """Block-paged one-token decode: same math as
-    ``apply_attention_decode`` but K/V live in a shared block pool indexed
-    through per-lane block tables, so admitting or evicting a lane is a
-    host-side table edit — the compiled executable never changes shape.
-    """
-    q, k, v = _qkv(p, cfg, x, pos[:, None])
-    pool_k = paged_write(pool_k, k[:, 0], block_tables, pos, active)
-    pool_v = paged_write(pool_v, v[:, 0], block_tables, pos, active)
-    kseq = paged_gather(pool_k, block_tables)
-    vseq = paged_gather(pool_v, block_tables)
-    out = _masked_decode_attention(
-        p, cfg, q, kseq, vseq, pos,
-        sites=("attn.paged_scores", "attn.paged_values"))
-    return out, pool_k, pool_v
 
 
 def _paged_kernel_attention(cfg, q, k, v, pool_k, pool_v, layer,
@@ -565,16 +530,18 @@ def apply_attention_decode_stacked(p, cfg, x, pool_k, pool_v, layer,
     """One-token decode of layer ``layer`` against stacked [L, NB, bs,
     ...] block pools, which it only reads: the new K/V row is returned,
     cast to the pool dtype, for ``paged_write_layers`` to store after the
-    layer scan.  Where ``kernels.paged_attention`` engages on the pools,
-    it reads each active lane's rows below ``pos`` in place and joins
-    the new row to their softmax.  Otherwise the new row joins the
-    gathered sequence at ``pos`` under the mask: the same math and bits
-    as ``apply_attention_decode_paged`` for every active lane."""
+    layer scan, shaped as a pool row (all heads flattened, [B, Hkv*hd],
+    or [B, Hkv, hd]).  Where ``kernels.paged_attention`` engages on the
+    pools, it reads each active lane's rows below ``pos`` in place and
+    joins the new row to their softmax.  Otherwise the new row joins the
+    gathered sequence at ``pos`` under the mask: for every active lane
+    the same math and bits as writing the row into the pool before
+    gathering it."""
     b = x.shape[0]
     q, k, v = _qkv(p, cfg, x, pos[:, None])
-    # pool rows hold all heads flattened: [B, Hkv*hd]
-    k = k.reshape(b, -1).astype(pool_k.dtype)
-    v = v.reshape(b, -1).astype(pool_v.dtype)
+    row = (b, *pool_k.shape[3:])
+    k = k.reshape(row).astype(pool_k.dtype)
+    v = v.reshape(row).astype(pool_v.dtype)
     if PA.engages(pool_k, cfg):
         # an inactive lane reads no pooled row; its output is not used
         out = _paged_kernel_attention(cfg, q, k, v, pool_k, pool_v, layer,

@@ -163,7 +163,9 @@ def _absorbed_decode(p, cfg, qn, qr, ckv_seq, kr_seq, pos):
 def apply_mla_decode(p, cfg, x, cache_ckv, cache_kr, pos):
     """Absorbed decode: score/readout in the compressed latent space.
 
-    cache_ckv: [B, S, kv_lora]; cache_kr: [B, S, rope]; pos: [B].
+    cache_ckv: [B, S, kv_lora]; cache_kr: [B, S, rope]; pos: [B].  The
+    contiguous-cache decode: the plain reference for
+    ``apply_mla_decode_paged``, served by no engine.
     """
     qn, qr = _queries(p, cfg, x, pos[:, None])  # [B,1,H,*]
     ckv_new, kr_new = _latent(p, cfg, x, pos[:, None])

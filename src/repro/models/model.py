@@ -7,6 +7,11 @@ dry-run.
     logits, cache = api.prefill(params, batch, max_seq)
     logits, cache = api.decode(params, cache, tokens)
 
+``decode``/``init_cache`` are the contiguous-cache decode: no engine
+serves them (``serve.make_engine`` runs ``paged_decode`` over block
+pools); they are the plain per-request reference the engine's tests
+compare against, and what ``launch.dryrun`` lowers.
+
 ``batch_specs(shape)`` returns ShapeDtypeStructs for every model input — the
 dry-run feeds these to jit.lower (no allocation), and the data pipeline
 materializes matching arrays.

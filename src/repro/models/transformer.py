@@ -332,7 +332,9 @@ def _decode_blocks(stacked, cfg, x, cache_slices, pos, *, moe: bool,
 
 
 def decode_step(p, cfg, cache, tokens):
-    """tokens [B,1] -> (logits [B,V], new cache)."""
+    """Contiguous-cache decode: tokens [B,1] -> (logits [B,V], new
+    cache).  No engine serves it; it is the plain reference the paged
+    engine is tested against and what ``launch.dryrun`` lowers."""
     pos = cache["pos"]
     x = embed_tokens(p, cfg, tokens)
     n_dense, n_moe = _layer_split(cfg)

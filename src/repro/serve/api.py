@@ -1,20 +1,16 @@
-"""Unified serving surface shared by both engines.
-
-One request model, one validation path, one submission API, one
-streaming implementation — written here once instead of twice:
+"""The serving surface: request model, validation, submission and
+streaming, beside the engine's cache and decode (``serve.engine``):
 
   * ``Request`` / ``validate_request``: the request dataclass and the
-    horizon check both engines apply at submit time, with identical
-    typed rejection errors.
-  * ``EngineBase``: everything engine-kind-independent — ``submit`` /
-    ``submit_text`` for token prompts, ``submit_audio_stream`` for raw
-    audio, ``run_until_drained``, the planning-override context, and
-    the whole chunked-streaming machinery (planned audio frontend,
-    incremental encoder state, per-step chunk feeds).  The two engines
-    (``serve.engine``) keep only what genuinely differs: how a prefill
-    cache lands in device state and how decode executes.
-  * ``make_engine(cfg, kind="slot"|"paged", **kw)``: the one
-    constructor callers use (``launch.serve``, benches, tests).
+    horizon check applied at submit time, with typed rejection errors.
+  * ``EngineBase``: ``submit`` / ``submit_text`` for token prompts,
+    ``submit_audio_stream`` for raw audio, ``run_until_drained``, the
+    planning-override context, and the whole chunked-streaming
+    machinery (planned audio frontend, incremental encoder state,
+    per-step chunk feeds).  ``serve.engine.PagedServeEngine`` adds how
+    a prefill cache lands in the block pools and how decode executes.
+  * ``make_engine(cfg, **kw)``: the one constructor callers use
+    (``launch.serve``, benches, tests).
 
 Streaming admission contract (``kind == "audio"`` requests, encdec
 only): the utterance arrives as fixed-size sample chunks
@@ -114,9 +110,9 @@ class _StreamState:
 
 
 class EngineBase:
-    """Shared request/submission/streaming layer for both engines.
+    """Request/submission/streaming layer of the serving engine.
 
-    Subclasses provide device-state specifics via three hooks:
+    The engine provides device-state specifics via three hooks:
     ``_lane_request(lane)`` (who holds the lane), ``_append_enc(lane,
     ek, ev, start, new_len)`` (write one chunk's cross K/V into the
     lane's encoder buffers), and their own admit/step/decode paths.
@@ -313,15 +309,13 @@ class EngineBase:
             req.fed = i + 1
 
 
-def make_engine(cfg, kind: str = "slot", **kwargs):
-    """The one serving-engine constructor: ``kind="slot"`` builds the
-    fixed-slot baseline, ``kind="paged"`` the block-paged
-    continuous-batching engine.  All keyword arguments pass through to
-    the engine class."""
-    from .engine import PagedServeEngine, ServeEngine
-    if kind == "slot":
-        return ServeEngine(cfg, **kwargs)
-    if kind == "paged":
-        return PagedServeEngine(cfg, **kwargs)
-    raise ValueError(
-        f"unknown engine kind {kind!r}: expected 'slot' or 'paged'")
+def make_engine(cfg, kind: str = "paged", **kwargs):
+    """The one serving-engine constructor: the block-paged
+    continuous-batching ``PagedServeEngine``.  All keyword arguments
+    pass through to it.  ``kind`` names that engine, ``"paged"``, and
+    any other value is refused."""
+    from .engine import PagedServeEngine
+    if kind != "paged":
+        raise ValueError(
+            f"unknown engine kind {kind!r}: expected 'paged'")
+    return PagedServeEngine(cfg, **kwargs)

@@ -1,21 +1,12 @@
-"""Batched serving engines: fixed-slot (lite) and block-paged continuous
-batching.
+"""The serving engine: continuous batching over a block-paged KV cache
+(``paged_cache``).
 
-Both engines are ``api.EngineBase`` subclasses — the request model,
-validation, submission (``submit`` / ``submit_text`` /
+``PagedServeEngine`` is an ``api.EngineBase`` subclass — the request
+model, validation, submission (``submit`` / ``submit_text`` /
 ``submit_audio_stream``), drain loop, planning context, and the whole
-chunked audio-streaming machinery live once in ``serve.api``.  What
-remains here is only what genuinely differs between the two designs:
-how a prefill cache lands in device state and how decode executes.
-Construct either through ``serve.make_engine(cfg, kind=...)``.
-
-``ServeEngine`` is the original slot engine: one stacked cache with
-``max_slots`` batch lanes, prompts prefilled at ``max_seq`` and copied
-into free lanes.  It stays as the comparison baseline (and the simplest
-correct thing).
-
-``PagedServeEngine`` replaces the fixed-slot admit/free model with
-continuous batching over a block-paged KV cache (``paged_cache``):
+chunked audio-streaming machinery live in ``serve.api``.  What remains
+here is how a prefill cache lands in the block pools and how decode
+executes.  Construct it through ``serve.make_engine(cfg, **kw)``.
 
   * K/V lives in fixed-size blocks on the sequence axis; each request
     holds a host-side block table.  Admit/evict/grow is a host table
@@ -43,11 +34,8 @@ and each engine ``step()`` feeds one more chunk per streaming lane in
 place — decode output starts before the utterance ends, and the decode
 executable itself never changes shape (``decode_compiles`` stays 1).
 
-Every GEMM in both serving paths routes through ``kernels.planned``;
-``load()`` traces/compiles up front and ``plan_report`` holds a *true
-delta* of the planning decisions that warmup made (every counter —
-planned/fallback, backends, autotune hit/miss, shapes — is delta'd
-against the process-global report).
+Every serving GEMM routes through ``kernels.planned``; ``load()``
+traces and compiles up front (``plan_report`` is the warmup's delta).
 
 Greedy sampling (argmax); temperature hooks included but the engine is a
 systems artifact, not a quality one.
@@ -69,183 +57,6 @@ from .api import EngineBase, Request, validate_request  # noqa: F401
 from .api import SPAN_PREFIX, _StreamState, span
 from .paged_cache import PagedKVCache
 from .scheduler import Scheduler, SchedulerConfig
-
-
-class ServeEngine(EngineBase):
-    def __init__(self, cfg: ModelConfig, *, max_slots: int = 4,
-                 max_seq: int = 512, prompt_len: int | None = None,
-                 policy: autotune.PlanPolicy | None = None,
-                 target=None, frontend=None):
-        super().__init__(cfg, max_seq=max_seq, policy=policy,
-                         target=target, frontend=frontend)
-        self.max_slots = max_slots
-        self.prompt_len = prompt_len
-        self.cache = None
-        self.slots: list[Request | None] = [None] * max_slots
-        self._decode_jit = jax.jit(
-            lambda p, c, t: self.api.decode(p, c, t))
-        self._decode_exec = None
-
-    def load(self, params):
-        """Install weights and plan + compile the serving GEMMs up front.
-
-        The decode step is traced and AOT-compiled here: tracing routes
-        every decode GEMM through ``kernels.planned`` (one ``best_plan``
-        per shape, memoized in the mapper's LRU cache) and ``step()``
-        then replays the compiled executable — no per-step re-planning.
-        If ``prompt_len`` was given, the prefill GEMM shapes are planned
-        ahead as well (abstract trace, no FLOPs).  ``plan_report`` keeps
-        only the decisions *this warmup* made — a true delta against the
-        process-global report, every counter included (planned/fallback,
-        per-backend, autotune hit/miss, per-shape), so earlier unrelated
-        traces don't leak in.  ``autotune_report`` is the crossover-table
-        traffic of the same window: table hits/misses and — the invariant
-        the tests pin — ``measure_calls == 0``, because serve-time
-        planning only *reads* the committed table, it never races
-        backends.
-
-        If the engine was constructed with a ``PlanPolicy`` and/or a
-        ``target`` (e.g. ``core.HierarchicalTarget`` for outer tensor
-        parallelism), the warmup trace runs under them
-        (``planned.override``); otherwise whatever ``planned.configure``
-        set up (default: ``mode="cached"``, single-chip target) applies.
-        """
-        self.params = params
-        self.cache = self.api.init_cache(self.max_slots, self.max_seq)
-        before = planned.planned_report()
-        tune0 = autotune.counters()
-        with self._plan_ctx():
-            tokens0 = jnp.zeros((self.max_slots, 1), jnp.int32)
-            self._decode_exec = self._decode_jit.lower(
-                params, self.cache, tokens0).compile()
-            if self.prompt_len:
-                jax.eval_shape(
-                    lambda p, b: self.api.prefill(p, b, self.max_seq),
-                    params, self._prefill_spec())
-        self.plan_report = planned.report_delta(
-            before, planned.planned_report())
-        tune1 = autotune.counters()
-        self.autotune_report = {k: tune1[k] - tune0[k] for k in tune1}
-
-    def _prefill_spec(self):
-        """Abstract prefill batch for plan warmup — family-aware and
-        dtype-matched to ``model._token_batch_specs`` so the warmed
-        trace covers the same GEMM shapes real traffic will emit."""
-        spec = {"tokens": jax.ShapeDtypeStruct(
-            (1, self.prompt_len), jnp.int32)}
-        if self.cfg.family == "vlm":
-            spec["extra_embeds"] = jax.ShapeDtypeStruct(
-                (1, self.cfg.vlm_patches, self.cfg.d_model), jnp.bfloat16)
-        if self.cfg.family == "encdec":
-            spec["frames"] = jax.ShapeDtypeStruct(
-                (1, self.cfg.enc_frames, self.cfg.d_model), jnp.bfloat16)
-        return spec
-
-    # -- internals ----------------------------------------------------------
-    def _free_slots(self) -> list[int]:
-        return [i for i, s in enumerate(self.slots) if s is None]
-
-    def _lane_request(self, lane: int) -> Request | None:
-        return self.slots[lane]
-
-    def _write_lane(self, lane: int, prefill_cache):
-        """Copy a single-request prefill cache into lane ``lane``.
-
-        Dtypes must match exactly: both caches come from ``init_cache`` /
-        ``prefill`` with the config's kv-cache dtype, so a mismatch means
-        a caller handed in a cache built with different settings — and a
-        silent ``astype`` here would quietly narrow (e.g. fp32 prefill
-        state into an fp8 lane), corrupting the lane without a trace.
-        """
-        def write(dst, src):
-            if src.dtype != dst.dtype:
-                raise TypeError(
-                    f"prefill cache dtype {src.dtype} != engine cache "
-                    f"dtype {dst.dtype} (shape {src.shape} -> "
-                    f"{dst.shape}); rebuild the prefill cache with the "
-                    "engine's kv_cache_dtype instead of relying on a "
-                    "silent cast")
-            # batch axis: 0 for the 1-D pos leaf ([B]), 1 for stacked
-            # cache leaves ([L, B, ...], always ndim >= 3 across all
-            # families) — discriminating on shape[0] == max_slots instead
-            # corrupts lanes whenever n_layers happens to equal max_slots
-            if dst.ndim == 1:
-                return dst.at[lane].set(src[0])
-            return dst.at[:, lane].set(src[:, 0])
-
-        self.cache = jax.tree.map(write, self.cache, prefill_cache)
-
-    def _append_enc(self, lane: int, ek, ev, start: int,
-                    new_len: int) -> None:
-        fns = self._stream_fns()
-        ck, cv, cl = fns["lane_append"](
-            self.cache["enc_k"], self.cache["enc_v"],
-            self.cache["enc_len"], ek, ev, lane, start, new_len)
-        self.cache = dict(self.cache, enc_k=ck, enc_v=cv, enc_len=cl)
-
-    def _admit(self):
-        free = self._free_slots()
-        while free and self.queue:
-            req = self.queue.pop(0)
-            if req.t_admit is None:
-                req.t_admit = time.perf_counter()
-            stream = None
-            if req.kind == "audio":
-                ck, cv, el, ec, carry = self._stream_admit_state(req)
-                logits, pc = self.api.stream_prefill(
-                    self.params, ck, cv, el,
-                    jnp.asarray(req.prompt[None]), self.max_seq)
-                stream = (ec, carry)
-            else:
-                batch = {"tokens": jnp.asarray(req.prompt[None])}
-                if req.extra:
-                    batch.update({k: jnp.asarray(v[None])
-                                  for k, v in req.extra.items()})
-                logits, pc = self.api.prefill(
-                    self.params, batch, self.max_seq)
-            first = int(jnp.argmax(logits[0]))
-            req.output.append(first)
-            if len(req.output) >= req.max_new_tokens:
-                # the prefill token already satisfied the request: it
-                # finishes at admit time and never occupies a lane (a
-                # decode step would emit a second token past the budget)
-                req.done = True
-                self.finished.append(req)
-                continue
-            lane = free.pop(0)
-            self._write_lane(lane, pc)
-            self.slots[lane] = req
-            if stream is not None:
-                self._streams[lane] = _StreamState(req, *stream)
-
-    def step(self) -> int:
-        """Admit + one decode step for all active lanes.  Returns number of
-        active requests after the step."""
-        with self._plan_ctx():
-            # admission prefills and streaming chunk feeds trace planned
-            # GEMMs at call time, so the engine's policy/target must be
-            # ambient here, not just in load
-            self._admit()
-            self._feed_streams()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return len(self.queue)
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        for i in active:
-            tokens[i, 0] = self.slots[i].output[-1]
-        decode = self._decode_exec or self._decode_jit
-        logits, self.cache = decode(
-            self.params, self.cache, jnp.asarray(tokens))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for i in active:
-            req = self.slots[i]
-            req.output.append(int(nxt[i]))
-            if len(req.output) >= req.max_new_tokens:
-                req.done = True
-                self.finished.append(req)
-                self.slots[i] = None
-                self._streams.pop(i, None)
-        return sum(s is not None for s in self.slots) + len(self.queue)
 
 
 class PagedServeEngine(EngineBase):
@@ -327,10 +138,19 @@ class PagedServeEngine(EngineBase):
         chunk feeds write into lane-resident encoder buffers through
         their own jitted updaters — the decode executable is untouched.
 
-        ``plan_report`` / ``autotune_report`` are true deltas of the
-        warmup window, as in ``ServeEngine.load``.  If ``prompt_len``
-        was given, the bucketed prefill for that length is plan-warmed
-        abstractly (no FLOPs).
+        Tracing routes every decode GEMM through ``kernels.planned``
+        (one ``best_plan`` per shape, memoized in the mapper's LRU
+        cache), so ``step()`` replays the compiled executable with no
+        per-step re-planning.  ``plan_report`` keeps only the decisions
+        *this warmup* made — a true delta against the process-global
+        report, every counter included (planned/fallback, per-backend,
+        autotune hit/miss, per-shape).  ``autotune_report`` is the
+        crossover-table traffic of the same window; ``measure_calls``
+        stays 0, because serve-time planning only *reads* the committed
+        table.  The warmup runs under the engine's ``PlanPolicy`` and
+        ``target`` when given (``planned.override``).  If
+        ``prompt_len`` was given, the bucketed prefill for that length
+        is plan-warmed abstractly (no FLOPs).
         """
         self.params = params
         self.kv = PagedKVCache(

@@ -117,7 +117,7 @@ class PagedKVCache:
     def guard_decode_write(self) -> None:
         """Assert-guard the decode write: every active lane's next write
         position must fall inside its allocated blocks AND inside
-        max_seq.  The slot engine's ``dynamic_update_slice`` silently
+        max_seq.  A contiguous cache's ``dynamic_update_slice`` silently
         clamps at the horizon (overwriting the last row in place); the
         paged cache refuses instead."""
         for lane in range(self.max_lanes):

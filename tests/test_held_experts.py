@@ -397,7 +397,7 @@ def test_yarn_frequencies_and_attention_factor_match_the_formula():
 # (f) the defaults leave today's dense models bit for bit
 # ---------------------------------------------------------------------------
 
-#: sha256 of qwen1.5-0.5b SMOKE's prefill, slot-decode and paged-decode
+#: sha256 of qwen1.5-0.5b SMOKE's prefill, contiguous-decode and paged-decode
 #: logits (PRNGKey(0) weights, tokens 37 * i mod vocab), computed on the
 #: commit before the held-expert, dropless and YaRN fields existed
 QWEN_SMOKE_SHA256 = \

@@ -1,10 +1,7 @@
 """Continuous-batching serving: block-paged KV cache, scheduler, and the
-slot-engine bugs the new engine flushed out.
-
-The three regression tests at the top (`test_max_new_tokens_one_*`,
-`test_submit_rejects_*`, `test_plan_report_*`) are written against
-``ServeEngine`` only and fail on the pre-paged engine — they pin the
-bugfixes, not the new subsystem."""
+engine's outputs against the plain per-request reference
+(``serve_reference.greedy_reference``: the model's contiguous decode,
+one request at a time)."""
 
 import dataclasses
 import functools
@@ -18,7 +15,8 @@ from repro.configs import get_smoke_config
 from repro.core.mapper import plan_cache_info
 from repro.models import build_model
 from repro.serve import (BlockAllocator, PagedServeEngine, Scheduler,
-                         SchedulerConfig, ServeEngine)
+                         SchedulerConfig, synth_samples)
+from serve_reference import greedy_reference
 
 
 @functools.lru_cache(maxsize=None)
@@ -42,10 +40,11 @@ def _drain(eng, prompts, max_new=5, extras=None):
     return {r.rid: r.output for r in eng.run_until_drained(4000)}
 
 
-def _slot(cfg, params, **kw):
-    eng = ServeEngine(cfg, **kw)
-    eng.load(params)
-    return eng
+def _reference(cfg, params, prompts, max_seq, max_new=5):
+    """``_drain``'s outputs as the plain reference gives them."""
+    api = build_model(cfg)
+    return {i: greedy_reference(api, params, p, max_new, max_seq)
+            for i, p in enumerate(prompts)}
 
 
 def _paged(cfg, params, **kw):
@@ -55,29 +54,29 @@ def _paged(cfg, params, **kw):
 
 
 # ---------------------------------------------------------------------------
-# slot-engine regressions (fail on the pre-paged engine)
+# request budget, horizon and plan-report regressions
 # ---------------------------------------------------------------------------
 
 def test_max_new_tokens_one_emits_exactly_one_token():
-    """A max_new_tokens=1 request is satisfied by the prefill token; the
-    old engine still parked it in a lane and ran a decode step, emitting
-    a second token past the budget."""
+    """A max_new_tokens=1 request is satisfied by the prefill token: it
+    finishes at admission, never parked in a lane for a decode step
+    that would emit a second token past the budget."""
     cfg, params = _setup()
-    eng = _slot(cfg, params, max_slots=2, max_seq=32)
+    eng = _paged(cfg, params, max_lanes=2, max_seq=32, block_size=8)
     rid = eng.submit(_prompts(cfg, [6])[0], max_new_tokens=1)
     done = eng.run_until_drained()
     assert [r.rid for r in done] == [rid]
     assert len(done[0].output) == 1
     # and it never occupied a lane: a follow-up request is unaffected
-    assert eng.slots == [None, None]
+    assert eng.lanes == [None, None]
+    assert eng.stats["steps"] == 0
 
 
 def test_submit_rejects_requests_past_the_sequence_horizon():
-    """prompt + max_new_tokens > max_seq used to be accepted; the decode
-    write then silently clamped at the horizon, overwriting the last
-    cache row in place (token soup, no error)."""
+    """prompt + max_new_tokens > max_seq is refused at submit time: the
+    decode write has no cache row past the horizon."""
     cfg, params = _setup()
-    eng = _slot(cfg, params, max_slots=1, max_seq=32)
+    eng = _paged(cfg, params, max_lanes=1, max_seq=32, block_size=8)
     with pytest.raises(ValueError, match="max_seq"):
         eng.submit(_prompts(cfg, [20])[0], max_new_tokens=20)
     with pytest.raises(ValueError, match="max_new_tokens"):
@@ -89,13 +88,13 @@ def test_submit_rejects_requests_past_the_sequence_horizon():
 
 
 def test_plan_report_deltas_every_counter():
-    """plan_report must be a true delta of the warmup window.  The old
-    load() delta'd only planned/fallback and copied backends/shapes
-    cumulatively, so a second engine's report double-counted the first
-    engine's warmup traffic."""
+    """plan_report must be a true delta of the warmup window, every
+    counter included: a delta of planned/fallback alone, with backends
+    and shapes copied cumulatively, would make a second engine's report
+    double-count the first engine's warmup traffic."""
     cfg, params = _setup()
-    r1 = _slot(cfg, params, max_slots=2, max_seq=32).plan_report
-    r2 = _slot(cfg, params, max_slots=2, max_seq=32).plan_report
+    r1 = _paged(cfg, params, max_lanes=2, max_seq=32).plan_report
+    r2 = _paged(cfg, params, max_lanes=2, max_seq=32).plan_report
     assert set(r1) == set(r2)
     for site in r1:
         assert r1[site]["backends"] == r2[site]["backends"], site
@@ -148,14 +147,16 @@ def test_paged_cache_rejects_unaligned_horizon():
 
 
 # ---------------------------------------------------------------------------
-# paged vs slot: bit-identical outputs
+# the engine vs the per-request contiguous reference: identical outputs
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("lanes", [1, 4])
 def test_paged_matches_slot_bit_identical(lanes):
+    """Batched paged decode gives each request exactly the tokens of its
+    own contiguous cache, decoded alone."""
     cfg, params = _setup()
     prompts = _prompts(cfg, [5, 9, 13, 4, 17, 7], seed=3)
-    ref = _drain(_slot(cfg, params, max_slots=lanes, max_seq=64), prompts)
+    ref = _reference(cfg, params, prompts, 64)
     got = _drain(_paged(cfg, params, max_lanes=lanes, max_seq=64,
                         block_size=8), prompts)
     assert ref == got
@@ -169,54 +170,69 @@ def test_paged_matches_slot_bit_identical(lanes):
 def test_paged_matches_slot_across_families(arch, lanes):
     cfg, params = _setup(arch)
     prompts = _prompts(cfg, [5, 9, 7], seed=1)
-    ref = _drain(_slot(cfg, params, max_slots=lanes, max_seq=64), prompts)
+    ref = _reference(cfg, params, prompts, 64)
     got = _drain(_paged(cfg, params, max_lanes=lanes, max_seq=64,
                         block_size=8), prompts)
     assert ref == got
 
 
-def _decode_write_then_gather(p, cfg, pools, tokens, tables, pos, active):
-    """The decode step as every layer once ran it: per-layer pools
-    through the layer scan, each layer writing its row with
-    ``paged_write`` and then reading its lanes with ``paged_gather``."""
-    from repro.models import layers as L
-    from repro.models import transformer as TFM
+def _decode_write_then_gather(api, p, pools, tokens, tables, pos, active):
+    """The decode step as a contiguous cache runs it: each lane's rows
+    gathered from its blocks into a [L, B, S, ...] cache, the family's
+    ``decode`` writing every layer's new row at ``pos`` before it
+    attends over it, then each layer's new row stored into its pool
+    layer by layer (an inactive lane's index out of range, dropped)."""
+    b, t = tables.shape
+    layout = api.paged_layout()
+    rows_of = jax.eval_shape(lambda: api.init_cache(b, 1))
+    cache = {"pos": pos}
+    for name, kind in layout.items():
+        pool = pools[name]
+        if kind == "paged":
+            g = pool[:, tables]                     # [L, B, T, bs, row]
+            cache[name] = g.reshape(g.shape[0], b, -1,
+                                    *rows_of[name].shape[3:])
+        else:
+            cache[name] = pool
+    logits, new_cache = api.decode(p, cache, tokens)
 
-    heads = (cfg.n_kv_heads, cfg.hd)
-
-    def body(x, inp):
-        lp, pk, pv = inp
-        h = L.apply_norm(lp["ln1"], cfg, x)
-        attn, pk, pv = L.apply_attention_decode_paged(
-            lp["attn"], cfg, h, pk, pv, tables, pos, active)
-        x = x + attn
-        h = L.apply_norm(lp["ln2"], cfg, x)
-        return x + L.apply_mlp(lp["mlp"], cfg, h), (pk, pv)
-
-    split = [pools[k].reshape(*pools[k].shape[:3], *heads) for k in "kv"]
-    x = TFM.embed_tokens(p, cfg, tokens)
-    x, (pk, pv) = jax.lax.scan(body, x, (p["dense_layers"], *split),
-                               unroll=cfg.scan_unroll)
-    x = L.apply_norm(p["ln_f"], cfg, x)
-    logits = TFM.logits_fn(p, cfg, x)[:, 0]
-    return logits, {"k": pk.reshape(pools["k"].shape),
-                    "v": pv.reshape(pools["v"].shape)}
+    lanes = jnp.arange(b)
+    new_pools = {}
+    for name, kind in layout.items():
+        pool = pools[name]
+        if kind != "paged":
+            new_pools[name] = new_cache[name]
+            continue
+        nl, nb, bs = pool.shape[:3]
+        rows = new_cache[name][:, lanes, pos].reshape(nl, b, *pool.shape[3:])
+        idx = tables[lanes, pos // bs] * bs + pos % bs
+        idx = jnp.where(active, idx, nb * bs)
+        for layer in range(nl):
+            flat = pool[layer].reshape(nb * bs, *pool.shape[3:])
+            flat = flat.at[idx].set(rows[layer], mode="drop")
+            pool = pool.at[layer].set(flat.reshape(pool.shape[1:]))
+        new_pools[name] = pool
+    return logits, new_pools
 
 
-def test_decode_reads_pools_then_writes_rows_once_as_write_then_gather():
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "zamba2-1.2b",
+                                  "whisper-base"])
+def test_decode_reads_pools_then_writes_rows_once_as_write_then_gather(arch):
     """The decode step gathers from the pools it was given, puts each
     lane's new row into its sequence, and writes every layer's rows
-    after the layer scan: the same pools and active-lane logits, bit for
+    after the layers: the same pools and active-lane logits, bit for
     bit, as writing each row before gathering, layer by layer.  Lane 3
     is inactive and its table points at lane 0's blocks: it writes
     nothing."""
-    cfg, params = _setup()
+    cfg, params = _setup(arch)
     api = build_model(cfg)
     nb, bs, lanes, per_lane = 24, 4, 4, 6
     pools = api.paged_init(nb, bs, lanes)
     keys = jax.random.split(jax.random.PRNGKey(7), len(pools))
     pools = {k: jax.random.normal(kk, v.shape, jnp.float32).astype(v.dtype)
              for kk, (k, v) in zip(keys, sorted(pools.items()))}
+    if "enc_len" in pools:      # encoder frames each lane has seen
+        pools["enc_len"] = jnp.asarray([32, 8, 16, 32], jnp.int32)
     rng = np.random.default_rng(11)
     tables = rng.permutation(nb)[:lanes * per_lane].reshape(lanes, per_lane)
     tables[3] = tables[0]
@@ -226,33 +242,42 @@ def test_decode_reads_pools_then_writes_rows_once_as_write_then_gather():
     tokens = jnp.asarray(rng.integers(0, cfg.vocab, (lanes, 1)), jnp.int32)
 
     ref_logits, ref_pools = jax.jit(
-        lambda *a: _decode_write_then_gather(params, cfg, *a))(
+        lambda *a: _decode_write_then_gather(api, params, *a))(
             pools, tokens, tables, pos, active)
     logits, new_pools = jax.jit(
         lambda *a: api.paged_decode(params, *a))(
             pools, tokens, tables, pos, active)
 
-    for k in pools:
+    for k, kind in api.paged_layout().items():
         np.testing.assert_array_equal(np.asarray(new_pools[k], np.float32),
                                       np.asarray(ref_pools[k], np.float32))
+        if kind != "paged":
+            continue
         # lane 3's row would land in lane 0's block 1; only the active
         # lanes' rows changed
         changed = np.argwhere(np.any(
-            np.asarray(new_pools[k] != pools[k]), axis=-1))
+            np.asarray(new_pools[k] != pools[k]),
+            axis=tuple(range(3, pools[k].ndim))))
         want = {(layer, int(tables[i, pos[i] // bs]), int(pos[i]) % bs)
-                for layer in range(cfg.n_layers) for i in range(3)}
+                for layer in range(pools[k].shape[0]) for i in range(3)}
         assert {tuple(map(int, c)) for c in changed} == want, k
     np.testing.assert_array_equal(np.asarray(logits[:3]),
                                   np.asarray(ref_logits[:3]))
 
 
-def test_decode_step_donates_the_pools():
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "zamba2-1.2b",
+                                  "whisper-base"])
+def test_decode_step_donates_the_pools(arch):
     """The engine donates the block pools to the decode executable, so
     each step updates them in place: the step's input pools are gone
     after it, and the engine holds the live output."""
-    cfg, params = _setup()
+    cfg, params = _setup(arch)
     eng = _paged(cfg, params, max_lanes=2, max_seq=32, block_size=8)
-    eng.submit(_prompts(cfg, [6])[0], max_new_tokens=4)
+    if eng.frontend is None:
+        eng.submit(_prompts(cfg, [6])[0], max_new_tokens=4)
+    else:   # one chunk: admission feeds it, no later step replaces a pool
+        eng.submit_audio_stream(synth_samples(eng.frontend.cfg, 1, seed=0),
+                                max_new_tokens=4)
     eng.step()                  # admission, then the first decode
     before = eng.kv.pools
     eng.step()                  # a decode-only step
@@ -279,7 +304,7 @@ def test_bucketed_prefill_is_output_transparent():
 def test_fp8_cache_roundtrips_through_paged_pools():
     cfg, params = _setup(kv_dtype="float8_e4m3fn")
     prompts = _prompts(cfg, [5, 9, 7], seed=2)
-    ref = _drain(_slot(cfg, params, max_slots=2, max_seq=64), prompts)
+    ref = _reference(cfg, params, prompts, 64)
     got = _drain(_paged(cfg, params, max_lanes=2, max_seq=64,
                         block_size=8), prompts)
     assert ref == got
@@ -352,8 +377,7 @@ def test_preemption_under_block_pressure_preserves_outputs():
     final output is unchanged (greedy decode is recompute-transparent)."""
     cfg, params = _setup()
     prompts = _prompts(cfg, [20, 20, 20, 20], seed=4)
-    ref = _drain(_slot(cfg, params, max_slots=4, max_seq=64), prompts,
-                 max_new=20)
+    ref = _reference(cfg, params, prompts, 64, max_new=20)
     eng = _paged(cfg, params, max_lanes=4, max_seq=64, block_size=8,
                  num_blocks=14)   # 4 lanes x 40 rows need 20 blocks
     got = _drain(eng, prompts, max_new=20)

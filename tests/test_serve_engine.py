@@ -1,8 +1,5 @@
-"""Direct coverage for serve/engine.py: continuous batching semantics,
-plan-once-serve-many (no plan-cache growth after warmup), and the
-_write_lane dtype guard."""
-
-import dataclasses
+"""Direct coverage for serve/engine.py: continuous batching semantics
+and plan-once-serve-many (no plan-cache growth after warmup)."""
 
 import numpy as np
 import jax
@@ -12,14 +9,15 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.core.mapper import plan_cache_info
 from repro.models import build_model
-from repro.serve import ServeEngine
+from repro.serve import make_engine
 
 
-def _engine(max_slots=4, max_seq=64, arch="qwen1.5-0.5b", **kw):
+def _engine(max_lanes=4, max_seq=64, arch="qwen1.5-0.5b", **kw):
     cfg = get_smoke_config(arch)
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(42))
-    eng = ServeEngine(cfg, max_slots=max_slots, max_seq=max_seq, **kw)
+    eng = make_engine(cfg, max_lanes=max_lanes, max_seq=max_seq,
+                      block_size=8, **kw)
     eng.load(params)
     return cfg, eng
 
@@ -34,16 +32,16 @@ def _prompts(cfg, n, plen=6, seed=0):
 # ---------------------------------------------------------------------------
 
 def test_admit_fills_free_lanes_and_queues_the_rest():
-    cfg, eng = _engine(max_slots=2)
+    cfg, eng = _engine(max_lanes=2)
     for p in _prompts(cfg, 5):
         eng.submit(p, max_new_tokens=4)
     eng._admit()
-    assert sum(s is not None for s in eng.slots) == 2
+    assert sum(r is not None for r in eng.lanes) == 2
     assert len(eng.queue) == 3
 
 
 def test_finished_lane_frees_and_next_request_joins():
-    cfg, eng = _engine(max_slots=1)
+    cfg, eng = _engine(max_lanes=1)
     r0, r1 = [eng.submit(p, max_new_tokens=2) for p in _prompts(cfg, 2)]
     # step 1: r0 admitted (prefill emits token 1), decode emits token 2 ->
     # r0 done, lane freed with r1 still queued
@@ -52,36 +50,36 @@ def test_finished_lane_frees_and_next_request_joins():
     assert remaining == 1  # r1 waiting
     eng.step()
     assert [r.rid for r in eng.finished] == [r0, r1]
-    assert eng.slots == [None]
+    assert eng.lanes == [None]
 
 
 def test_queue_drains_all_requests():
-    cfg, eng = _engine(max_slots=4)
+    cfg, eng = _engine(max_lanes=4)
     rids = [eng.submit(p, max_new_tokens=5)
             for p in _prompts(cfg, 7, plen=5)]
     done = eng.run_until_drained()
     assert sorted(r.rid for r in done) == sorted(rids)
     assert all(len(r.output) == 5 for r in done)
-    assert eng.slots == [None] * 4 and eng.queue == []
+    assert eng.lanes == [None] * 4 and eng.queue == []
 
 
 def test_run_until_drained_respects_max_steps():
-    cfg, eng = _engine(max_slots=1)
+    cfg, eng = _engine(max_lanes=1)
     for p in _prompts(cfg, 2):
         eng.submit(p, max_new_tokens=8)
     done = eng.run_until_drained(max_steps=3)
     # 3 steps of a 1-lane engine cannot finish 2x8 tokens — the bound
     # must return control instead of spinning
     assert len(done) < 2
-    assert eng.queue or any(s is not None for s in eng.slots)
+    assert eng.queue or any(r is not None for r in eng.lanes)
 
 
 @pytest.mark.parametrize("slots", [2, 4])
 def test_outputs_identical_max_slots_1_vs_n(slots):
-    # slots=2 equals the smoke config's n_layers — the geometry where
-    # _write_lane's old shape[0]==max_slots heuristic corrupted lanes
-    cfg1, eng1 = _engine(max_slots=1)
-    cfgn, engn = _engine(max_slots=slots)
+    # lanes=2 equals the smoke config's n_layers: a lane write that
+    # told the layer axis from the lane axis by size would corrupt lanes
+    cfg1, eng1 = _engine(max_lanes=1)
+    cfgn, engn = _engine(max_lanes=slots)
     prompts = _prompts(cfg1, 5, plen=7, seed=3)
     for eng in (eng1, engn):
         for p in prompts:
@@ -92,7 +90,7 @@ def test_outputs_identical_max_slots_1_vs_n(slots):
 
 
 def test_late_submissions_join_without_restart():
-    cfg, eng = _engine(max_slots=2)
+    cfg, eng = _engine(max_lanes=2)
     for p in _prompts(cfg, 2):
         eng.submit(p, max_new_tokens=6)
     eng.step()
@@ -107,7 +105,7 @@ def test_late_submissions_join_without_restart():
 # ---------------------------------------------------------------------------
 
 def test_load_plans_and_compiles_decode_ahead():
-    cfg, eng = _engine(max_slots=2, prompt_len=6)
+    cfg, eng = _engine(max_lanes=2, prompt_len=6)
     assert eng._decode_exec is not None
     # the warmup trace routed the serving GEMMs through the facade
     assert eng.plan_report, "load() must snapshot the planning report"
@@ -120,7 +118,7 @@ def test_load_plans_and_compiles_decode_ahead():
 def test_load_prefill_warmup_covers_encdec_family():
     """The family-aware prefill spec must include the encoder frames —
     an encdec engine with prompt_len used to KeyError in load()."""
-    cfg, eng = _engine(max_slots=1, max_seq=32, arch="whisper-base",
+    cfg, eng = _engine(max_lanes=1, max_seq=32, arch="whisper-base",
                        prompt_len=4)
     assert eng._decode_exec is not None
     assert eng.plan_report
@@ -140,19 +138,19 @@ def test_plan_report_is_a_warmup_delta():
     jax.grad(lambda p: api.loss(p, {"tokens": toks, "labels": toks}))(
         params)
     assert any("/bwd_" in s for s in planned.planned_report())
-    eng = ServeEngine(cfg, max_slots=2, max_seq=32)
+    eng = make_engine(cfg, max_lanes=2, max_seq=32, block_size=8)
     eng.load(params)
     # decode-only warmup: no sdpa scores, no backward GEMMs
     assert not any("/bwd_" in s for s in eng.plan_report)
     assert "attn.scores" not in eng.plan_report
-    assert "attn.decode_scores" in eng.plan_report
+    assert "attn.paged_scores" in eng.plan_report
 
 
 def test_engine_serves_with_planned_off():
     from repro.kernels import planned
 
     with planned.override(enabled=False):
-        cfg, eng = _engine(max_slots=2)
+        cfg, eng = _engine(max_lanes=2)
         assert all(st["planned"] == 0 for st in eng.plan_report.values())
         for p in _prompts(cfg, 2):
             eng.submit(p, max_new_tokens=3)
@@ -161,7 +159,7 @@ def test_engine_serves_with_planned_off():
 
 
 def test_steady_state_steps_do_not_grow_plan_cache():
-    cfg, eng = _engine(max_slots=2)
+    cfg, eng = _engine(max_lanes=2)
     # warmup: one full drain covers prefill + decode GEMM shapes
     for p in _prompts(cfg, 2, plen=6):
         eng.submit(p, max_new_tokens=3)
@@ -181,7 +179,7 @@ def test_load_performs_no_measurement():
     backends — and steady-state traffic doesn't either."""
     from repro.core import autotune
 
-    cfg, eng = _engine(max_slots=2)
+    cfg, eng = _engine(max_lanes=2)
     assert eng.autotune_report["measure_calls"] == 0, eng.autotune_report
     before = autotune.counters()["measure_calls"]
     for p in _prompts(cfg, 2):
@@ -195,7 +193,7 @@ def test_engine_accepts_explicit_policy():
     never consulted during its warmup."""
     from repro.core.autotune import PlanPolicy
 
-    cfg, eng = _engine(max_slots=2,
+    cfg, eng = _engine(max_lanes=2,
                        policy=PlanPolicy(mode="modelled"))
     assert eng.autotune_report["measure_calls"] == 0
     assert eng.autotune_report["hits"] == 0
@@ -203,46 +201,3 @@ def test_engine_accepts_explicit_policy():
         eng.submit(p, max_new_tokens=3)
     done = eng.run_until_drained()
     assert len(done) == 2 and all(len(r.output) == 3 for r in done)
-
-
-# ---------------------------------------------------------------------------
-# _write_lane dtype guard
-# ---------------------------------------------------------------------------
-
-def test_write_lane_rejects_mismatched_dtype():
-    cfg, eng = _engine(max_slots=2)
-    batch = {"tokens": jnp.asarray(_prompts(cfg, 1)[0][None], jnp.int32)}
-    _, pc = eng.api.prefill(eng.params, batch, eng.max_seq)
-    # a prefill cache built with the wrong storage dtype must be rejected,
-    # not silently narrowed into the lane
-    bad = {
-        k: (v.astype(jnp.float16)
-            if jnp.issubdtype(v.dtype, jnp.floating) else v)
-        for k, v in pc.items()
-    }
-    with pytest.raises(TypeError, match="dtype"):
-        eng._write_lane(0, bad)
-
-
-def test_write_lane_accepts_matching_dtype():
-    cfg, eng = _engine(max_slots=2)
-    batch = {"tokens": jnp.asarray(_prompts(cfg, 1)[0][None], jnp.int32)}
-    _, pc = eng.api.prefill(eng.params, batch, eng.max_seq)
-    eng._write_lane(1, pc)  # must not raise
-    for k, v in eng.cache.items():
-        assert v.dtype == pc[k].dtype
-
-
-def test_fp8_cache_config_roundtrips_through_lanes():
-    """An engine configured for fp8 KV storage works end to end — the
-    guard rejects accidental narrowing, not the configured storage."""
-    cfg = dataclasses.replace(
-        get_smoke_config("qwen1.5-0.5b"), kv_cache_dtype="float8_e4m3fn")
-    api = build_model(cfg)
-    eng = ServeEngine(cfg, max_slots=2, max_seq=32)
-    eng.load(api.init(jax.random.PRNGKey(0)))
-    for p in _prompts(cfg, 3, plen=5):
-        eng.submit(p, max_new_tokens=3)
-    done = eng.run_until_drained()
-    assert len(done) == 3
-    assert all(len(r.output) == 3 for r in done)

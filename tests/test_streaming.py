@@ -12,12 +12,13 @@ The contracts pinned here:
     float rounding to the one-shot block-causal ``encode(chunk=C)`` and
     to the engines' per-step feed (different XLA programs, see
     ``_assert_close``);
-  * audio streams served by the slot and paged engines produce
-    identical tokens and identical lane encoder state;
+  * an audio stream served by the engine produces the tokens of the
+    same chunk schedule decoded alone through the model's contiguous
+    decode, and lane encoder state equal to the offline comparator;
   * streaming steady state never replans, never measures, and never
     touches the AOT decode executable (``decode_compiles == 1``);
-  * both engines share one validation surface with identical typed
-    rejections (no duplicated ``Request``/``validate_request``).
+  * the engine has one validation surface with typed rejections (no
+    duplicated ``Request``/``validate_request``).
 """
 
 import numpy as np
@@ -46,13 +47,10 @@ def _params():
     return _PARAMS
 
 
-def _engine(kind, **kw):
-    if kind == "paged":
-        kw.setdefault("max_lanes", 2)
-        kw.setdefault("block_size", 8)
-    else:
-        kw.setdefault("max_slots", 2)
-    eng = make_engine(CFG, kind=kind, max_seq=64, **kw)
+def _engine(**kw):
+    kw.setdefault("max_lanes", 2)
+    kw.setdefault("block_size", 8)
+    eng = make_engine(CFG, max_seq=64, **kw)
     eng.load(_params())
     return eng
 
@@ -174,43 +172,75 @@ def test_block_causal_encode_equals_incremental():
 # engine streaming end to end
 # ---------------------------------------------------------------------------
 
-def test_streamed_audio_slot_equals_paged_and_offline():
-    """One utterance through both engines: identical token streams,
-    lane encoder state bitwise equal to the offline comparator, and
-    decode starting before the stream completes."""
-    params = _params()
-    slot = _engine("slot")
-    paged = _engine("paged")
-    fc = slot.frontend.cfg
-    samples = synth_samples(fc, 4, seed=3)
+def _streamed_reference(api, params, fe, samples, max_new, max_seq=64):
+    """The greedy tokens of one audio stream decoded alone through the
+    model's contiguous decode, on the engine's schedule: the prompt
+    (token 0) prefilled against the first chunk's encoder K/V, then one
+    more chunk appended before each decode step until all are fed."""
+    chunks = fe.split(samples)
+    C = fe.cfg.frames_per_chunk
+    enc_step, enc_kv = jax.jit(api.enc_step), jax.jit(api.enc_kv)
+    carry, ec = fe.init_state(), api.enc_init(1, CFG.enc_frames)
+    shape = (CFG.n_layers, 1, CFG.enc_frames, CFG.n_kv_heads, CFG.hd)
+    enc = {k: jnp.zeros(shape, cache_dtype_of(CFG))
+           for k in ("enc_k", "enc_v")}
 
-    outs = {}
-    for name, eng in (("slot", slot), ("paged", paged)):
-        rid = eng.submit_audio_stream(samples, max_new_tokens=8)
-        done = {r.rid: r for r in eng.run_until_drained()}
-        req = done[rid]
-        assert req.done and len(req.output) == 8
-        assert req.fed == 4, "all chunks must be consumed"
-        outs[name] = list(req.output)
-    assert outs["slot"] == outs["paged"]
+    def feed(i):
+        nonlocal carry, ec
+        carry, feats = fe.chunk_features(carry, chunks[i])
+        ec, out = enc_step(params, ec, feats[None])
+        for k, kv in zip(("enc_k", "enc_v"), enc_kv(params, out)):
+            enc[k] = enc[k].at[:, :, i * C:(i + 1) * C].set(kv)
+        enc["enc_len"] = jnp.full((1,), (i + 1) * C, jnp.int32)
+
+    feed(0)
+    logits, cache = jax.jit(api.stream_prefill, static_argnums=5)(
+        params, enc["enc_k"], enc["enc_v"], enc["enc_len"],
+        jnp.asarray([[0]], jnp.int32), max_seq)
+    decode = jax.jit(api.decode)
+    out, fed = [int(jnp.argmax(logits[0]))], 1
+    while len(out) < max_new:
+        if fed < len(chunks):
+            feed(fed)
+            fed += 1
+        logits, cache = decode(params, dict(cache, **enc),
+                               jnp.asarray([[out[-1]]], jnp.int32))
+        out.append(int(jnp.argmax(logits[0])))
+    return out
+
+
+def test_streamed_audio_slot_equals_paged_and_offline():
+    """One utterance through the engine: the token stream of the same
+    chunk schedule decoded alone through the contiguous decode, lane
+    encoder state equal to the offline comparator, and decode starting
+    before the stream completes."""
+    params = _params()
+    paged = _engine()
+    fe = paged.frontend
+    samples = synth_samples(fe.cfg, 4, seed=3)
+
+    rid = paged.submit_audio_stream(samples, max_new_tokens=8)
+    req = {r.rid: r for r in paged.run_until_drained()}[rid]
+    assert req.done and len(req.output) == 8
+    assert req.fed == 4, "all chunks must be consumed"
+    assert list(req.output) == _streamed_reference(
+        paged.api, params, fe, samples, 8)
 
     # lane 0's encoder K/V (device state survives release) must equal
-    # the offline whole-utterance comparator.  The engines run each
+    # the offline whole-utterance comparator.  The engine runs each
     # chunk as its own jitted encoder and K/V programs, the comparator
     # dispatches the utterance op by op, so a bfloat16 cache value can
     # round one or two ulps (2**-7 of the element each) apart; after
     # the cancellation of a projection that shows up on small elements,
     # hence 2**-12 absolute, 1/32 of a bf16 ulp at the values' scale (~4)
-    feats = slot.frontend.offline_features(samples)
+    feats = fe.offline_features(samples)
     _, cache, _ = E.prefill_streaming(
         params, CFG, feats[None], jnp.asarray([[0]]), 64,
-        fc.frames_per_chunk, cache_dtype=cache_dtype_of(CFG))
-    for eng, ek, ev in (
-            (slot, slot.cache["enc_k"][:, 0], slot.cache["enc_v"][:, 0]),
-            (paged, paged.kv.pools["enc_k"][:, 0],
-             paged.kv.pools["enc_v"][:, 0])):
-        _assert_close(ek, cache["enc_k"][:, 0], rtol=2**-6, atol=2**-12)
-        _assert_close(ev, cache["enc_v"][:, 0], rtol=2**-6, atol=2**-12)
+        fe.cfg.frames_per_chunk, cache_dtype=cache_dtype_of(CFG))
+    _assert_close(paged.kv.pools["enc_k"][:, 0], cache["enc_k"][:, 0],
+                  rtol=2**-6, atol=2**-12)
+    _assert_close(paged.kv.pools["enc_v"][:, 0], cache["enc_v"][:, 0],
+                  rtol=2**-6, atol=2**-12)
 
     # chunked admission means decode ran while chunks were still
     # arriving: 8 tokens over 4 chunks needs fewer steps than a
@@ -222,7 +252,7 @@ def test_streamed_audio_slot_equals_paged_and_offline():
 def test_streaming_decode_starts_before_utterance_end():
     """After one step, the audio lane has emitted tokens but not yet
     consumed its chunks — decode genuinely overlaps the stream."""
-    eng = _engine("paged")
+    eng = _engine()
     fc = eng.frontend.cfg
     rid = eng.submit_audio_stream(synth_samples(fc, 4, seed=1),
                                   max_new_tokens=8)
@@ -239,7 +269,7 @@ def test_mixed_text_audio_under_preemption():
     """Text + audio sharing an oversubscribed block pool: preemption
     fires, prefers text victims, and every request still finishes with
     its full budget."""
-    eng = _engine("paged", max_lanes=3, block_size=4, num_blocks=10)
+    eng = _engine(max_lanes=3, block_size=4, num_blocks=10)
     fc = eng.frontend.cfg
     samples = synth_samples(fc, 3, seed=2)
     frames = _frames()
@@ -255,7 +285,7 @@ def test_mixed_text_audio_under_preemption():
 
     # the audio stream's tokens must match an unpressured run — the
     # replayed chunks reproduce the lost encoder state bit-identically
-    calm = _engine("paged", max_lanes=3)
+    calm = _engine(max_lanes=3)
     rid_c = calm.submit_audio_stream(samples, max_new_tokens=10)
     calm_done = {r.rid: r for r in calm.run_until_drained()}
     assert calm.stats["preemptions"] == 0
@@ -265,7 +295,7 @@ def test_mixed_text_audio_under_preemption():
 def test_streaming_steady_state_no_replanning_no_measurement():
     """Second identical stream on a warm engine: zero plan-cache
     misses, zero autotune traffic, decode executable untouched."""
-    eng = _engine("paged")
+    eng = _engine()
     fc = eng.frontend.cfg
     samples = synth_samples(fc, 4, seed=4)
     eng.submit_audio_stream(samples, max_new_tokens=6)
@@ -295,14 +325,14 @@ def test_engine_module_has_no_duplicate_request_surface():
     assert engine.Request is api.Request
     assert engine.validate_request is api.validate_request
     assert not hasattr(engine, "_validate_request")
-    assert engine.ServeEngine.submit is api.EngineBase.submit
+    assert engine.PagedServeEngine.submit is api.EngineBase.submit
     assert (engine.PagedServeEngine.run_until_drained
             is api.EngineBase.run_until_drained)
 
 
-@pytest.mark.parametrize("kind", ["slot", "paged"])
+@pytest.mark.parametrize("kind", ["paged"])
 def test_validation_rejections_identical_across_engines(kind):
-    eng = _engine(kind)
+    eng = _engine(kind=kind)
     with pytest.raises(ValueError,
                        match=r"max_new_tokens must be >= 1, got 0"):
         eng.submit(np.arange(3), max_new_tokens=0)
@@ -324,11 +354,12 @@ def test_validation_rejections_identical_across_engines(kind):
 
 def test_audio_submit_rejected_for_non_encdec():
     cfg = get_smoke_config("qwen1.5-0.5b")
-    eng = make_engine(cfg, kind="slot", max_slots=1, max_seq=32)
+    eng = make_engine(cfg, max_lanes=1, max_seq=32)
     with pytest.raises(ValueError, match="audio"):
         eng.submit_audio_stream(np.zeros(804, np.int16))
 
 
-def test_make_engine_rejects_unknown_kind():
+@pytest.mark.parametrize("kind", ["ring", "slot"])
+def test_make_engine_rejects_unknown_kind(kind):
     with pytest.raises(ValueError, match="unknown engine kind"):
-        make_engine(CFG, kind="ring")
+        make_engine(CFG, kind=kind)

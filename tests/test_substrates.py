@@ -159,13 +159,13 @@ def test_moe_tp_equals_dense_when_single_shard():
 # ---------------------------------------------------------------------------
 
 def test_serve_engine_continuous_batching():
-    from repro.serve import ServeEngine
+    from repro.serve import make_engine
 
     cfg = get_smoke_config("qwen1.5-0.5b")
     from repro.models import build_model
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
-    eng = ServeEngine(cfg, max_slots=2, max_seq=32)
+    eng = make_engine(cfg, max_lanes=2, max_seq=32, block_size=8)
     eng.load(params)
     rng = np.random.default_rng(3)
     rids = [eng.submit(rng.integers(0, cfg.vocab, 5), max_new_tokens=4)
@@ -178,7 +178,7 @@ def test_serve_engine_continuous_batching():
 
 def test_serve_deterministic_per_request():
     """Lane placement must not change a request's outputs."""
-    from repro.serve import ServeEngine
+    from repro.serve import make_engine
     from repro.models import build_model
 
     cfg = get_smoke_config("qwen1.5-0.5b")
@@ -187,8 +187,8 @@ def test_serve_deterministic_per_request():
     prompt = np.arange(6) % cfg.vocab
 
     outs = []
-    for slots in (1, 3):
-        eng = ServeEngine(cfg, max_slots=slots, max_seq=32)
+    for lanes in (1, 3):
+        eng = make_engine(cfg, max_lanes=lanes, max_seq=32, block_size=8)
         eng.load(params)
         eng.submit(prompt, max_new_tokens=5)
         done = eng.run_until_drained()
