@@ -67,6 +67,10 @@ class ModelAPI:
     # paged_kernel(pools) -> whether paged_decode attends through
     # kernels.paged_attention on these pools (None: never)
     paged_kernel: Callable = None
+    # paged_moe_inplace(params, lanes) -> whether paged_decode's MoE
+    # layers read the held experts in place (kernels.held_experts;
+    # None: never)
+    paged_moe_inplace: Callable = None
     # streaming (chunked) admission — encdec only.  enc_init(b, f_max)
     # builds the incremental encoder state; enc_step(p, ec, frames_chunk)
     # appends one chunk and returns its encoder states; enc_kv(p, enc)
@@ -147,6 +151,7 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
                 TFM.decode_step_paged(p, cfg, pools, t, bt, pos, act),
             paged_layout=lambda: TFM.paged_layout(cfg),
             paged_kernel=lambda pools: TFM.paged_kernel(cfg, pools),
+            paged_moe_inplace=TFM.moe_inplace,
         )
 
     if cfg.family in ("ssm", "hybrid"):

@@ -11,13 +11,25 @@ an expert-parallel deployment (``cfg.moe_router_experts`` wider than
 part of the result its own experts give; what the others would add is
 left out.
 
-Three execution paths, same routing math:
+Four execution paths, same routing math:
 
   * single device — dropless: every (token, held expert) assignment is
     computed, by a grouped matmul over the held experts
     (``jax.lax.ragged_dot``) on the assignments sorted by expert.  Rows
     marked invalid (prefill pad rows, inactive decode lanes) get no
     assignment, so they neither cost expert work nor change a real row.
+  * in-place decode — single device, in the paged decode scan: the held
+    experts' matmuls read the scanned layer's weights in place from the
+    stacked ``[L_moe, n, K, N]`` parameters (``kernels.held_experts``),
+    every held expert against all the step's tokens, each token weighted
+    by its top-k weight of the expert (an exact 0 where it did not pick
+    it, or is not a real row).  It engages where the caller hands over
+    the stacks and the layer's index (``held_at``) and
+    ``inplace_stacks`` accepts them: no mesh, widths of whole 128-lane
+    tiles, at most ``held_experts.MAX_ROWS`` tokens.  The ragged path
+    would need each layer's slice of the stacks copied out first, since
+    ``ragged_dot`` fuses no operand.  Prefill, training and the mesh
+    paths keep ``ragged_dot``.
   * "TP-MoE" — experts sharded over the 'model' axis, tokens replicated
     across it; every shard computes its local experts' part, dropless as
     above, and a psum combines.  Collective cost = one all-reduce of
@@ -37,6 +49,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import held_experts as HE
 from repro.kernels.planned import planned_bmm, planned_dense
 from repro.parallel.sharding import constrain
 from .layers import dense_init, _dtype
@@ -185,22 +198,63 @@ def _grouped_ffn(cfg, p, x_flat, weights, ids, first, valid):
     return y, jnp.sum(sizes)
 
 
-def moe_ffn_tokens(cfg, p, x_flat, *, local_experts=None, valid=None):
+def inplace_stacks(stacked, rows: int):
+    """The held-expert stacks {"wg", "wu", "wd"} ``[L_moe, n, ...]`` of
+    a layer stack's MoE params ``stacked`` for the in-place decode path
+    on ``rows`` tokens, or None where that path does not engage (under
+    a mesh, or where ``held_experts.engages`` refuses them)."""
+    from repro.parallel.sharding import current_mesh
+
+    ctx = current_mesh()
+    if ctx is not None and ctx.mesh is not None:
+        return None
+    stacks = {k: stacked[k] for k in ("wg", "wu", "wd")}
+    return stacks if HE.engages(*stacks.values(), rows) else None
+
+
+def _inplace_ffn(cfg, stacks, layer, x_flat, weights, ids, first, valid):
+    """The held experts' FFN of layer ``layer`` of ``stacks`` (see
+    ``inplace_stacks``), read in place: every held expert against every
+    row, weighted by ``w[T, n]``, the row's top-k weight of the expert
+    where the row is valid and picked it and 0 elsewhere.  Returns what
+    ``_grouped_ffn`` returns."""
+    n = stacks["wg"].shape[1]
+    held = _held(ids, first, n, valid)
+    with jax.named_scope("moe.dispatch"):
+        # ids of a row are distinct: each held expert gets one weight
+        w = jnp.sum(jnp.where(held, weights, 0.0)[..., None]
+                    * jax.nn.one_hot(ids - first, n, dtype=jnp.float32),
+                    axis=1)
+    with jax.named_scope("moe.experts"):
+        y = HE.held_experts_ffn(x_flat, stacks["wg"], stacks["wu"],
+                                stacks["wd"], layer, w, act=cfg.act)
+    return y, jnp.sum(held, dtype=jnp.int32)
+
+
+def moe_ffn_tokens(cfg, p, x_flat, *, local_experts=None, valid=None,
+                   held_at=None):
     """Route + dropless held-expert FFN + combine for a flat token batch.
 
     x_flat: [T, d]; valid: [T] bool or None (every row real).
     ``local_experts``: (start, count) when p's expert stacks are an
     expert shard (TP-MoE path; each shard computes its own experts'
     part, later psum'd).  Otherwise p holds the router's experts [0,
-    cfg.moe_num_experts).  Returns (y_flat, aux_loss, held): ``held``
-    counts the (token, held expert) assignments computed.
+    cfg.moe_num_experts).  ``held_at``: (stacks, layer), the held
+    experts' stacks ``inplace_stacks`` gave and this layer's index into
+    them, for the in-place path; p then needs no expert weights.
+    Returns (y_flat, aux_loss, held): ``held`` counts the (token, held
+    expert) assignments computed.
     """
     logits = planned_dense(
         x_flat.astype(jnp.float32), p["router"], site="moe.router")
     weights, ids, probs = route(cfg, logits)
     aux = load_balance_loss(cfg, probs[None], ids[None])
     first = 0 if local_experts is None else local_experts[0]
-    y, held = _grouped_ffn(cfg, p, x_flat, weights, ids, first, valid)
+    if held_at is not None:
+        y, held = _inplace_ffn(cfg, *held_at, x_flat, weights, ids, first,
+                               valid)
+    else:
+        y, held = _grouped_ffn(cfg, p, x_flat, weights, ids, first, valid)
     return y, aux, held
 
 
@@ -254,17 +308,18 @@ def _moe_shard_map(p, cfg, x, ctx, valid):
     return fn(x, valid, p["router"], p["wg"], p["wu"], p["wd"])
 
 
-def apply_moe(p, cfg, x, *, valid=None):
+def apply_moe(p, cfg, x, *, valid=None, held_at=None):
     """MoE forward: x [B,S,d] -> ([B,S,d], aux loss, held).
 
     ``valid`` [B,S] bool marks the real rows (None: all); the others get
     no expert assignment.  Under a mesh the TP-MoE shard_map path runs
     (experts sharded over the 'model' axis, one activation psum per
-    block); on a single device the dropless held-expert path runs, and
-    ``held`` counts the (token, held expert) assignments it computed
-    (None under a mesh).  The EP all-to-all variant lives in
-    parallel/collectives.py and is switched in by the hillclimb configs
-    (it ignores ``valid``).
+    block); on a single device the dropless held-expert path runs, in
+    place from the stacks where ``held_at`` is given (see
+    ``moe_ffn_tokens``), and ``held`` counts the (token, held expert)
+    assignments it computed (None under a mesh).  The EP all-to-all
+    variant lives in parallel/collectives.py and is switched in by the
+    hillclimb configs (it ignores ``valid``).
     """
     from repro.parallel.sharding import current_mesh
 
@@ -279,7 +334,8 @@ def apply_moe(p, cfg, x, *, valid=None):
     else:
         y, aux, held = moe_ffn_tokens(
             cfg, p, x.reshape(b * s, d),
-            valid=None if valid is None else valid.reshape(b * s))
+            valid=None if valid is None else valid.reshape(b * s),
+            held_at=held_at)
         y = y.reshape(b, s, d)
     if cfg.moe_shared_experts:
         act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu

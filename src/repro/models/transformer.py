@@ -404,6 +404,15 @@ def paged_kernel(cfg, pools) -> bool:
     return "k" in pools and PA.engages(pools["k"], cfg)
 
 
+def moe_inplace(p, lanes: int) -> bool:
+    """Whether ``decode_step_paged`` of ``lanes`` lanes computes its
+    MoE layers' held experts in place from the stacked parameters
+    (``moe.inplace_stacks``)."""
+    moe = p.get("moe_layers")
+    return moe is not None and MOE.inplace_stacks(moe["moe"], lanes) \
+        is not None
+
+
 def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
                          pos, active, *, moe: bool):
     """Paged twin of ``_decode_blocks``.  The stacked [L, NB, bs, ...]
@@ -415,8 +424,17 @@ def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
     out and written back whole, layer by layer, on every step.  MoE
     layers route only the active lanes; the scan also returns each MoE
     layer's count of (token, held expert) assignments (None for dense
-    layers)."""
+    layers).  Where ``moe.inplace_stacks`` accepts them, the held
+    experts' stacks leave the scan's inputs too and stay whole and
+    loop-invariant: each layer's expert matmuls read its weights in
+    place (``kernels.held_experts``), at index ``layer - layers[0]``,
+    instead of from a slice the scan copies out."""
     valid = active[:, None]
+    stacks = MOE.inplace_stacks(stacked["moe"], x.shape[0]) if moe \
+        else None
+    if stacks is not None:
+        stacked = dict(stacked, moe={k: v for k, v in stacked["moe"].items()
+                                     if k not in stacks})
 
     def body(x, inp):
         lp, layer = inp
@@ -435,7 +453,9 @@ def _decode_blocks_paged(stacked, cfg, x, pools, layers, block_tables,
         h = L.apply_norm(lp["ln2"], cfg, x)
         held = None
         if moe:
-            y, _, held = MOE.apply_moe(lp["moe"], cfg, h, valid=valid)
+            at = None if stacks is None else (stacks, layer - layers[0])
+            y, _, held = MOE.apply_moe(lp["moe"], cfg, h, valid=valid,
+                                       held_at=at)
         else:
             y = L.apply_mlp(lp["mlp"], cfg, h)
         return x + y, (rows, held)

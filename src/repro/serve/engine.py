@@ -75,7 +75,10 @@ class PagedServeEngine(EngineBase):
     ``decode_kernel_steps`` counts the decode steps whose program
     attends through ``kernels.paged_attention``, and ``decode_kv_rows``
     the K/V rows those steps' kernel read per layer: each active lane's
-    ``pos + 1`` (its pooled rows and its new one).  Each
+    ``pos + 1`` (its pooled rows and its new one).
+    ``moe_inplace_steps`` counts the decode steps whose MoE layers read
+    the held experts in place from the stacked parameters
+    (``kernels.held_experts``).  Each
     request counts the prompt rows prefilled for it, real
     (``prefill_tokens``) and as their buckets computed them
     (``prefill_padded_tokens``).
@@ -117,9 +120,11 @@ class PagedServeEngine(EngineBase):
         self._prefill_fns: dict = {}
         self._decode_exec = None
         self._decode_kernel = False
+        self._moe_inplace = False
         self.stats = {"decode_compiles": 0, "prefill_compiles": 0,
                       "preemptions": 0, "steps": 0,
-                      "decode_kernel_steps": 0, "decode_kv_rows": 0}
+                      "decode_kernel_steps": 0, "decode_kv_rows": 0,
+                      "moe_inplace_steps": 0}
         if cfg.family == "moe":
             self.stats["moe_held_assignments"] = 0
 
@@ -159,6 +164,9 @@ class PagedServeEngine(EngineBase):
         self.num_blocks = self.kv.num_blocks
         self._decode_kernel = bool(
             self.api.paged_kernel and self.api.paged_kernel(self.kv.pools))
+        self._moe_inplace = bool(
+            self.api.paged_moe_inplace
+            and self.api.paged_moe_inplace(params, self.max_lanes))
         before = planned.planned_report()
         tune0 = autotune.counters()
 
@@ -401,6 +409,8 @@ class PagedServeEngine(EngineBase):
             self.stats["decode_kernel_steps"] += 1
             self.stats["decode_kv_rows"] += int(
                 self.kv.pos[active].sum()) + len(active)
+        if self._moe_inplace:
+            self.stats["moe_inplace_steps"] += 1
         with span("sample"):
             nxt, held = jax.device_get((jnp.argmax(logits, axis=-1), held))
             for n in held:

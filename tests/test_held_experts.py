@@ -431,3 +431,193 @@ def test_defaults_leave_dense_outputs_bit_identical():
     old = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     np.testing.assert_array_equal(np.asarray(L.apply_rope(x, pos, cfg)),
                                   np.asarray(old))
+
+
+# ---------------------------------------------------------------------------
+# (g) the in-place decode path: held experts read from the stacks
+# ---------------------------------------------------------------------------
+
+#: TINY at widths of whole 128-lane tiles, where the in-place path engages
+TILED = dataclasses.replace(TINY, name="dsv2-tiny-tiled", d_model=128,
+                            moe_d_ff=128)
+
+
+@pytest.fixture(scope="module")
+def tiled():
+    params = build_model(TILED).init(jax.random.PRNGKey(11))
+    params["moe_layers"]["moe"]["router"] = jax.random.normal(
+        jax.random.PRNGKey(12), params["moe_layers"]["moe"]["router"].shape
+    ) / math.sqrt(TILED.d_model)
+    return params
+
+
+def _ragged_only(monkeypatch):
+    """Keep every MoE layer on ``ragged_dot`` over the scanned slices."""
+    monkeypatch.setattr(MOE, "inplace_stacks", lambda stacked, rows: None)
+
+
+def _decode_args(cfg, lanes=4):
+    api = build_model(cfg)
+    pools = api.paged_init(4 * lanes, 4, lanes)
+    tokens = jnp.asarray((np.arange(lanes) * 7 + 3)[:, None], jnp.int32)
+    tables = jnp.arange(4 * lanes, dtype=jnp.int32).reshape(lanes, 4)
+    pos = jnp.asarray([0, 2, 1, 0][:lanes], jnp.int32)
+    active = jnp.asarray([True, True, False, True][:lanes])
+    return api, (pools, tokens, tables, pos, active)
+
+
+def test_inplace_decode_matches_ragged_dot_on_slices(tiled, monkeypatch):
+    """The paged decode step of the tiled model, its held experts read in
+    place, gives the logits and the held count of the same step over
+    ``ragged_dot`` on each layer's slice (one lane inactive)."""
+    api, args = _decode_args(TILED)
+    assert api.paged_moe_inplace(tiled, 4)
+    logits, _, held = jax.jit(
+        lambda p, *a: api.paged_decode(p, *a))(tiled, *args)
+    _ragged_only(monkeypatch)
+    assert not api.paged_moe_inplace(tiled, 4)
+    want, _, want_held = jax.jit(
+        lambda p, *a: api.paged_decode(p, *a))(tiled, *args)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert int(held) == int(want_held) > 0
+
+
+def test_inplace_kernels_accumulate_over_tiles(monkeypatch):
+    """Weight tiles smaller than a layer's widths (d in three tiles: the
+    gate/up matmuls' K, the down matmul's N) add up to the plain
+    weighted FFN of the layer the index picks."""
+    from repro.kernels import held_experts as HE
+
+    monkeypatch.setattr(HE, "BLOCK_BYTES", 128 * 384 * 4)
+    n_layers, n, d, ff, t = 3, 4, 384, 256, 10
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    wg = jax.random.normal(ks[0], (n_layers, n, d, ff)) / 20
+    wu = jax.random.normal(ks[1], (n_layers, n, d, ff)) / 20
+    wd = jax.random.normal(ks[2], (n_layers, n, ff, d)) / 16
+    x = jax.random.normal(ks[3], (t, d))
+    w = np.array(jax.random.uniform(ks[4], (t, n)))
+    w[np.arange(t) % 3 == 0] = 0.0          # rows that picked no expert
+    y = HE.held_experts_ffn(x, wg, wu, wd, jnp.int32(1), jnp.asarray(w))
+    g = _np({"wg": wg[1], "wu": wu[1], "wd": wd[1]})
+    want = sum(w[:, e:e + 1] * _mlp(g["wg"][e], g["wu"][e], g["wd"][e],
+                                    np.asarray(x, np.float64))
+               for e in range(n))
+    np.testing.assert_allclose(np.asarray(y), want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(y)[w.sum(1) == 0], 0.0)
+
+
+def _tiled_layer(seed):
+    cfg = dataclasses.replace(TILED, moe_num_experts=16)
+    p = MOE.init_moe(jax.random.PRNGKey(seed), cfg)
+    p["router"] = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                                    p["router"].shape) / 8.0
+    held = dataclasses.replace(cfg, moe_num_experts=4)
+    stacks = {k: p[k][None, :4] for k in ("wg", "wu", "wd")}
+    rest = {k: v for k, v in p.items() if k not in stacks}
+    return cfg, held, p, stacks, rest
+
+
+def test_inplace_inactive_lanes_and_unpicked_tokens_change_no_real_row():
+    """Rows marked invalid, whatever they hold, change no real row and
+    add no held assignment; a real row that picked no held expert gets
+    an exact zero from the held experts."""
+    cfg, held, p, stacks, rest = _tiled_layer(seed=21)
+    x = jax.random.normal(jax.random.PRNGKey(23), (1, 12, cfg.d_model))
+    valid = np.ones((1, 12), bool)
+    valid[0, 8:] = False
+    garbage = jnp.where(jnp.asarray(valid)[..., None], x, 1e3)
+    at = (stacks, jnp.int32(0))
+    y, _, n = MOE.apply_moe(rest, held, garbage, valid=jnp.asarray(valid),
+                            held_at=at)
+    alone, _, n_alone = MOE.apply_moe(rest, held, x[:, :8], held_at=at)
+    np.testing.assert_allclose(np.asarray(y[0, :8]), np.asarray(alone[0]),
+                               rtol=1e-6, atol=1e-6)
+    assert int(n) == int(n_alone)
+    # the held experts alone (no shared experts): rows whose top-k misses
+    # experts 0-3 read exactly 0
+    bare = dataclasses.replace(held, moe_shared_experts=0)
+    flat = x[0]
+    _, ids, _ = MOE.route(bare, flat @ p["router"])
+    none = ~np.any(np.asarray(ids) < 4, axis=1)
+    assert none.any() and not none.all()
+    y, _, n = MOE.moe_ffn_tokens(bare, rest, flat, held_at=at)
+    np.testing.assert_array_equal(np.asarray(y)[none], 0.0)
+    ragged = dict(rest, **{k: v[0] for k, v in stacks.items()})
+    want, _, n_want = MOE.moe_ffn_tokens(bare, ragged, flat)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+    assert int(n) == int(n_want)
+
+
+def test_inplace_computes_all_tokens_on_one_held_expert():
+    """Every token picks held expert 0 first (T rows in one group) and
+    experts 1-5 after it: the in-place path computes each assignment, as
+    the plain forward does."""
+    cfg = dataclasses.replace(TILED, moe_num_experts=4)
+    p = MOE.init_moe(jax.random.PRNGKey(31), cfg)
+    router = np.zeros((cfg.d_model, 16), np.float32)
+    router[:, 0] = 4.0 / cfg.d_model
+    router[:, 1:6] = np.linspace(2.0, 1.0, 5) / cfg.d_model
+    p["router"] = jnp.asarray(router)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(32),
+                                  (1, 40, cfg.d_model))) + 0.1
+    _, ids, _ = MOE.route(cfg, x.reshape(40, -1) @ p["router"])
+    assert np.all(np.asarray(ids)[:, 0] == 0)
+    stacks = {k: p[k][None] for k in ("wg", "wu", "wd")}
+    rest = {k: v for k, v in p.items() if k not in stacks}
+    y, _, counted = MOE.apply_moe(rest, cfg, x,
+                                  held_at=(stacks, jnp.int32(0)))
+    want = _moe(_np(p), cfg, np.asarray(x[0], np.float64), list(range(4)))
+    np.testing.assert_allclose(np.asarray(y[0]), want, rtol=1e-4, atol=1e-5)
+    assert int(counted) == 40 * 4
+
+
+@pytest.mark.parametrize("cfg_name", ["tiled", "tiny", "qwen"])
+def test_moe_inplace_steps_counts_decode_steps(cfg_name, tiled, tiny):
+    """``moe_inplace_steps`` counts every decode step where the held
+    experts are read in place, and stays 0 where the path does not
+    engage: widths that are not whole 128-lane tiles, a model with no
+    MoE layer."""
+    if cfg_name == "qwen":
+        cfg = get_smoke_config("qwen1.5-0.5b")
+        params = build_model(cfg).init(jax.random.PRNGKey(0))
+    else:
+        cfg, params = {"tiled": (TILED, tiled), "tiny": (TINY, tiny)}[
+            cfg_name]
+    eng = PagedServeEngine(cfg, max_lanes=3, max_seq=32, block_size=4)
+    eng.load(params)
+    for n in (5, 9):
+        eng.submit(np.arange(n, dtype=np.int32) % cfg.vocab,
+                   max_new_tokens=4)
+    eng.run_until_drained()
+    assert eng.stats["steps"] > 0
+    want = eng.stats["steps"] if cfg_name == "tiled" else 0
+    assert eng.stats["moe_inplace_steps"] == want
+
+
+def _layer_slices(text, stack_shape):
+    """The dynamic-slice ops of ``text`` (StableHLO) that cut one whole
+    layer out of a ``stack_shape`` expert stack."""
+    big = "tensor<" + "x".join(map(str, stack_shape)) + "xf32>"
+    one = "tensor<" + "x".join(map(str, (1,) + stack_shape[1:])) + "xf32>"
+    return [line for line in text.splitlines()
+            if "dynamic_slice" in line and big in line and one in line]
+
+
+def test_lowered_decode_has_no_slice_of_the_expert_stacks(tiled,
+                                                         monkeypatch):
+    """The decode program reads the expert stacks whole: no dynamic-slice
+    cuts a layer out of a [L_moe, n, K, N] stack, as the scan over the
+    stacks does on the ragged path."""
+    api, args = _decode_args(TILED)
+    shape = tuple(tiled["moe_layers"]["moe"]["wg"].shape)
+    assert shape == (3, 4, 128, 128)
+
+    def lowered():
+        return jax.jit(lambda p, *a: api.paged_decode(p, *a)).lower(
+            tiled, *args).as_text()
+
+    assert _layer_slices(lowered(), shape) == []
+    _ragged_only(monkeypatch)
+    assert len(_layer_slices(lowered(), shape)) >= 3

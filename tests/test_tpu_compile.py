@@ -120,3 +120,22 @@ def test_paged_attention_compiles_for_v5e(one_chip, pool_dtype, bs):
         *a, scale=0.125, interpret=False)).lower(
             *_on(one_chip, shapes)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_held_experts_compile_for_v5e(one_chip):
+    """The held experts' in-place decode kernels at DeepSeek-V2-Lite's
+    serving shapes: 26 MoE layers of 8 held experts, d 2048, ff 1408, 64
+    lanes, bf16."""
+    from repro.kernels import held_experts as HE
+
+    layers, n, d, ff, lanes = 26, 8, 2048, 1408, 64
+    up = jax.ShapeDtypeStruct((layers, n, d, ff), jnp.bfloat16)
+    down = jax.ShapeDtypeStruct((layers, n, ff, d), jnp.bfloat16)
+    assert HE.engages(up, up, down, lanes)
+    shapes = (jax.ShapeDtypeStruct((lanes, d), jnp.bfloat16), up, up, down,
+              jax.ShapeDtypeStruct((), jnp.int32),
+              jax.ShapeDtypeStruct((lanes, n), jnp.float32))
+    compiled = jax.jit(lambda *a: HE.held_experts_ffn(
+        *a, interpret=False)).lower(*_on(one_chip, shapes)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
